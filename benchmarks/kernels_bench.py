@@ -29,14 +29,16 @@ def run():
         assert ws < VMEM_BYTES
         q = jax.random.normal(key, (1, 256, 4, D), jnp.float32)
         k = jax.random.normal(key, (1, 256, 2, D), jnp.float32)
-        us = _time(lambda a, b, c: ops.flash_attention_bshd(a, b, c), q, k, k)
+        us = _time(lambda a, b, c: ops.flash_attention_bshd(
+            a, b, c, interpret=True), q, k, k)
         rows.append((f"flash_attention_D{D}", us,
                      f"vmem_ws={ws/1024:.0f}KiB"))
     # decode attention
     q = jax.random.normal(key, (4, 1, 8, 128), jnp.float32)
     kc = jax.random.normal(key, (4, 1024, 2, 128), jnp.float32)
     lens = jnp.full((4,), 1000, jnp.int32)
-    us = _time(lambda a, b, c, l: ops.decode_attention_bshd(a, b, c, l),
+    us = _time(lambda a, b, c, l: ops.decode_attention_bshd(
+        a, b, c, l, interpret=True),
                q, kc, kc, lens)
     rows.append(("decode_attention_S1024", us, "flash_decoding_grid"))
     # ssd
@@ -44,7 +46,8 @@ def run():
     dt = jax.nn.softplus(jax.random.normal(key, (1, 512, 4)))
     A = -jnp.exp(jax.random.normal(key, (4,)) * 0.3)
     B = jax.random.normal(key, (1, 512, 1, 64)) * 0.3
-    us = _time(lambda *a: ops.ssd(*a, chunk=128), x, dt, A, B, B)
+    us = _time(lambda *a: ops.ssd(*a, chunk=128, interpret=True),
+               x, dt, A, B, B)
     rows.append(("ssd_scan_L512", us, "chunked_dual_form"))
     for name, us, derived in rows:
         print(f"{name},{us:.0f},{derived}")

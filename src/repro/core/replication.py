@@ -17,6 +17,7 @@ device-set changes lower to HLO with fewer resharding collectives
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 import re
@@ -77,8 +78,8 @@ def batch_spec_for_degree(degree: int, mesh: Mesh) -> P:
     return P(axes)
 
 
-def layer_hook_from_degrees(degrees: Tuple[int, ...], mesh: Mesh, *,
-                            extra_dims: int = 2):
+@dataclasses.dataclass(frozen=True)
+class LayerHook:
     """hook(i, x) -> x constrained to layer i's batch sharding, from an
     already-quantized degree tuple. The tuple is hashable, so the LIVE
     engine passes it as a static jit argument — changing the plan recompiles
@@ -86,24 +87,32 @@ def layer_hook_from_degrees(degrees: Tuple[int, ...], mesh: Mesh, *,
     ``layer_hook_from_plan``; see serving/engine.Engine.apply_plan).
 
     ``extra_dims``: trailing activation dims left unsharded ([B,S,d] -> 2).
+    ``batch_spec(i)`` is layer i's batch PartitionSpec over ``mesh``: code
+    inside a layer that the compiler cannot partition by itself — a Pallas
+    kernel — maps itself over the batch shards with it.
     """
-    def hook(i: int, x):
-        d = min(degrees[i], mesh.devices.size)
-        spec = batch_spec_for_degree(d, mesh)
-        full = P(*(tuple(spec) + (None,) * extra_dims))
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, full))
+    degrees: Tuple[int, ...]
+    mesh: Mesh
+    extra_dims: int = 2
 
-    return hook
+    def batch_spec(self, i: int) -> P:
+        return batch_spec_for_degree(
+            min(self.degrees[i], self.mesh.devices.size), self.mesh)
+
+    def __call__(self, i: int, x):
+        full = P(*(tuple(self.batch_spec(i)) + (None,) * self.extra_dims))
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(self.mesh, full))
 
 
 def layer_hook_from_plan(plan: PlacementPlan, mesh: Mesh, *,
-                         extra_dims: int = 2):
+                         extra_dims: int = 2) -> LayerHook:
     """Returns hook(i, x) -> x constrained to the layer's batch sharding.
 
     ``extra_dims``: trailing activation dims left unsharded ([B,S,d] -> 2).
     """
     degrees = tuple(quantize_degrees(plan.p, mesh.devices.size))
-    return layer_hook_from_degrees(degrees, mesh, extra_dims=extra_dims)
+    return LayerHook(degrees, mesh, extra_dims)
 
 
 def count_collectives(hlo_text: str) -> dict:

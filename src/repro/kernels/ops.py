@@ -1,9 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-Each op picks ``interpret=True`` automatically off-TPU (this container), so
-the same call sites run the compiled kernel on real hardware and the
-Python-interpreted kernel body here. Wrappers also handle padding to block
-multiples and layout conversion from the model's [B, S, H, D] convention.
+``interpret`` comes from the caller: False (the default) compiles the
+kernel for the TPU, True runs its interpreted body, which is how the tests
+exercise it on the CPU. There is no platform switch in here. Wrappers also
+handle padding to block multiples and layout conversion from the model's
+[B, S, H, D] convention.
 """
 from __future__ import annotations
 
@@ -17,16 +18,9 @@ from repro.kernels import decode_attention as _da
 from repro.kernels import ssd_scan as _ssd
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _interpret(override):
-    return (not on_tpu()) if override is None else override
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "interpret"))
-def flash_attention_bshd(q, k, v, *, causal: bool = True, interpret=None):
+def flash_attention_bshd(q, k, v, *, causal: bool = True,
+                         interpret: bool = False):
     """Model-layout flash attention: q [B,Sq,H,D], k/v [B,Sk,KV,D]."""
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
@@ -45,12 +39,12 @@ def flash_attention_bshd(q, k, v, *, causal: bool = True, interpret=None):
     if not causal:
         assert pad_k == 0, "non-causal path requires block-aligned Sk"
     out = _fa.flash_attention(qt, kt, vt, causal=causal, blk_q=blk_q,
-                              blk_k=blk_k, interpret=_interpret(interpret))
+                              blk_k=blk_k, interpret=interpret)
     return out.transpose(0, 2, 1, 3)[:, :Sq]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def decode_attention_bshd(q, k, v, lengths, *, interpret=None):
+def decode_attention_bshd(q, k, v, lengths, *, interpret: bool = False):
     """Decode: q [B,1,H,D], cache k/v [B,S,KV,D], lengths [B] -> [B,1,H,D]."""
     B, _, H, D = q.shape
     S = k.shape[1]
@@ -59,13 +53,13 @@ def decode_attention_bshd(q, k, v, lengths, *, interpret=None):
     kt = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
     vt = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
     out = _da.decode_attention(q[:, 0], kt, vt, lengths, blk_k=blk_k,
-                               interpret=_interpret(interpret))
+                               interpret=interpret)
     return out[:, None]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssd(x, dt, A, B, C, chunk: int = 128, initial_state=None, *,
-        interpret=None):
+        interpret: bool = False):
     """SSD scan with the model's signature (see models/ssm.ssd_chunked).
 
     Falls back to the jnp reference when an initial state is supplied
@@ -83,5 +77,5 @@ def ssd(x, dt, A, B, C, chunk: int = 128, initial_state=None, *,
         B = jnp.pad(B, ((0, 0), (0, pad), (0, 0), (0, 0)))
         C = jnp.pad(C, ((0, 0), (0, pad), (0, 0), (0, 0)))
     y, h = _ssd.ssd_scan(x, dt, A, B, C, chunk=Q,
-                         interpret=_interpret(interpret))
+                         interpret=interpret)
     return y[:, :L], h

@@ -45,15 +45,8 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import tomllib
 from typing import List, Optional
-
-try:                      # 3.11+ stdlib
-    import tomllib as _toml
-except ImportError:       # 3.10: the vendored backport
-    try:
-        import tomli as _toml  # type: ignore
-    except ImportError:   # pragma: no cover - one of the two is baked in
-        _toml = None
 
 
 @dataclasses.dataclass
@@ -113,11 +106,8 @@ def load_inventory(path: str) -> List[Node]:
     if path.endswith(".json"):
         with open(path) as f:
             return parse_inventory(json.load(f), origin=path)
-    if _toml is None:  # pragma: no cover - tomli/tomllib is baked in
-        raise RuntimeError("TOML inventory needs tomllib (py3.11+) or "
-                           "tomli; use a .json inventory instead")
     with open(path, "rb") as f:
-        return parse_inventory(_toml.load(f), origin=path)
+        return parse_inventory(tomllib.load(f), origin=path)
 
 
 def launch_pod(cfg, params, nodes: List[Node], *,
@@ -149,9 +139,13 @@ def launch_pod(cfg, params, nodes: List[Node], *,
     import multiprocessing as mp
     import time
 
-    from repro.serving.remote_engine import EngineProxy, engine_server_listen
+    from repro.serving.remote_engine import (EngineProxy,
+                                             engine_server_listen,
+                                             refuse_spawn_on_accelerator)
     from repro.serving.transport import TransportError
 
+    if any(node.spawn for node in nodes):
+        refuse_spawn_on_accelerator("an inventory with spawn nodes")
     deadline = (None if pod_timeout is None
                 else time.monotonic() + pod_timeout)
     ctx = mp.get_context("spawn")
@@ -204,23 +198,31 @@ def make_worker_factory(cfg, params, *, remote: bool = False,
     engine-server process and returns its ``EngineProxy`` (the same
     plane launch-time ``--workers`` instances live on); the default
     builds an in-process paged ``LocalInstance`` — enough for tests and
-    single-host elasticity without process spin-up cost.
+    single-host elasticity without process spin-up cost — placed
+    round-robin over the local devices (one chip per instance).
 
     Grown workers are labeled ``g<idx>`` — disjoint from the
     launch-time ``w<k>`` namespace, so fault-injection plans and logs
     can tell a runtime spawn from the original fleet."""
     if remote:
-        from repro.serving.remote_engine import EngineProxy
+        from repro.serving.remote_engine import (EngineProxy,
+                                                 refuse_spawn_on_accelerator)
+        refuse_spawn_on_accelerator("--elastic with remote workers")
 
         def factory(idx: int):
             return EngineProxy(cfg, params, peer_label=f"g{idx}",
                                **engine_kw)
     else:
+        import jax
+
         from repro.serving.engine import Engine
         from repro.serving.instance import LocalInstance
 
+        devs = jax.local_devices()
+
         def factory(idx: int):
             return LocalInstance(Engine(cfg, params, cache_kind="paged",
+                                        device=devs[idx % len(devs)],
                                         **engine_kw))
     return factory
 

@@ -3,13 +3,22 @@ engines, real telemetry feeding Monitor -> Controller, decisions executed
 on the running instances (scale-up replication degrees, scale-down
 KV-block migration).
 
-Runs REAL JAX execution with a reduced config (CPU-feasible); on a real
-pod the same orchestrator runs the full config under
-make_production_mesh(). Families without paged support (SSM/MLA/audio)
-fall back to a single dense engine with the same submission loop.
+Runs REAL JAX execution. By default the model is the reduced
+(CPU-sized) variant in float32; ``--full-width`` serves the published
+widths in the config's dtype (bfloat16 weights and KV pool), which is how
+it runs on a TPU chip (``chip_smoke.py`` drives this construction end to
+end). ``--max-len`` bounds each request's context. Families without paged
+support (SSM/MLA/audio) fall back to a single dense engine with the same
+submission loop.
 
     PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b \
         --requests 24 --rps 4 --instances 2
+
+On a host with several chips, in-process instances go one per chip.
+Spawned engine servers (``--workers``, spawn nodes of ``--inventory``,
+``--elastic`` with remote workers) are CPU-only: a chip belongs to one
+process, and this one holds it, so on a TPU they are refused before
+anything is spawned.
 
 Every flag lives on ``ServeConfig`` — one dataclass, built either from
 the command line (``ServeConfig.from_args``) or from a TOML file
@@ -50,7 +59,10 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+import pathlib
 import time
+import tomllib
 from typing import Optional
 
 import jax
@@ -74,6 +86,8 @@ class ServeConfig:
     max_batch: int = 4
     max_new: int = 16
     prompt_len: int = 12
+    max_len: int = 128
+    full_width: bool = False
     instances: int = 2
     workers: int = 0
     inventory: Optional[str] = None
@@ -106,19 +120,16 @@ class ServeConfig:
         if self.requests < 0 or self.token_budget < 1:
             raise ValueError("requests must be >= 0 and "
                              "token_budget >= 1")
+        if self.max_len < 2:
+            raise ValueError(f"max_len must be >= 2, got {self.max_len}")
         return self
 
     @classmethod
     def from_toml(cls, path: str) -> "ServeConfig":
         """Load a config file: all keys optional, unknown keys are an
-        error (a typo should not silently fall back to a default).
-        Reuses launch/pod.py's tomllib/tomli probe."""
-        from repro.launch.pod import _toml
-        if _toml is None:  # pragma: no cover - tomli/tomllib baked in
-            raise RuntimeError("TOML config needs tomllib (py3.11+) or "
-                               "tomli")
+        error (a typo should not silently fall back to a default)."""
         with open(path, "rb") as f:
-            data = _toml.load(f)
+            data = tomllib.load(f)
         if "serve" in data and isinstance(data["serve"], dict):
             data = data["serve"]
         names = {f.name for f in dataclasses.fields(cls)}
@@ -152,6 +163,14 @@ def _build_parser(d: ServeConfig) -> argparse.ArgumentParser:
     ap.add_argument("--max-batch", type=int, default=d.max_batch)
     ap.add_argument("--max-new", type=int, default=d.max_new)
     ap.add_argument("--prompt-len", type=int, default=d.prompt_len)
+    ap.add_argument("--max-len", type=int, default=d.max_len,
+                    help="per-request context bound (prompt + output "
+                         "tokens); at most the model's max_seq_len")
+    ap.add_argument("--full-width", action="store_true",
+                    default=d.full_width,
+                    help="serve the published widths in the config's "
+                         "dtype (default: the reduced CPU-sized variant "
+                         "in float32)")
     ap.add_argument("--instances", type=int, default=d.instances)
     ap.add_argument("--workers", type=int, default=d.workers,
                     help="spawn N engine-server PROCESSES and drive them "
@@ -230,14 +249,101 @@ def _build_parser(d: ServeConfig) -> argparse.ArgumentParser:
     return ap
 
 
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing else is set. Otherwise the cache lives at ``.jax_cache/`` in
+    the checkout: a fixed path, because the path is part of what a later
+    run looks up. Call it before the first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def main(argv=None):
+    enable_compile_cache()
     return run(ServeConfig.from_args(argv))
+
+
+def build_model(args: ServeConfig):
+    """The served model: ``(cfg, params, dtype)``, weights drawn from a
+    fixed seed. ``dtype`` is the storage type of the weights and the KV
+    pool: the config's own with ``full_width``, else float32 with the
+    reduced config."""
+    cfg = get_config(args.arch)
+    if args.full_width:
+        dtype = cfg.dtype
+    else:
+        cfg, dtype = cfg.reduced(), "float32"
+    if args.max_len > cfg.max_seq_len:
+        raise ValueError(f"max_len {args.max_len} exceeds {cfg.name}'s "
+                         f"max_seq_len {cfg.max_seq_len}")
+    return cfg, T.init_params(cfg, jax.random.PRNGKey(0), dtype), dtype
+
+
+def build_pod(args: ServeConfig, cfg, params, dtype, **engine_kw):
+    """The paged serving pod ``run`` drives: an ``Orchestrator`` over
+    in-process instances (one per local device, round-robin), spawned
+    ``--workers`` or an ``--inventory`` pod, with the scheduler, prefix
+    sharing and, for ``--http``, the front-door knobs. ``engine_kw``
+    passes further ``Engine`` options through (``chip_smoke.py`` uses
+    it to pick the decode attention)."""
+    from repro.serving.orchestrator import Orchestrator, RespawnPolicy
+    policy = RespawnPolicy() if args.supervise else None
+    engine_kw = dict(engine_kw, scheduler=args.scheduler,
+                     token_budget=args.token_budget)
+    front_kw = {}
+    if args.http:
+        # front-door knobs: admission ceiling (-> 429 at the door) and,
+        # with --elastic, the runtime worker factory + pod thresholds
+        # that let the controller grow/shrink the pod while serving
+        front_kw["max_queue"] = args.max_queue
+        if args.elastic:
+            from repro.core.controller import PodElasticityConfig
+            from repro.launch.pod import make_worker_factory
+            front_kw["worker_factory"] = make_worker_factory(
+                cfg, params, remote=bool(args.workers or args.inventory),
+                max_batch=args.max_batch, max_len=args.max_len,
+                dtype=dtype, **engine_kw)
+            front_kw["pod_cfg"] = PodElasticityConfig(
+                max_instances=args.max_pod)
+    if args.flightrec_out:
+        front_kw["flightrec_path"] = args.flightrec_out
+    if args.inventory:
+        from repro.launch.pod import launch_pod, load_inventory
+        nodes = load_inventory(args.inventory)
+        handles = launch_pod(cfg, params, nodes,
+                             max_batch=args.max_batch, max_len=args.max_len,
+                             dtype=dtype, **engine_kw)
+        orch = Orchestrator(cfg, params, handles=handles,
+                            slo_latency=args.slo, telemetry_every=4,
+                            rpc_deadline=args.rpc_deadline,
+                            respawn_policy=policy, **front_kw)
+        print(f"[serve] pod: {len(handles)} engine servers over TCP "
+              f"({sum(n.spawn for n in nodes)} node(s) spawned, "
+              f"{sum(not n.spawn for n in nodes)} attached)")
+        return orch
+    orch = Orchestrator(cfg, params, n_instances=args.workers or args.instances,
+                        max_batch=args.max_batch, max_len=args.max_len,
+                        dtype=dtype, slo_latency=args.slo,
+                        telemetry_every=4, remote=bool(args.workers),
+                        rpc_deadline=args.rpc_deadline,
+                        respawn_policy=policy, **front_kw, **engine_kw)
+    if args.workers:
+        print(f"[serve] distributed plane: {args.workers} "
+              f"engine-server processes over RPC")
+    return orch
 
 
 def run(args: ServeConfig):
     args.validate()
-    cfg = get_config(args.arch).reduced()
-    params = T.init_params(cfg, jax.random.PRNGKey(0), "float32")
+    cfg, params, dtype = build_model(args)
     kind = args.cache
     if kind == "auto":  # primary path where the family supports it
         kind = "paged" if cfg.supports_paged_kv else "dense"
@@ -260,8 +366,8 @@ def run(args: ServeConfig):
                          "pool's content chains)")
 
     if kind == "dense":  # legacy single-engine fallback (no paged pool)
-        eng = Engine(cfg, params, max_batch=args.max_batch, max_len=128,
-                     cache_kind="dense")
+        eng = Engine(cfg, params, max_batch=args.max_batch,
+                     max_len=args.max_len, dtype=dtype, cache_kind="dense")
         submitted, finished, step = 0, [], 0
         while len(finished) < args.requests and step < 5000:
             while submitted < args.requests and \
@@ -273,52 +379,8 @@ def run(args: ServeConfig):
         _report(finished, time.time() - t_start)
         return len(finished)
 
-    from repro.serving.orchestrator import Orchestrator, RespawnPolicy
-    policy = RespawnPolicy() if args.supervise else None
-    sched_kw = dict(scheduler=args.scheduler,
-                    token_budget=args.token_budget)
-    front_kw = {}
-    if args.http:
-        # front-door knobs: admission ceiling (-> 429 at the door) and,
-        # with --elastic, the runtime worker factory + pod thresholds
-        # that let the controller grow/shrink the pod while serving
-        front_kw["max_queue"] = args.max_queue
-        if args.elastic:
-            from repro.core.controller import PodElasticityConfig
-            from repro.launch.pod import make_worker_factory
-            front_kw["worker_factory"] = make_worker_factory(
-                cfg, params, remote=bool(args.workers or args.inventory),
-                max_batch=args.max_batch, max_len=128, **sched_kw)
-            front_kw["pod_cfg"] = PodElasticityConfig(
-                max_instances=args.max_pod)
-    if args.flightrec_out:
-        front_kw["flightrec_path"] = args.flightrec_out
-    if args.inventory:
-        from repro.launch.pod import launch_pod, load_inventory
-        nodes = load_inventory(args.inventory)
-        handles = launch_pod(cfg, params, nodes,
-                             max_batch=args.max_batch, max_len=128,
-                             **sched_kw)
-        n_instances = len(handles)
-        orch = Orchestrator(cfg, params, handles=handles,
-                            slo_latency=args.slo, telemetry_every=4,
-                            rpc_deadline=args.rpc_deadline,
-                            respawn_policy=policy, **front_kw)
-        print(f"[serve] pod: {n_instances} engine servers over TCP "
-              f"({sum(n.spawn for n in nodes)} node(s) spawned, "
-              f"{sum(not n.spawn for n in nodes)} attached)")
-    else:
-        n_instances = args.workers or args.instances
-        orch = Orchestrator(cfg, params, n_instances=n_instances,
-                            max_batch=args.max_batch, max_len=128,
-                            slo_latency=args.slo, telemetry_every=4,
-                            remote=bool(args.workers),
-                            rpc_deadline=args.rpc_deadline,
-                            respawn_policy=policy, **front_kw,
-                            **sched_kw)
-        if args.workers:
-            print(f"[serve] distributed plane: {args.workers} "
-                  f"engine-server processes over RPC")
+    orch = build_pod(args, cfg, params, dtype)
+    n_instances = len(orch.instances)
     if args.http:
         from repro.serving.ingress import Ingress
         ing = Ingress(orch, host=args.http_host, port=args.http_port,
@@ -328,12 +390,12 @@ def run(args: ServeConfig):
               f"(POST /v1/completions; GET /v1/models /healthz /stats "
               f"/metrics /debug/flightrec)"
               + ("  [elastic pod]" if args.elastic else ""), flush=True)
+        end = (None if args.http_seconds is None
+               else time.monotonic() + args.http_seconds)
         try:
-            if args.http_seconds is not None:
-                time.sleep(args.http_seconds)
-            else:
-                while True:
-                    time.sleep(1.0)
+            while ing.pump_error is None and (
+                    end is None or time.monotonic() < end):
+                time.sleep(0.1)
         except KeyboardInterrupt:
             print("\n[serve] interrupt; draining streams", flush=True)
         ing.close()
@@ -345,6 +407,9 @@ def run(args: ServeConfig):
               f"400s={c.bad_requests}")
         _report(orch.finished, time.time() - t_start)
         orch.close()
+        if ing.pump_error is not None:
+            raise SystemExit(f"[serve] the ingress pump died: "
+                             f"{ing.pump_error!r}")
         return len(orch.finished)
 
     submitted, step = 0, 0

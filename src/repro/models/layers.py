@@ -8,11 +8,13 @@ lowers with a bounded live-score footprint — XLA does not flash-ify a naive
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro.configs.base import ModelConfig
 
@@ -246,9 +248,15 @@ def apply_gqa(p, x, cfg: ModelConfig, *, positions, cache=None, slots=None,
     return jnp.einsum("bsh,hd->bsd", out, p["wo"]), new_cache
 
 
+def paged_kernel_serves(cfg: ModelConfig, window) -> bool:
+    """Whether the Pallas paged-decode kernel computes this attention:
+    plain causal GQA, no logit softcap, no sliding window."""
+    return not cfg.logit_softcap and window is None
+
+
 def apply_gqa_paged(p, x, cfg: ModelConfig, *, positions, pool_k, pool_v,
                     block_tables, active, window=None,
-                    impl="gather", interpret=False):
+                    impl="gather", interpret=False, batch_shard=None):
     """GQA decode attention against a paged block pool (write-then-attend).
 
     Single-token decode only: x [B,1,d]. ``pool_k/v`` are ONE layer's pool
@@ -264,9 +272,12 @@ def apply_gqa_paged(p, x, cfg: ModelConfig, *, positions, pool_k, pool_v,
     gather and reuses the chunked fp32 attention (jit-friendly anywhere);
     "kernel" calls the Pallas paged-decode kernel (kernels/paged_decode.py)
     whose HBM traffic stops at each request's true length. The kernel
-    implements plain causal GQA only, so logit-softcap archs and sliding
-    windows silently route back to the gather path — identical numerics,
-    no divergence between impls.
+    implements plain causal GQA only (``paged_kernel_serves``): asking it
+    to serve a logit softcap or a sliding window raises. The Engine makes
+    that choice once, at construction. ``batch_shard`` = (mesh, batch
+    PartitionSpec) of a replicated layer: the compiler cannot partition a
+    Pallas kernel, so the kernel is mapped over the batch shards there
+    (each shard reads the replicated pool).
 
     Returns (out [B,1,d], new_pool_k, new_pool_v).
     """
@@ -295,12 +306,21 @@ def apply_gqa_paged(p, x, cfg: ModelConfig, *, positions, pool_k, pool_v,
                                        mode="drop")
 
     kv_len = jnp.where(active, pos + 1, 0).astype(jnp.int32)
-    if impl == "kernel" and (cfg.logit_softcap or window is not None):
-        impl = "gather"  # kernel has no softcap/window support
     if impl == "kernel":
+        if not paged_kernel_serves(cfg, window):
+            raise ValueError("the paged decode kernel has no logit softcap "
+                             "or sliding window; use impl='gather'")
         from repro.kernels.paged_decode import paged_decode_attention
-        out = paged_decode_attention(q[:, 0], new_k, new_v, block_tables,
-                                     kv_len, interpret=interpret)[:, None]
+        kernel = functools.partial(paged_decode_attention,
+                                   interpret=interpret)
+        if batch_shard is not None:
+            mesh, spec = batch_shard
+            b = PartitionSpec(*spec)
+            kernel = jax.shard_map(
+                kernel, mesh=mesh, in_specs=(b, PartitionSpec(),
+                                             PartitionSpec(), b, b),
+                out_specs=b, check_vma=False)
+        out = kernel(q[:, 0], new_k, new_v, block_tables, kv_len)[:, None]
     else:
         max_blocks = block_tables.shape[1]
         tbl = jnp.maximum(block_tables, 0).astype(jnp.int32)

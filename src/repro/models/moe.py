@@ -173,7 +173,6 @@ def _moe_expert_parallel(p, x, weights, idx, cfg: ModelConfig, rules,
     Requires: experts sharded over `exp_axis` (= rules["experts"]), tokens
     batch-sharded over the same axis, d_ff sharded over rules["ffn"].
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = rules["mesh"]
@@ -266,7 +265,7 @@ def _moe_expert_parallel(p, x, weights, idx, cfg: ModelConfig, rules,
         return out.reshape(Bl, Sl, d).astype(xb.dtype)
 
     bspec = batch_spec
-    return shard_map(
+    return jax.shard_map(
         block, mesh=mesh,
         in_specs=(P(bspec, None, None), P(bspec, None, None),
                   P(bspec, None, None),
@@ -274,5 +273,5 @@ def _moe_expert_parallel(p, x, weights, idx, cfg: ModelConfig, rules,
                   P(exp_axis, None, rules.get("ffn")),
                   P(exp_axis, rules.get("ffn"), None)),
         out_specs=P(bspec, None, None),
-        check_rep=False,
+        check_vma=False,
     )(x, weights, idx, p["w_gate"], p["w_up"], p["w_down"])

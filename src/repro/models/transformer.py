@@ -394,10 +394,10 @@ def forward_paged(params, cfg: ModelConfig, tokens, cache, *, window=None,
       out of every pool write — the shape-stable static-batch trick)
 
     Positions are derived on device (new token sits at ``lengths[b]``).
-    ``layer_hook(i, x) -> x`` (core/replication.layer_hook_from_degrees)
-    unrolls the stack so each layer can carry its own batch-sharding
-    constraint — CoCoServe's per-layer replication degrees applied to the
-    LIVE paged decode step; ``None`` keeps the O(1)-depth lax.scan.
+    ``layer_hook`` (a core/replication.LayerHook) unrolls the stack so
+    each layer can carry its own batch-sharding constraint — CoCoServe's
+    per-layer replication degrees applied to the LIVE paged decode step;
+    ``None`` keeps the O(1)-depth lax.scan.
     Returns (logits [B, Vpad], new_cache, aux_loss).
     """
     if not cfg.supports_paged_kv:
@@ -411,14 +411,15 @@ def forward_paged(params, cfg: ModelConfig, tokens, cache, *, window=None,
         x = x + L.sinusoidal_positions(positions, cfg.d_model).astype(x.dtype)
     x = lshard(x, "batch", "seq", None)
 
-    def body(carry, xs):
+    def body(carry, xs, batch_shard=None):
         x, aux = carry
         lp, kl, vl = xs
         h = L.apply_norm(lp["norm1"], x, cfg)
         h, kl, vl = L.apply_gqa_paged(
             lp["attn"], h, cfg, positions=positions, pool_k=kl, pool_v=vl,
             block_tables=cache["block_tables"], active=active,
-            window=window, impl=attn_impl, interpret=interpret)
+            window=window, impl=attn_impl, interpret=interpret,
+            batch_shard=batch_shard)
         x = _residual(x, h)
         x, a = _mlp_sublayer(lp, x, cfg, "auto")
         return (x, aux + a), (kl, vl)
@@ -434,7 +435,8 @@ def forward_paged(params, cfg: ModelConfig, tokens, cache, *, window=None,
             x = layer_hook(i, x)
             (x, aux), (kl, vl) = body(
                 (x, aux), (_layer_slice(params["layers"], i),
-                           cache["k"][i], cache["v"][i]))
+                           cache["k"][i], cache["v"][i]),
+                batch_shard=(layer_hook.mesh, layer_hook.batch_spec(i)))
             nks.append(kl)
             nvs.append(vl)
         nk, nv = jnp.stack(nks), jnp.stack(nvs)

@@ -19,7 +19,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models.layers import NEG_INF
@@ -74,7 +73,7 @@ def flash_decode(q, k_cache, v_cache, q_positions, k_positions, *,
         return (o_sum / denom[..., None]).astype(q.dtype)     # [B,KV,R,Dv]
 
     bspec = batch_axis
-    out = shard_map(
+    out = jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(P(bspec, None, None, None, None),
                   P(bspec, seq_axis, None, None),
@@ -82,7 +81,7 @@ def flash_decode(q, k_cache, v_cache, q_positions, k_positions, *,
                   P(bspec, None),
                   P(bspec, seq_axis)),
         out_specs=P(bspec, None, None, None),
-        check_rep=False,
+        check_vma=False,
     )(qg, k_cache, v_cache, q_positions, k_positions)
     Dv = v_cache.shape[-1]
     return out.reshape(B, 1, H, Dv)
@@ -133,11 +132,11 @@ def flash_decode_mla(q_lat, q_rope, c_cache, kr_cache, q_positions,
         return (o_sum / denom[..., None])[:, None].astype(q_lat.dtype)
 
     b = batch_axis
-    return shard_map(
+    return jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(P(b, None, None, None), P(b, None, None, None),
                   P(b, seq_axis, None), P(b, seq_axis, None),
                   P(b, None), P(b, seq_axis)),
         out_specs=P(b, None, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q_lat, q_rope, c_cache, kr_cache, q_positions, k_positions)

@@ -75,9 +75,11 @@ from typing import Callable, Deque, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ModelConfig
 from repro.models import transformer as T
+from repro.models.layers import paged_kernel_serves
 from repro.serving import kvcache as KV
 from repro.serving import paged_kv as PK
 from repro.serving import sampling as SMP
@@ -143,6 +145,16 @@ class _ChunkSpec:
 # compile cache is shared across Engine instances — restarting an engine,
 # or running dense and paged engines side by side (benchmarks, parity
 # tests), never recompiles an already-seen step shape.
+
+# The paged decode step rounds each bf16 op exactly as written. By default
+# XLA may keep fused bf16 intermediates in f32, so results depend on how a
+# program happens to fuse: on a TPU v5e the unrolled per-layer step of a
+# replication plan then differed from the scanned step by ~1e-2 relative
+# in the logits, enough to change sampled tokens mid-stream. With exact
+# rounding the scanned, unrolled and batch-sharded steps give identical
+# logits, so apply_plan keeps streams token-identical. The step is the one
+# program a plan recompiles; the others keep XLA's default.
+STEP_COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "window"))
@@ -233,8 +245,7 @@ def _paged_step_impl(params, k, v, tables, lengths, active, tokens, temps,
         # tuple unrolls the stack with one batch-sharding constraint per
         # layer — a plan change recompiles exactly this step, nothing else
         from repro.core import replication as R
-        hook = R.layer_hook_from_degrees(degrees,
-                                         R.default_replication_mesh())
+        hook = R.LayerHook(degrees, R.default_replication_mesh())
     logits, nc, _ = T.forward_paged(params, cfg, tokens[:, None], handle,
                                     window=window, attn_impl=impl,
                                     interpret=interp, layer_hook=hook)
@@ -258,7 +269,8 @@ def _jitted_steps():
     paged = jax.jit(_paged_step_impl,
                     static_argnames=("cfg", "window", "impl", "interp",
                                      "stochastic", "max_top_k", "degrees"),
-                    donate_argnums=(1, 2) if can_donate else ())
+                    donate_argnums=(1, 2) if can_donate else (),
+                    compiler_options=STEP_COMPILER_OPTIONS)
     return dense, paged
 
 
@@ -278,12 +290,18 @@ class Engine:
                  prefill_chunk: int = 0,
                  cache_kind: str = "dense", block_size: int = 16,
                  n_blocks: Optional[int] = None,
-                 paged_attn_impl: str = "gather", interpret: bool = False,
+                 paged_attn_impl: str = "auto", interpret: bool = False,
                  prefix_sharing: Optional[bool] = None,
-                 scheduler: Optional[str] = None, token_budget: int = 128):
+                 scheduler: Optional[str] = None, token_budget: int = 128,
+                 device: Optional[jax.Device] = None):
         assert cache_kind in ("dense", "paged"), cache_kind
         self.cfg = cfg
-        self.params = params
+        # ``device`` pins this engine's weights and KV pool to one device
+        # (one instance per chip on a multi-chip host); None leaves them
+        # where JAX puts them by default
+        self.device = device
+        self.params = (params if device is None
+                       else jax.device_put(params, device))
         self.max_batch = max_batch
         # dense SWA ring-buffers down to the window; the PAGED path keeps
         # the logical length (block-table columns are absolute positions)
@@ -340,6 +358,9 @@ class Engine:
                                         block_size=block_size, dtype=dtype,
                                         max_len=self.max_len,
                                         prefix_cache=self.prefix_sharing)
+            if device is not None:
+                self.pstate.k = jax.device_put(self.pstate.k, device)
+                self.pstate.v = jax.device_put(self.pstate.v, device)
             self.cache = None
         else:
             self.cache = T.init_cache(cfg, max_batch, self.max_len, dtype)
@@ -363,7 +384,23 @@ class Engine:
         self.token_budget = token_budget if self.sched.budgeted else 0
         self.last_step_packed: Optional[int] = None  # telemetry, per step
 
-        self._paged_impl = paged_attn_impl
+        # decode attention over the pool, chosen ONCE here: "auto" takes
+        # the Pallas kernel on a TPU wherever it computes this model's
+        # attention (no softcap, no window) and the jnp gather otherwise;
+        # an explicit "kernel" the kernel cannot serve is refused
+        can_kernel = paged_kernel_serves(cfg, self.window)
+        if paged_attn_impl == "auto":
+            paged_attn_impl = ("kernel" if can_kernel
+                               and jax.default_backend() == "tpu"
+                               else "gather")
+        if paged_attn_impl not in ("kernel", "gather"):
+            raise ValueError(f"paged_attn_impl must be auto|kernel|gather, "
+                             f"got {paged_attn_impl!r}")
+        if paged_attn_impl == "kernel" and not can_kernel:
+            raise ValueError(
+                f"paged_attn_impl='kernel' cannot serve {cfg.name}: the "
+                f"kernel has no logit softcap or sliding window")
+        self.paged_attn_impl = paged_attn_impl
         self._interpret = interpret
         # optional observe.EngineSpanRecorder: lifecycle span hooks
         # (queue / prefill chunks / first token / decode / finish).
@@ -373,6 +410,7 @@ class Engine:
         # live module-scaling state (Engine.apply_plan)
         self.replication_degrees: Optional[tuple] = None  # plan intent
         self._step_degrees: Optional[tuple] = None        # quantized/static
+        self._replicated = False    # weights + pool spread over the mesh
         self._prefill_shapes = set()  # (G, S) executables admitted so far
 
     # ------------------------------------------------------------- sampling
@@ -1140,22 +1178,10 @@ class Engine:
             st = self.pstate
             pre_lengths = st.lengths.copy()
             bs = st.block_size
-            # power-of-2 bucket of the widest needed table prefix: decode
-            # cost tracks the true max context, with O(log) recompiles.
-            # Derived from LENGTHS (col of the incoming write), not from
-            # block counts — window-freed rows have leading holes.
-            need = (int(st.lengths[active_mask].max()) // bs + 1) if \
-                active_mask.any() else 1
-            nb = min(_pow2_at_least(max(need, 1)),
-                     st.block_tables.shape[1])
-            tables = np.ascontiguousarray(st.block_tables[:, :nb])
-            toks_dev, st.k, st.v = _jitted_steps()[1](
-                self.params, st.k, st.v, tables,
-                st.lengths.astype(np.int32), active_mask, tokens,
-                temps, topks, seeds, ctrs, cfg=self.cfg,
-                window=self.window, impl=self._paged_impl,
-                interp=self._interpret, stochastic=stoch,
-                max_top_k=max_top_k, degrees=self._step_degrees)
+            args, kw = self._paged_step_args(tokens, active_mask, temps,
+                                             topks, seeds, ctrs, stoch,
+                                             max_top_k)
+            toks_dev, st.k, st.v = _jitted_steps()[1](*args, **kw)
             toks = jax.device_get(toks_dev)     # the ONE host sync
             st.lengths[active_mask] += 1
             # dirty-set bookkeeping for overlapped migration: the fused
@@ -1192,6 +1218,28 @@ class Engine:
                 finished.append(req)
                 self._retire(slot)
         return finished
+
+    def _paged_step_args(self, tokens, active_mask, temps, topks, seeds,
+                         ctrs, stoch: bool, max_top_k: int):
+        """Arguments and static options of one fused paged decode step
+        (``_jitted_steps()[1](*args, **kw)``)."""
+        st = self.pstate
+        bs = st.block_size
+        # power-of-2 bucket of the widest needed table prefix: decode
+        # cost tracks the true max context, with O(log) recompiles.
+        # Derived from LENGTHS (col of the incoming write), not from
+        # block counts — window-freed rows have leading holes.
+        need = (int(st.lengths[active_mask].max()) // bs + 1) if \
+            active_mask.any() else 1
+        nb = min(_pow2_at_least(max(need, 1)), st.block_tables.shape[1])
+        tables = np.ascontiguousarray(st.block_tables[:, :nb])
+        args = (self.params, st.k, st.v, tables, st.lengths.astype(np.int32),
+                active_mask, tokens, temps, topks, seeds, ctrs)
+        kw = dict(cfg=self.cfg, window=self.window,
+                  impl=self.paged_attn_impl, interp=self._interpret,
+                  stochastic=stoch, max_top_k=max_top_k,
+                  degrees=self._step_degrees)
+        return args, kw
 
     def _retire(self, slot: int):
         del self.active[slot]
@@ -1265,11 +1313,30 @@ class Engine:
             raise ValueError(f"plan covers {len(p)} layers, "
                              f"model has {self.cfg.num_layers}")
         self.replication_degrees = p
+        mesh = R.default_replication_mesh()
         if all(d == 1 for d in p):
             self._step_degrees = None
+            if self._replicated:
+                # replicas released: weights and pool go back home
+                self._place(self.device if self.device is not None
+                            else jax.devices()[0])
+                self._replicated = False
         else:
-            mesh_n = R.default_replication_mesh().devices.size
-            self._step_degrees = tuple(R.quantize_degrees(list(p), mesh_n))
+            self._step_degrees = tuple(
+                R.quantize_degrees(list(p), mesh.devices.size))
+            if mesh.devices.size > 1 and not self._replicated:
+                # every replica holds a copy of the weights (and the pool
+                # the step reads and writes), so the replicated layers
+                # never ship them per step
+                self._place(NamedSharding(mesh, PartitionSpec()))
+                self._replicated = True
+
+    def _place(self, where):
+        """Move the weights and the KV pool to ``where`` (a device or a
+        sharding)."""
+        self.params = jax.device_put(self.params, where)
+        self.pstate.k = jax.device_put(self.pstate.k, where)
+        self.pstate.v = jax.device_put(self.pstate.v, where)
 
     # --------------------------------------- request migration (paged)
     def pause_request(self, slot: int,

@@ -89,6 +89,7 @@ import asyncio
 import dataclasses
 import itertools
 import json
+import logging
 import queue
 import threading
 import time
@@ -100,6 +101,8 @@ from repro.serving import observe as OBS
 from repro.serving.engine import Request
 from repro.serving.instrument import IngressCounters
 from repro.serving.request import RequestSpec, SamplingParams, SpecError
+
+log = logging.getLogger(__name__)
 
 
 def byte_tokens(text: str, vocab_size: int) -> np.ndarray:
@@ -249,6 +252,12 @@ class Ingress:
         self._pump_thread: Optional[threading.Thread] = None
         self._pump_error: Optional[BaseException] = None
 
+    @property
+    def pump_error(self) -> Optional[BaseException]:
+        """What killed the pump thread, or None while it runs (or after
+        a clean stop). A dead pump serves nothing: callers check this."""
+        return self._pump_error
+
     # ---------------------------------------------------------- lifecycle
     def start(self) -> "Ingress":
         self._http_thread = threading.Thread(
@@ -271,8 +280,8 @@ class Ingress:
             fut = asyncio.run_coroutine_threadsafe(self._shutdown(),
                                                    self._loop)
             fut.result(timeout=10)
-        except Exception:
-            pass
+        except Exception:  # noqa: BLE001 — shutdown goes on regardless
+            log.exception("ingress shutdown did not complete cleanly")
         self._stop.set()
         if self._pump_thread is not None:
             self._pump_thread.join(timeout=10)
@@ -284,8 +293,7 @@ class Ingress:
 
     async def _shutdown(self):
         if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
+            self._server.close()           # stop accepting
         with self._lock:
             sessions = list(self._sessions.values())
             self._sessions.clear()
@@ -293,6 +301,10 @@ class Ingress:
             self.counters.aborted_streams += 1
             s.events.put_nowait(("abort", "shutting down"))
         await asyncio.sleep(0.05)          # let handlers flush their tails
+        if self._server is not None:
+            # waits for every open connection to close, so it comes after
+            # the aborts that let in-flight streams end
+            await self._server.wait_closed()
 
     def _run_loop(self):
         loop = asyncio.new_event_loop()
@@ -320,10 +332,10 @@ class Ingress:
     def _pump(self):
         """The ONLY thread that touches orchestrator serving ops."""
         o = self.orch
-        self.last_snapshot = o.snapshot()
-        self._metrics_mirror = self._build_mirror()
-        t_snap = t_ctl = time.monotonic()
         try:
+            self.last_snapshot = o.snapshot()
+            self._metrics_mirror = self._build_mirror()
+            t_snap = t_ctl = time.monotonic()
             while not self._stop.is_set():
                 if self.hold_pump.is_set():
                     time.sleep(0.002)

@@ -61,6 +61,8 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+import jax
+
 from repro.configs.base import ModelConfig
 from repro.core import migration as MIG
 from repro.core.cluster import Cluster, Device, layer_weight_bytes
@@ -167,13 +169,18 @@ class Orchestrator:
                             **engine_kw)
                 for _ in range(n_instances)]
         else:
+            # in-process instances go round-robin over the local devices
+            # (one chip each on a multi-chip host)
+            devs = jax.local_devices()
             self.instances = [
                 LocalInstance(Engine(cfg, params, max_batch=max_batch,
                                      max_len=max_len, dtype=dtype,
                                      cache_kind="paged",
                                      block_size=block_size,
-                                     n_blocks=n_blocks, **engine_kw))
-                for _ in range(n_instances)]
+                                     n_blocks=n_blocks,
+                                     device=devs[i % len(devs)],
+                                     **engine_kw))
+                for i in range(n_instances)]
         assert self.instances, "need at least one instance"
         n_instances = len(self.instances)
         self.telemetry = [h.telemetry for h in self.instances]

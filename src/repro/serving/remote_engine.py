@@ -147,6 +147,7 @@ class EngineServer:
                 "preempt_count": e.preempt_count,
                 "prefix_stats": e.prefix_stats(),
                 "block_size": e.block_size,
+                "paged_attn_impl": e.paged_attn_impl,
                 # sorted list (sets aren't msgpack-able); the proxy
                 # rebuilds the set on read
                 "prefix_keys": sorted(e.prefix_keys())}
@@ -278,6 +279,21 @@ class _PendingStage:
             raise
 
 
+def refuse_spawn_on_accelerator(what: str):
+    """Spawned engine servers each need the accelerator, but a chip
+    belongs to one process at a time and this one (which builds the
+    orchestrator and the weights) already holds it: a spawned child
+    would fail on the device lock or hang. Refuse, before anything is
+    spawned, on any backend but the CPU."""
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{what} spawns engine-server processes, but this process "
+            f"holds the {backend} device they would need; serve the "
+            f"instances in-process with --instances N instead")
+
+
 def rendezvous_transport() -> str:
     """Transport family for spawned proxies with no explicit endpoint:
     ``REPRO_RPC_TRANSPORT=tcp`` lifts the whole plane onto loopback TCP
@@ -335,6 +351,8 @@ class EngineProxy(InstanceHandle):
         import jax
         import numpy as np
 
+        if endpoint is None or spawn:
+            refuse_spawn_on_accelerator("a spawned engine server")
         ctx = mp.get_context("spawn")     # never fork a live JAX runtime
         if endpoint is None:
             # dial-back rendezvous: parent listens, spawned child connects

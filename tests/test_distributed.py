@@ -134,10 +134,12 @@ def test_sharded_train_step_runs():
     from repro.configs import get_config
     from repro.models import transformer as T
     from repro.parallel import sharding as SH
+    from jax.sharding import AxisType
     from repro.training import optimizer as OPT, train as TR
 
     cfg = get_config('qwen2-moe-a2.7b').reduced()
-    mesh = jax.make_mesh((4, 2), ('data', 'model'))
+    mesh = jax.make_mesh((4, 2), ('data', 'model'),
+                         axis_types=(AxisType.Auto,) * 2)
     rules = SH.rules_for(cfg, mesh)
     params = T.init_params(cfg, jax.random.PRNGKey(0), 'float32')
     specs = SH.param_specs(cfg, params, rules, mesh)
@@ -163,7 +165,9 @@ def test_flash_decode_matches_reference():
     import jax, jax.numpy as jnp, numpy as np
     from repro.parallel.distributed_attention import flash_decode
     from repro.kernels.ref import ref_decode_attention
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     B, H, KV, M, D = 4, 8, 2, 64, 32
     q = jax.random.normal(jax.random.PRNGKey(0), (B,1,H,D), jnp.float32)
     kc = jax.random.normal(jax.random.PRNGKey(1), (B,M,KV,D), jnp.float32)
@@ -192,10 +196,12 @@ def test_moe_expert_parallel_matches_dense():
     from repro.configs import get_config
     from repro.models import moe as MOE
     from repro.parallel import sharding as SH
+    from jax.sharding import AxisType
     cfg = dataclasses.replace(get_config('qwen2-moe-a2.7b').reduced(),
                               num_experts=16, num_experts_per_tok=2,
                               num_shared_experts=0)
-    mesh = jax.make_mesh((4, 2), ('data', 'model'))
+    mesh = jax.make_mesh((4, 2), ('data', 'model'),
+                         axis_types=(AxisType.Auto,) * 2)
     rules = SH.rules_for(cfg, mesh); rules['mesh'] = mesh
     p = MOE.init_moe(cfg, jax.random.PRNGKey(0), jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model),
@@ -232,6 +238,7 @@ def test_mla_flash_decode_matches_reference():
     from repro.configs import get_config
     from repro.models import transformer as T
     from repro.parallel import sharding as SH
+    from jax.sharding import AxisType
     cfg = get_config('minicpm3-4b').reduced()
     params = T.init_params(cfg, jax.random.PRNGKey(0), 'float32')
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 12), 0,
@@ -241,7 +248,8 @@ def test_mla_flash_decode_matches_reference():
     pos = jnp.full((4, 1), 12, jnp.int32)
     ref, _, _ = T.forward(params, cfg, tokens[:, :1], positions=pos,
                           mode='decode', cache=cache)
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
+    mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                         axis_types=(AxisType.Auto,) * 2)
     rules = SH.rules_for(cfg, mesh)
     rules.update(mesh=mesh, flash_decode=True, cache_seq='model')
     with mesh, SH.use_rules(rules):

@@ -284,6 +284,9 @@ def test_graceful_shutdown_mid_stream(tiny):
     orch = Orchestrator(cfg, params, n_instances=1, max_batch=2,
                         max_len=64, block_size=8)
     ing = Ingress(orch).start()
+    # hold the pump: the request is accepted and its stream opened, but
+    # it can never finish, so it is provably in flight when close() runs
+    ing.hold_pump.set()
     s = _connect(ing)
     _send(s, "POST", "/v1/completions",
           body={"prompt": "long running stream", "max_tokens": 256,

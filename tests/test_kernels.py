@@ -94,14 +94,14 @@ def test_ops_wrappers_pad_paths():
     q = jax.random.normal(K[0], (B, S, H, D))
     k = jax.random.normal(K[1], (B, S, KV, D))
     v = jax.random.normal(K[2], (B, S, KV, D))
-    out = ops.flash_attention_bshd(q, k, v)
+    out = ops.flash_attention_bshd(q, k, v, interpret=True)
     ref = ref_flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                               v.transpose(0, 2, 1, 3)).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=3e-5, atol=3e-5)
     lens = jnp.array([150, 99], jnp.int32)
     qd = jax.random.normal(K[0], (B, 1, H, D))
-    outd = ops.decode_attention_bshd(qd, k, v, lens)
+    outd = ops.decode_attention_bshd(qd, k, v, lens, interpret=True)
     refd = ref_decode_attention(qd[:, 0], k.transpose(0, 2, 1, 3),
                                 v.transpose(0, 2, 1, 3), lens)
     np.testing.assert_allclose(np.asarray(outd[:, 0]), np.asarray(refd),
