@@ -1,0 +1,18 @@
+"""Model FLOP utilization of the traced sub-window: the operations the
+model needs for the tokens the engine processed there (decode tokens
+with their attention span and logits, prefill chunk tokens with causal
+attention, one logits row per finished prompt; ``flops.py``) over the
+sub-window's length times the chip's peak bf16 rate, in percent."""
+from bench import flops as F
+
+
+def read(run):
+    if run.trace is None or run.trace_host is None:
+        return None
+    total = 0
+    for s in run.traced_steps():
+        total += sum(F.decode_token_flops(run.dm, c) for c in s.decode_ctx)
+        total += sum(F.prefill_chunk_flops(run.dm, a, n, end)
+                     for a, n, end in s.prefill)
+    span = run.trace_host[1] - run.trace_host[0]
+    return 100.0 * total / (span * run.peak["bf16_flops"])

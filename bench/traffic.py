@@ -1,0 +1,180 @@
+"""The one traffic generator: reads a mix's parameters
+(``bench/traffic/<mix>.json``) and a cell's serving knobs
+(``bench/cells/<cell>.json``) and draws every request of a run from the
+seed.
+
+Every seed gets the same work: the lengths, the arrival times and the
+primed streams' progress are drawn from the mix's own ``shape_seed``; the
+run's ``--seed`` draws the token ids, which requests are greedy and each
+sampled request's seed. So two seeds differ in content, not in how much
+there is to do or when it comes. (At the chat cell's rate a window holds
+about eleven arrivals; permuting their order by seed moved the window's
+output rate by a seventh between seeds.)
+
+Two loops:
+
+* ``open``: independent users; requests arrive at a fixed rate
+  (``rate_rps`` in the cell file) whatever the server does.
+* ``closed``: ``clients`` callers that each wait for a reply before
+  sending the next request.
+
+Set-up first sends the warm-up groups (``warmup``), then: both start
+from steady state. Set-up sends ``prime_streams`` (open; in
+the cell file) or ``clients`` (closed) streams that are already under
+way: each prompt carries the tokens already "generated" (fraction
+``(i + 0.5) / n`` of its output, in a seeded order) and asks for the
+rest, so the window opens with streams decoding at staggered context
+lengths instead of all starting together. Once every primed stream has
+its first token, a lead-in of ``lead_in_s`` seconds runs (open loop:
+arrivals drawn from a different stream of the seed) and is set-up too:
+it warms the shapes the cell's traffic uses. Then the window.
+"""
+from __future__ import annotations
+
+import json
+import pathlib
+
+import numpy as np
+
+BENCH = pathlib.Path(__file__).resolve().parent
+GREEDY = 0.0
+
+
+def load(kind: str, name: str, root: pathlib.Path = BENCH) -> dict:
+    """``kind`` is ``traffic`` or ``cells``."""
+    with open(root / kind / f"{name}.json") as f:
+        return json.load(f)
+
+
+def lengths(rng, spec: dict, n: int) -> np.ndarray:
+    """``n`` lengths, lognormal with the given median and sigma, rounded
+    and clipped to [min, max]."""
+    x = rng.lognormal(np.log(spec["median"]), spec["sigma"], size=n)
+    return np.clip(np.rint(x), spec["min"], spec["max"]).astype(np.int64)
+
+
+def _shapes(mix: dict, knobs: dict, n: int, stream: int):
+    """``n`` (prompt, output) pairs from the mix's shape seed; outputs
+    clipped so prompt + output stays under the cell's ``max_len``."""
+    rng = np.random.default_rng([mix["shape_seed"], stream])
+    p = lengths(rng, mix["prompt_len"], n)
+    o = lengths(rng, mix["output_len"], n)
+    return p, np.minimum(o, knobs["max_len"] - 2 - p)
+
+
+def _sampling(rng, mix: dict, n: int):
+    """Per request: temperature (0 = greedy, exactly ``greedy_share`` of
+    them) and a sampling seed."""
+    greedy = np.zeros(n, bool)
+    greedy[:int(round(mix["greedy_share"] * n))] = True
+    greedy = rng.permutation(greedy)
+    temps = np.where(greedy, GREEDY, mix["temperature"])
+    return temps, rng.integers(0, 2 ** 31 - 1, size=n)
+
+
+def _requests(rng, mix, knobs, vocab, p, o, phase, first_id):
+    temps, seeds = _sampling(rng, mix, len(p))
+    return [{"id": first_id + i, "phase": phase,
+             "prompt": rng.integers(2, vocab, size=int(p[i])).tolist(),
+             "max_tokens": int(o[i]),
+             "temperature": float(temps[i]), "seed": int(seeds[i])}
+            for i in range(len(p))]
+
+
+def _arrivals(mix, n: int, span: float, stream: int) -> np.ndarray:
+    """``n`` Poisson arrival times in [0, span): uniform order statistics
+    drawn from the shape seed."""
+    srng = np.random.default_rng([mix["shape_seed"], stream])
+    return np.sort(srng.uniform(0.0, span, size=n))
+
+
+def _prime(rng, mix, knobs, vocab, n: int) -> list:
+    """``n`` streams already under way: stream i carries, in its prompt,
+    the fraction ``(i + 0.5) / n`` (in an order drawn from the shape seed)
+    of its output as tokens already generated, and asks for the rest."""
+    p, o = _shapes(mix, knobs, n, 0)
+    primes = _requests(rng, mix, knobs, vocab, p, o, "prime", 0)
+    srng = np.random.default_rng([mix["shape_seed"], 5])
+    frac = srng.permutation((np.arange(n) + 0.5) / n)
+    for r, f in zip(primes, frac):
+        done = min(int(f * r["max_tokens"]),
+                   r["max_tokens"] - mix["min_remaining"])
+        if done > 0:
+            r["prompt"] += rng.integers(2, vocab, size=done).tolist()
+            r["max_tokens"] -= done
+    return primes
+
+
+def warmup(knobs: dict, vocab: int, seed: int) -> list:
+    """Groups of requests that set-up sends before anything else, one group
+    at a time, so the server compiles (or loads from the compilation
+    cache) the programs the cell's traffic will ask for, and not inside
+    the window. Prompt lengths ``k * B + r`` (B = the cell's token budget)
+    put a prompt's last prefill chunk at every power-of-two start bucket
+    (k) with every power-of-two chunk size (r) the budget allows, once
+    alone and once followed at once by a second prompt (so a step runs two
+    chunks together); lengths up to ``max_len`` also visit every decode
+    table bucket. Each asks for two tokens, greedy and sampled."""
+    B, cap = int(knobs["token_budget"]), int(knobs["max_len"]) - 4
+    rng = np.random.default_rng([int(seed), 3])
+    starts, seen = [], set()
+    for k in range(cap // B + 1):
+        bucket = 1 << max(k * B - 1, 0).bit_length() if k else 0
+        if bucket not in seen:
+            seen.add(bucket)
+            starts.append(k * B)
+    sizes = [16 << i for i in range(B.bit_length()) if 16 << i <= B]
+    groups = []
+    for pair in (False, True):
+        for a in starts:
+            for r in sizes:
+                lens = [min(a + r, cap)] + ([B // 2] if pair else [])
+                groups.append([{"prompt": rng.integers(2, vocab,
+                                                       size=n).tolist(),
+                                "max_tokens": 2, "temperature": t,
+                                "seed": int(rng.integers(0, 2 ** 31 - 1))}
+                               for n, t in zip(lens, (GREEDY, 0.8))])
+    return groups
+
+
+def generate(mix: dict, knobs: dict, vocab: int, seed: int,
+             seconds: float) -> dict:
+    """The loadgen's spec for one run (see ``loadgen.py``): the primed
+    streams, then the lead-in and window traffic."""
+    rng = np.random.default_rng([int(seed), 1])
+    spec = {"mode": mix["loop"], "window_s": float(seconds),
+            "lead_in_s": float(mix["lead_in_s"]),
+            "grace_s": float(mix.get("grace_s", 60.0)),
+            "warmup": warmup(knobs, vocab, seed)}
+    if mix["loop"] == "open":
+        primes = _prime(rng, mix, knobs, vocab, int(knobs["prime_streams"]))
+        rate = float(knobs["rate_rps"])
+        lead = float(mix["lead_in_s"])
+        n_lead = int(round(rate * lead))
+        n_win = int(round(rate * seconds))
+        lrng = np.random.default_rng([int(seed), 2])   # lead-in: own stream
+        p, o = _shapes(mix, knobs, n_lead, 2)
+        reqs = _requests(lrng, mix, knobs, vocab, p, o, "lead", len(primes))
+        for r, t in zip(reqs, _arrivals(mix, n_lead, lead, 3)):
+            r["due"] = float(t)
+        p, o = _shapes(mix, knobs, n_win, 1)
+        win = _requests(rng, mix, knobs, vocab, p, o, "window",
+                        len(primes) + n_lead)
+        for r, t in zip(win, _arrivals(mix, n_win, seconds, 4)):
+            r["due"] = float(lead + t)
+        spec["requests"] = primes + reqs + win
+        spec["primes"] = [r["id"] for r in primes]
+        return spec
+    if mix["loop"] != "closed":
+        raise ValueError(f"unknown loop {mix['loop']!r}")
+    k = int(mix["clients"])
+    per = int(mix["requests_per_client"])
+    primes = _prime(rng, mix, knobs, vocab, k)
+    p, o = _shapes(mix, knobs, k * per, 2)
+    fresh = _requests(rng, mix, knobs, vocab, p, o, "fresh", k)
+    spec["requests"] = primes + fresh
+    spec["primes"] = [r["id"] for r in primes]
+    spec["clients"] = [[primes[c]["id"]]
+                       + [fresh[c * per + j]["id"] for j in range(per)]
+                       for c in range(k)]
+    return spec
