@@ -241,8 +241,8 @@ def phase_serve(orch, cfg, args: SV.ServeConfig, *,
     hit_rate = orch.stats()["prefix_hit_rate"]
     check(hit_rate > 0, "no prefix-cache hit for the shared prompt prefix")
     long_id = outs[2]["id"]
-    chunks = sum(s["name"] == "prefill_chunk"
-                 for s in traces[long_id]["spans"])
+    chunks = sum(s["attrs"].get("chunks", 0)
+                 for s in traces[long_id]["spans"] if s["name"] == "prefill")
     check(chunks >= 2, f"the {len(bodies[2]['prompt'])}-token prompt ran "
           f"as {chunks} prefill chunk(s) under a budget of "
           f"{args.token_budget}")

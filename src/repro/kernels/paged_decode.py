@@ -122,6 +122,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, rep, D), q.dtype),
-        interpret=interpret,
+        interpret=interpret, name="paged_decode",
     )(tbl, lengths, qg, kp, vp)
     return out.reshape(B, H, D)

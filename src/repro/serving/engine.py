@@ -80,6 +80,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from repro.configs.base import ModelConfig
 from repro.models import transformer as T
 from repro.models.layers import paged_kernel_serves
+from repro.serving import instrument as INS
 from repro.serving import kvcache as KV
 from repro.serving import paged_kv as PK
 from repro.serving import sampling as SMP
@@ -383,6 +384,12 @@ class Engine:
         self.scheduler_kind = self.sched.name
         self.token_budget = token_budget if self.sched.budgeted else 0
         self.last_step_packed: Optional[int] = None  # telemetry, per step
+        # per step, reset at each: host seconds of each phase
+        # (instrument.STEP_PHASES) and programs compiled or loaded
+        self.last_step_phases: Dict[str, float] = {}
+        self.last_step_compiles = 0
+        self._step_chunks = 0      # prefill chunks run by this step
+        self._compiles = INS.compile_counter()
 
         # decode attention over the pool, chosen ONCE here: "auto" takes
         # the Pallas kernel on a TPU wherever it computes this model's
@@ -880,13 +887,14 @@ class Engine:
         return logits
 
     def _admit(self):
-        if self.cache_kind == "paged":
-            if self.sched.budgeted:
-                self._admit_budget()
-            else:
+        if self.cache_kind == "paged" and self.sched.budgeted:
+            self._admit_budget()      # times its plan, wave and chunks
+            return
+        with INS.phase(self.last_step_phases, INS.STEP_WAVE):
+            if self.cache_kind == "paged":
                 self._admit_paged()
-        else:
-            self._admit_dense()
+            else:
+                self._admit_dense()
 
     # ------------------------------------- token-budget admission (CB)
     def _admit_budget(self):
@@ -897,45 +905,50 @@ class Engine:
         grants run through ``_run_chunks``. Decode tokens were charged
         first inside ``plan``, so admission work is bounded and a long
         prompt is sliced across steps instead of stalling the batch."""
-        plan = self.sched.plan(self)
-        self.last_step_packed = plan.packed
-        cont = [g for g in plan.grants if g.slot is not None]
-        chunks = [_ChunkSpec(g.req, g.slot, g.n_tokens) for g in cont]
-        wave: List[Request] = []
-        partial = None
-        for g in plan.grants:
-            if g.slot is not None:
-                continue
-            if g.final:
-                # granted fresh requests need not be a queue PREFIX —
-                # class-aware policies admit out of FIFO order — so pop
-                # each one wherever it sits (raises if the policy granted
-                # something not actually queued)
-                self._queue_remove(g.req)
-                wave.append(g.req)
-            else:
-                partial = g         # stays queued for now
+        with INS.phase(self.last_step_phases, INS.STEP_PLAN):
+            plan = self.sched.plan(self)
+            self.last_step_packed = plan.packed
+            cont = [g for g in plan.grants if g.slot is not None]
+            chunks = [_ChunkSpec(g.req, g.slot, g.n_tokens) for g in cont]
+            wave: List[Request] = []
+            partial = None
+            for g in plan.grants:
+                if g.slot is not None:
+                    continue
+                if g.final:
+                    # granted fresh requests need not be a queue PREFIX —
+                    # class-aware policies admit out of FIFO order — so
+                    # pop each one wherever it sits (raises if the policy
+                    # granted something not actually queued)
+                    self._queue_remove(g.req)
+                    wave.append(g.req)
+                else:
+                    partial = g         # stays queued for now
         requeued = False
-        if wave:
-            admitted = self._admit_paged(wave)
-            requeued = len(admitted) < len(wave)
-        if partial is not None and not requeued:
-            # pool pressure on the wave means the partial (younger) grant
-            # would only add pressure — leave it queued, FIFO intact
-            spec = self._begin_chunked(partial.req, partial.n_tokens)
-            if spec is not None:
-                chunks.append(spec)
-        ran = self._run_chunks(chunks) if chunks else 0
-        # forward-progress guard: nothing decoding, nothing admitted,
-        # every chunk blocked on the pool -> the prefilling slots are
-        # starving each other; preempt the youngest so the oldest can
-        # finish (never-fits rejection guarantees a lone prefill fits)
-        if (not self.active and not ran and not wave
-                and len(self.prefilling) > 1):
-            victims = [s for s in self._admit_order
-                       if s in self.prefilling]
-            if len(victims) > 1:
-                self._preempt(victims[-1])
+        with INS.phase(self.last_step_phases, INS.STEP_WAVE):
+            if wave:
+                admitted = self._admit_paged(wave)
+                requeued = len(admitted) < len(wave)
+        with INS.phase(self.last_step_phases, INS.STEP_CHUNKS):
+            if partial is not None and not requeued:
+                # pool pressure on the wave means the partial (younger)
+                # grant would only add pressure — leave it queued, FIFO
+                # intact
+                spec = self._begin_chunked(partial.req, partial.n_tokens)
+                if spec is not None:
+                    chunks.append(spec)
+            ran = self._run_chunks(chunks) if chunks else 0
+            self._step_chunks = ran
+            # forward-progress guard: nothing decoding, nothing admitted,
+            # every chunk blocked on the pool -> the prefilling slots are
+            # starving each other; preempt the youngest so the oldest can
+            # finish (never-fits rejection guarantees a lone prefill fits)
+            if (not self.active and not ran and not wave
+                    and len(self.prefilling) > 1):
+                victims = [s for s in self._admit_order
+                           if s in self.prefilling]
+                if len(victims) > 1:
+                    self._preempt(victims[-1])
 
     def _begin_chunked(self, req: Request, n: int) -> Optional[_ChunkSpec]:
         """Admit a WAITING request with a partial grant: claim a slot,
@@ -1041,17 +1054,13 @@ class Engine:
                                                   + sp.n]
                      for sp in padded]
         ctxs = [sp.req.prefill_pos for sp in padded]
-        t_chunk0 = self.span_hook.now() if self.span_hook else 0.0
+        if self.span_hook:
+            t = self.span_hook.now()
+            for sp in gsp:
+                self.span_hook.on_chunk(sp.req.rid, sp.n, t)
         logits = self._prefill_shared_batch(
             [sp.slot for sp in padded], toks_list, ctxs, cb, Sb,
             n_real=len(gsp))
-        if self.span_hook:
-            # one batched forward ran all chunks: they honestly share a
-            # wall window, recorded per request against its prefill span
-            t_chunk1 = self.span_hook.now()
-            for sp in gsp:
-                self.span_hook.on_chunk(sp.req.rid, sp.req.prefill_pos,
-                                        sp.n, t_chunk0, t_chunk1)
         finals = [sp for sp in gsp
                   if sp.req.prefill_pos + sp.n
                   >= self.prefill_total(sp.req)]
@@ -1149,74 +1158,100 @@ class Engine:
     def step(self, dt: float = 1.0):
         """One engine iteration: admit from queue, one fused decode+sample
         call for all active slots, retire finished requests. Exactly one
-        device→host sync (the sampled-token fetch) in steady state."""
+        device→host sync (the sampled-token fetch) in steady state. Each
+        phase (``instrument.STEP_PHASES``) is a profiler span, and its
+        host seconds land in ``last_step_phases``."""
         self.clock += dt
         self.last_step_packed = None   # set by the token-budget planner
+        self.last_step_phases = {}
+        self._step_chunks = 0
+        c0 = self._compiles.total
+        try:
+            return self._step()
+        finally:
+            self.last_step_compiles = self._compiles.total - c0
+
+    def _step(self):
         self._admit()
         finished = self._admit_finished
         self._admit_finished = []
         if self.cache_kind == "paged" and self.active:
             # may preempt: must run BEFORE the step snapshots active slots
-            self._ensure_decode_room()
+            with INS.phase(self.last_step_phases, INS.STEP_DECODE_ROOM):
+                self._ensure_decode_room()
         if not self.active:
             return finished or None
-        B = self.max_batch
-        tokens = np.zeros((B,), np.int32)
-        active_mask = np.zeros((B,), bool)
-        for slot, req in self.active.items():
-            tokens[slot] = req.generated[-1]
-            active_mask[slot] = True
-        temps, topks, seeds, ctrs = self._sampling_arrays()
-        # static flags: all-greedy batches skip the sampler's top-k +
-        # Gumbel work inside the fused step entirely, and the batch-max
-        # top_k bounds the threshold search to lax.top_k instead of a
-        # full-vocab sort (a handful of compiled variants at most)
-        stoch = bool((temps > 0).any())
-        max_top_k = int(topks.max())
-
-        if self.cache_kind == "paged":
-            st = self.pstate
-            pre_lengths = st.lengths.copy()
-            bs = st.block_size
-            args, kw = self._paged_step_args(tokens, active_mask, temps,
-                                             topks, seeds, ctrs, stoch,
-                                             max_top_k)
-            toks_dev, st.k, st.v = _jitted_steps()[1](*args, **kw)
+        paged = self.cache_kind == "paged"
+        with INS.phase(self.last_step_phases, INS.STEP_INPUTS):
+            B = self.max_batch
+            tokens = np.zeros((B,), np.int32)
+            active_mask = np.zeros((B,), bool)
+            for slot, req in self.active.items():
+                tokens[slot] = req.generated[-1]
+                active_mask[slot] = True
+            temps, topks, seeds, ctrs = self._sampling_arrays()
+            # static flags: all-greedy batches skip the sampler's top-k +
+            # Gumbel work inside the fused step entirely, and the
+            # batch-max top_k bounds the threshold search to lax.top_k
+            # instead of a full-vocab sort (a handful of compiled
+            # variants at most)
+            stoch = bool((temps > 0).any())
+            max_top_k = int(topks.max())
+            if paged:
+                st = self.pstate
+                pre_lengths = st.lengths.copy()
+                args, kw = self._paged_step_args(tokens, active_mask, temps,
+                                                 topks, seeds, ctrs, stoch,
+                                                 max_top_k)
+                shape = {"bucket": args[3].shape[1],
+                         "chunks": self._step_chunks}
+            else:
+                pre_lengths = self._host_lengths.copy()
+                positions = pre_lengths[:, None].astype(np.int32)
+                shape = {}
+        with INS.phase(self.last_step_phases, INS.STEP_DISPATCH,
+                       step=self._step_count, rows=len(self.active),
+                       **shape):
+            if paged:
+                toks_dev, st.k, st.v = _jitted_steps()[1](*args, **kw)
+            else:
+                toks_dev, self.cache = _jitted_steps()[0](
+                    self.params, self.cache, tokens[:, None],
+                    positions, temps, topks, seeds, ctrs,
+                    cfg=self.cfg, window=self.window, stochastic=stoch,
+                    max_top_k=max_top_k)
+        with INS.phase(self.last_step_phases, INS.STEP_WAIT):
             toks = jax.device_get(toks_dev)     # the ONE host sync
-            st.lengths[active_mask] += 1
-            # dirty-set bookkeeping for overlapped migration: the fused
-            # step scattered each active slot's token into the block at
-            # its pre-step write head (host arithmetic only — no sync)
-            PK.mark_written(st, [
-                int(st.block_tables[s, int(pre_lengths[s]) // bs])
-                for s in self.active])
-            if self.window:
-                for slot in self.active:
-                    PK.free_out_of_window(st, slot, self.window)
-        else:
-            pre_lengths = self._host_lengths.copy()
-            positions = pre_lengths[:, None].astype(np.int32)
-            toks_dev, self.cache = _jitted_steps()[0](
-                self.params, self.cache, tokens[:, None],
-                positions, temps, topks, seeds, ctrs,
-                cfg=self.cfg, window=self.window, stochastic=stoch,
-                max_top_k=max_top_k)
-            toks = jax.device_get(toks_dev)     # the ONE host sync
-            self._host_lengths[active_mask] += 1
-        self._step_count += 1
+        with INS.phase(self.last_step_phases, INS.STEP_BOOKKEEP):
+            if paged:
+                bs = st.block_size
+                st.lengths[active_mask] += 1
+                # dirty-set bookkeeping for overlapped migration: the
+                # fused step scattered each active slot's token into the
+                # block at its pre-step write head (host arithmetic only
+                # — no sync)
+                PK.mark_written(st, [
+                    int(st.block_tables[s, int(pre_lengths[s]) // bs])
+                    for s in self.active])
+                if self.window:
+                    for slot in self.active:
+                        PK.free_out_of_window(st, slot, self.window)
+            else:
+                self._host_lengths[active_mask] += 1
+            self._step_count += 1
 
-        for slot, req in list(self.active.items()):
-            tok = int(toks[slot])
-            req.generated.append(tok)
-            hit_eos = req.eos_id is not None and tok == req.eos_id
-            full = len(req.generated) >= req.max_new_tokens
-            over = int(pre_lengths[slot]) + 2 >= self.logical_max
-            if hit_eos or full or over:
-                req.finish_time = self.clock
-                if self.span_hook:
-                    self.span_hook.on_finish(req)
-                finished.append(req)
-                self._retire(slot)
+            for slot, req in list(self.active.items()):
+                tok = int(toks[slot])
+                req.generated.append(tok)
+                hit_eos = req.eos_id is not None and tok == req.eos_id
+                full = len(req.generated) >= req.max_new_tokens
+                over = int(pre_lengths[slot]) + 2 >= self.logical_max
+                if hit_eos or full or over:
+                    req.finish_time = self.clock
+                    if self.span_hook:
+                        self.span_hook.on_finish(req)
+                    finished.append(req)
+                    self._retire(slot)
         return finished
 
     def _paged_step_args(self, tokens, active_mask, temps, topks, seeds,

@@ -34,12 +34,13 @@ serving/transport.py hand-rolls its RPC frames. Endpoints:
   fleet gauges (tok/s, budget utilization, prefix hit rate, pod size),
   per-instance queue depth / vacancy / TTFT / ITL histograms,
   per-SLO-class TTFT/ITL histograms (``slo_class`` label), the
-  in-force per-instance token budget, fault counters. Rendered from an
-  IMMUTABLE mirror the pump thread rebuilds
+  in-force per-instance token budget, fault counters, engine steps and
+  host seconds per step phase and per pump phase, programs compiled.
+  Rendered from an IMMUTABLE mirror the pump thread rebuilds
   next to ``last_snapshot`` — a scrape never touches the orchestrator.
-* ``GET /debug/flightrec`` — the orchestrator's flight-recorder ring
+* ``GET /debug/flightrec`` — the orchestrator's flight-recorder rings
   (controller votes with inputs, migrations with phase timings,
-  quarantines/respawns, routing verdicts), newest last.
+  quarantines/respawns, routing verdicts, engine steps), oldest first.
 
 **Tracing**: every accepted completion opens a trace
 (serving/observe.py); its id returns as ``X-Request-Id`` (unary header
@@ -97,6 +98,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from repro.serving import instrument as INS
 from repro.serving import observe as OBS
 from repro.serving.engine import Request
 from repro.serving.instrument import IngressCounters
@@ -224,6 +226,9 @@ class Ingress:
         self.model_id = model_id or getattr(orch.cfg, "name", None) \
             or getattr(orch.cfg, "family", "model")
         self.counters = IngressCounters()
+        # cumulative host seconds of the pump's own phases
+        # (instrument.PUMP_*), written by the pump thread only
+        self.phase_seconds: Dict[str, float] = {}
         self.last_snapshot = None          # refreshed by the pump
         # request tracing: adopt the orchestrator's tracer (a test may
         # have installed one) or own a fresh one; trace_out appends one
@@ -350,11 +355,13 @@ class Ingress:
                 if self.governor is not None:
                     self.governor.tick(now)
                 if now - t_snap > 0.2 or moved:
-                    self.last_snapshot = o.snapshot()
-                    # one plain-data mirror per refresh; /metrics (HTTP
-                    # thread) renders whichever mirror it observes — it
-                    # never reads handles or telemetry deques itself
-                    self._metrics_mirror = self._build_mirror()
+                    with INS.phase(self.phase_seconds, INS.PUMP_MIRROR):
+                        self.last_snapshot = o.snapshot()
+                        # one plain-data mirror per refresh; /metrics
+                        # (HTTP thread) renders whichever mirror it
+                        # observes — it never reads handles or telemetry
+                        # deques itself
+                        self._metrics_mirror = self._build_mirror()
                     t_snap = now
                 if not moved:
                     # step() carries the control ticks under load; while
@@ -377,34 +384,36 @@ class Ingress:
 
     def _drain_submissions(self) -> bool:
         moved = False
-        while True:
-            try:
-                idx, spec = self._submit_q.get_nowait()
-            except queue.Empty:
-                return moved
-            self.orch.submit_to(idx, spec)
-            with self._lock:
-                n = self._pending.get(idx, 0) - 1
-                if n > 0:
-                    self._pending[idx] = n
-                else:
-                    self._pending.pop(idx, None)
-            moved = True
+        with INS.phase(self.phase_seconds, INS.PUMP_SUBMIT):
+            while True:
+                try:
+                    idx, spec = self._submit_q.get_nowait()
+                except queue.Empty:
+                    return moved
+                self.orch.submit_to(idx, spec)
+                with self._lock:
+                    n = self._pending.get(idx, 0) - 1
+                    if n > 0:
+                        self._pending[idx] = n
+                    else:
+                        self._pending.pop(idx, None)
+                moved = True
 
     def _post(self, sess: _Session, event):
         """Thread-safe event push into a session's asyncio queue."""
         self._loop.call_soon_threadsafe(sess.events.put_nowait, event)
 
     def _push_streams(self):
-        for rid, toks in self.orch.stream_view().items():
-            with self._lock:
-                sess = self._sessions.get(rid)
-            if sess is None or len(toks) <= sess.sent:
-                continue
-            for t in toks[sess.sent:]:
-                self._post(sess, ("tok", int(t)))
-            self.counters.tokens_out += len(toks) - sess.sent
-            sess.sent = len(toks)
+        with INS.phase(self.phase_seconds, INS.PUMP_PUSH):
+            for rid, toks in self.orch.stream_view().items():
+                with self._lock:
+                    sess = self._sessions.get(rid)
+                if sess is None or len(toks) <= sess.sent:
+                    continue
+                for t in toks[sess.sent:]:
+                    self._post(sess, ("tok", int(t)))
+                self.counters.tokens_out += len(toks) - sess.sent
+                sess.sent = len(toks)
 
     def _finish(self, req: Request):
         with self._lock:
@@ -556,6 +565,9 @@ class Ingress:
                 "ttfts": list(tel.ttfts),
                 "itls": list(tel.step_seconds),
                 "token_budget": tel.budget,
+                "steps_total": tel.steps_total,
+                "phase_seconds": dict(tel.phase_seconds_total),
+                "compiles_total": tel.compiles_total,
                 "class_ttfts": {c: list(d)
                                 for c, d in tel.class_ttfts.items()},
                 "class_itls": {c: list(d)
@@ -571,6 +583,7 @@ class Ingress:
                        "quarantines": o.faults.quarantines,
                        "respawns": o.faults.respawns,
                        "evictions": o.faults.evictions},
+            "pump_phases": {**self.phase_seconds, **o.phase_seconds},
         }
 
     def _render_metrics(self) -> str:
@@ -639,6 +652,20 @@ class Ingress:
                           "Per-step token budget in force (0 = phase "
                           "scheduler, nothing to govern).",
                           e["token_budget"], labels=lab)
+                reg.counter("repro_engine_steps_total",
+                            "Engine steps run by the instance.",
+                            e["steps_total"], labels=lab)
+                reg.counter("repro_engine_compiles_total",
+                            "Programs compiled or loaded from the "
+                            "persistent compilation cache while the "
+                            "instance's steps ran, in its own process.",
+                            e["compiles_total"], labels=lab)
+                for ph, v in sorted(e["phase_seconds"].items()):
+                    reg.counter("repro_step_phase_seconds_total",
+                                "Host seconds of engine steps, by phase "
+                                "(step/wait is the device's share).", v,
+                                labels={"instance": str(e["idx"]),
+                                        "phase": ph})
                 for cls in sorted(e["class_ttfts"]):
                     reg.histogram(
                         "repro_class_ttft_steps",
@@ -655,6 +682,22 @@ class Ingress:
                         e["class_itls"][cls], self._CLASS_ITL_BUCKETS,
                         labels={"instance": str(e["idx"]),
                                 "slo_class": cls})
+            for ph, v in sorted(m["pump_phases"].items()):
+                reg.counter("repro_pump_phase_seconds_total",
+                            "Host seconds of the ingress pump's and the "
+                            "orchestrator's own work between engine "
+                            "steps, by phase.", v, labels={"phase": ph})
+        comp = INS.compile_counter().state()
+        for prog, n in sorted(comp["by_program"].items()):
+            reg.counter("repro_compiles_total",
+                        "Programs compiled or loaded from the persistent "
+                        "compilation cache in the ingress process, by "
+                        "program.", n,
+                        labels={"program": prog})
+        reg.counter("repro_compile_seconds_total",
+                    "Seconds spent compiling or loading programs in "
+                    "the ingress process.",
+                    comp["seconds"])
         if self.governor is not None:
             reg.counter("repro_budget_adjustments_total",
                         "Token-budget retargets applied by the "
@@ -666,8 +709,8 @@ class Ingress:
                     "Spans that arrived for unknown/finished traces.",
                     self.tracer.dropped_spans)
         reg.gauge("repro_flightrec_events",
-                  "Control-plane events recorded since start.",
-                  self.orch.flightrec.dump()["recorded"])
+                  "Flight-recorder events recorded since start.",
+                  self.orch.flightrec.recorded)
         return reg.render()
 
     def _stats(self) -> dict:

@@ -13,17 +13,110 @@ the orchestrator records every engine step (wall seconds, tokens) and
 every finished request (engine-clock latency) here, and turns the rolling
 windows into ``core.monitor.MetricsSnapshot``s — the paper's NVML+timer
 feed, replaced by real engine counters instead of synthetic traces.
+
+``phase`` times one named phase of the host loop (``Engine.step``, the
+ingress pump, ``Orchestrator.step``): a ``jax.profiler.TraceAnnotation``
+of a fixed name puts it on the profiler's clock beside the device ops,
+and its host seconds add into the owner's record. ``compile_counter()``
+is the process-wide count of programs compiled or loaded from the
+persistent compilation cache, by program, from ``jax.monitoring``.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import threading
+import time
 from collections import deque
-from typing import Deque, Iterable, Optional
+from typing import Deque, Dict, Iterable, Optional
 
 import jax
 import numpy as np
+
+
+# Phase span names. The step phases tile ``Engine.step``: admission
+# (plan, whole-prompt wave, prefill chunks), pool room for the next
+# token, host inputs, the jitted call until it returns, the blocking
+# fetch of the sampled tokens, and the bookkeeping after it.
+STEP_PLAN = "step/plan"
+STEP_WAVE = "step/wave"
+STEP_CHUNKS = "step/chunks"
+STEP_DECODE_ROOM = "step/decode_room"
+STEP_INPUTS = "step/inputs"
+STEP_DISPATCH = "step/dispatch"
+STEP_WAIT = "step/wait"
+STEP_BOOKKEEP = "step/bookkeep"
+STEP_PHASES = (STEP_PLAN, STEP_WAVE, STEP_CHUNKS, STEP_DECODE_ROOM,
+               STEP_INPUTS, STEP_DISPATCH, STEP_WAIT, STEP_BOOKKEEP)
+# the host loop between steps: the ingress pump's own work, and the
+# orchestrator's around the engine steps
+PUMP_SUBMIT = "pump/submit"
+PUMP_PUSH = "pump/push"
+PUMP_MIRROR = "pump/mirror"
+ORCH_STREAMS = "orch/streams"
+ORCH_SPANS = "orch/spans"
+ORCH_CONTROL = "orch/control"
+
+
+@contextlib.contextmanager
+def phase(record: Dict[str, float], name: str, **attrs):
+    """Time one phase: a ``TraceAnnotation`` of ``name`` (with ``attrs``
+    as its arguments) while the block runs, and the block's host seconds
+    added into ``record[name]``. Without an active profiler session the
+    annotation is a few microseconds."""
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(name, **attrs):
+            yield
+    finally:
+        record[name] = record.get(name, 0.0) + time.perf_counter() - t0
+
+
+class CompileCounter:
+    """Programs compiled, or loaded from the persistent compilation cache,
+    in this process: one ``/jax/core/compile/backend_compile_duration``
+    event each (JAX times the cache lookup and the compile together, so a
+    cache load is one event too), counted by ``fun_name`` with the
+    seconds they took. Listeners may run on any thread that compiles."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.total = 0
+        self.seconds = 0.0
+        self.by_program: Dict[str, int] = {}
+
+    def _on_event(self, event: str, duration: float, **kw):
+        if event != self.EVENT:
+            return
+        name = str(kw.get("fun_name", "?"))
+        with self._lock:
+            self.total += 1
+            self.seconds += duration
+            self.by_program[name] = self.by_program.get(name, 0) + 1
+
+    def state(self) -> dict:
+        """A consistent copy: total, seconds, and the count per program."""
+        with self._lock:
+            return {"total": self.total, "seconds": self.seconds,
+                    "by_program": dict(self.by_program)}
+
+
+_compiles: Optional[CompileCounter] = None
+_compiles_lock = threading.Lock()
+
+
+def compile_counter() -> CompileCounter:
+    """The process-wide ``CompileCounter``, listening from the first call
+    on (installed once per process)."""
+    global _compiles
+    with _compiles_lock:
+        if _compiles is None:
+            _compiles = CompileCounter()
+            jax.monitoring.register_event_duration_secs_listener(
+                _compiles._on_event)
+        return _compiles
 
 
 @dataclasses.dataclass
@@ -116,10 +209,18 @@ class EngineTelemetry:
         self.prefix_queries = 0
         self.prefix_hits = 0
         self.blocks_saved = 0
+        # monotonic totals of every step (not windows): steps, host
+        # seconds per step phase (STEP_PHASES), programs compiled or
+        # loaded while a step ran
+        self.steps_total = 0
+        self.phase_seconds_total: Dict[str, float] = {}
+        self.compiles_total = 0
 
     def record_step(self, wall_s: float, n_tokens: int,
                     packed: Optional[int] = None,
-                    budget: Optional[int] = None):
+                    budget: Optional[int] = None,
+                    phases: Optional[Dict[str, float]] = None,
+                    compiles: int = 0):
         self.step_seconds.append(wall_s)
         self.step_tokens.append(n_tokens)
         self.total_tokens += n_tokens
@@ -127,6 +228,11 @@ class EngineTelemetry:
             self.packed_tokens.append(packed)
         if budget:
             self.budget = budget
+        self.steps_total += 1
+        for k, v in (phases or {}).items():
+            self.phase_seconds_total[k] = \
+                self.phase_seconds_total.get(k, 0.0) + v
+        self.compiles_total += compiles
 
     def record_finished(self, requests: Iterable):
         w = self.step_seconds.maxlen
@@ -254,7 +360,10 @@ class EngineTelemetry:
                 "class_ttfts": {c: list(d)
                                 for c, d in self.class_ttfts.items()},
                 "class_itls": {c: list(d)
-                               for c, d in self.class_itls.items()}}
+                               for c, d in self.class_itls.items()},
+                "steps_total": self.steps_total,
+                "phase_seconds_total": dict(self.phase_seconds_total),
+                "compiles_total": self.compiles_total}
 
     def load_state(self, state: dict):
         """Overwrite this telemetry with a serialized snapshot (in place:
@@ -282,6 +391,10 @@ class EngineTelemetry:
                             in state.get("class_ttfts", {}).items()}
         self.class_itls = {c: deque(v, maxlen=w) for c, v
                            in state.get("class_itls", {}).items()}
+        self.steps_total = state.get("steps_total", 0)
+        self.phase_seconds_total = dict(state.get("phase_seconds_total",
+                                                  {}))
+        self.compiles_total = state.get("compiles_total", 0)
 
 
 def timed_step(engine, telemetry: EngineTelemetry):
@@ -290,13 +403,14 @@ def timed_step(engine, telemetry: EngineTelemetry):
     (serving/instance.LocalInstance) and the remote engine server
     (serving/remote_engine.EngineServer) so the two planes' metrics can
     never silently diverge. Returns the finished requests."""
-    import time
     t0 = time.perf_counter()
     done = engine.step() or []
     telemetry.record_step(time.perf_counter() - t0,
                           len(engine.active) + len(done),
                           packed=getattr(engine, "last_step_packed", None),
-                          budget=getattr(engine, "token_budget", 0))
+                          budget=getattr(engine, "token_budget", 0),
+                          phases=getattr(engine, "last_step_phases", None),
+                          compiles=getattr(engine, "last_step_compiles", 0))
     telemetry.record_finished(done)
     return done
 

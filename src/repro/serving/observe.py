@@ -9,10 +9,10 @@ that survives the process.
 Span model (DESIGN.md §12)
 --------------------------
 A trace is one completion's timeline; the ROOT span's id IS the trace
-id. Every other span parents either the root (accept / route / queue /
-prefill / decode / migration hops) or a locally generated engine span
-(prefill chunk -> its prefill span), so the tree is connected BY
-CONSTRUCTION — no cross-process id coordination. Timestamps are
+id. Every other span parents the root (accept / route / queue /
+prefill / decode / migration hops; the prefill span counts its chunks),
+so the tree is connected BY CONSTRUCTION — no cross-process id
+coordination. Timestamps are
 ``time.monotonic()`` seconds in the INGRESS process's clock domain:
 spans recorded inside a remote engine server are stamped with the
 server's clock (``server_now``) and shifted by the proxy's RTT-estimated
@@ -173,19 +173,20 @@ class EngineSpanRecorder:
         if req.rid in self._traces:
             self._start(req.rid, "queue", self.now())
 
-    def on_chunk(self, rid: int, start: int, n: int, t0: float, t1: float):
-        """One executed prefill chunk [start, start+n); chunks parent
-        the rid's prefill span (opened at the first chunk)."""
+    def on_chunk(self, rid: int, n: int, t0: float):
+        """One prefill chunk of ``n`` tokens, dispatched at ``t0``. The
+        chunk runs asynchronously, so its own time is not measured here:
+        the rid's prefill span (opened at the first chunk) counts
+        ``chunks`` and ``chunk_tokens``."""
         if rid not in self._traces:
             return
         self._close(rid, "queue", t0)
         pre = self._open.get(rid, {}).get("prefill")
         if pre is None:
             pre = self._start(rid, "prefill", t0)
-        chunk = make_span(self._traces[rid], "prefill_chunk", t0, t1,
-                          parent=pre["id"], origin=self.origin,
-                          attrs={"start": start, "n": n})
-        self._buf.append(chunk)
+        a = pre["attrs"]
+        a["chunks"] = a.get("chunks", 0) + 1
+        a["chunk_tokens"] = a.get("chunk_tokens", 0) + n
 
     def on_activate(self, req, fresh_first: bool):
         """Request entered decode rotation (or finished at admission).
@@ -646,39 +647,50 @@ def parse_prometheus(text: str) -> dict:
 
 # ======================================================== flight recorder
 class FlightRecorder:
-    """Bounded ring of structured control-plane events — WHY the plane
-    did what it did (controller votes with their inputs, grow/shrink,
-    migration phase timings, quarantines, respawns, routing verdicts).
-    ``GET /debug/flightrec`` serves ``dump()``; crash-recovery events
-    auto-dump to ``dump_path`` when one is configured, so a dead soak
-    still leaves evidence on disk."""
+    """Bounded rings of structured events — WHY the plane did what it did
+    (controller votes with their inputs, grow/shrink, migration phase
+    timings, quarantines, respawns, routing verdicts) and how each engine
+    step split its time. Each kind keeps its own last ``capacity``
+    events, so a frequent kind (steps, routing verdicts) never pushes a
+    rare one (a migration, a quarantine) out. ``GET /debug/flightrec``
+    serves ``dump()``; crash-recovery events auto-dump to ``dump_path``
+    when one is configured, so a dead soak still leaves evidence on
+    disk."""
 
     def __init__(self, capacity: int = 512,
                  dump_path: Optional[str] = None):
         self._lock = threading.Lock()
-        self._ring: collections.deque = collections.deque(maxlen=capacity)
+        self.capacity = capacity
+        self._rings: Dict[str, collections.deque] = {}
         self._seq = itertools.count(1)
+        self.recorded = 0             # events recorded since start
         self.dump_path = dump_path
         self.dumps = 0
 
     def record(self, kind: str, **fields) -> dict:
-        evt = dict(seq=next(self._seq), t=time.monotonic(),
-                   wall=time.time(), kind=kind, **fields)
         with self._lock:
-            self._ring.append(evt)
+            evt = dict(seq=next(self._seq), t=time.monotonic(),
+                       wall=time.time(), kind=kind, **fields)
+            ring = self._rings.get(kind)
+            if ring is None:
+                ring = self._rings[kind] = collections.deque(
+                    maxlen=self.capacity)
+            ring.append(evt)
+            self.recorded = evt["seq"]
         return evt
 
     def events(self, kind: Optional[str] = None) -> List[dict]:
+        """Events of ``kind`` (every kind when None), oldest first."""
         with self._lock:
-            evts = list(self._ring)
-        return evts if kind is None else [e for e in evts
-                                          if e["kind"] == kind]
+            if kind is not None:
+                return list(self._rings.get(kind, ()))
+            evts = [e for ring in self._rings.values() for e in ring]
+        return sorted(evts, key=lambda e: e["seq"])
 
     def dump(self) -> dict:
-        with self._lock:
-            evts = list(self._ring)
-        return {"capacity": self._ring.maxlen, "recorded": evts[-1]["seq"]
-                if evts else 0, "events": evts}
+        evts = self.events()
+        return {"capacity": self.capacity, "recorded": self.recorded,
+                "events": evts}
 
     def auto_dump(self, reason: str) -> Optional[str]:
         """Persist the ring to ``dump_path`` (overwrite: latest crash

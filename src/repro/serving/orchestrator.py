@@ -71,6 +71,7 @@ from repro.core.controller import (Controller, ControllerConfig,
 from repro.core.monitor import MetricsSnapshot, Monitor
 from repro.core.plan import PlacementPlan
 from repro.serving import faults as FLT
+from repro.serving import instrument as INS
 from repro.serving import observe as OBS
 from repro.serving import transport as TR
 from repro.serving.engine import Engine, Request
@@ -143,6 +144,9 @@ class Orchestrator:
         self.tracer = tracer
         self.flightrec = OBS.FlightRecorder(capacity=512,
                                             dump_path=flightrec_path)
+        # cumulative host seconds of this loop's own phases around the
+        # engine steps (instrument.ORCH_*)
+        self.phase_seconds: Dict[str, float] = {}
         self.slo_latency = slo_latency
         self.telemetry_every = telemetry_every
         self.link_bandwidth = link_bandwidth
@@ -424,6 +428,15 @@ class Orchestrator:
                 errors.append(val)
             else:
                 fin.extend(h.finish_step(val))
+                # every engine step in the flight recorder, with its
+                # instance's monotonic totals: two consecutive events of
+                # one instance differ by that step's phases and compiles
+                tel = h.telemetry
+                self.flightrec.record(
+                    "step", instance=i, seconds=tel.step_seconds[-1],
+                    steps_total=tel.steps_total,
+                    phase_seconds_total=dict(tel.phase_seconds_total),
+                    compiles_total=tel.compiles_total)
                 if i in self._grace and h.active_count():
                     # first step with real work done: compiles are paid,
                     # the replica now answers on normal latency — arm it
@@ -497,10 +510,13 @@ class Orchestrator:
         self.finished.extend(fin)
         self._tick += 1
         if self._tick % self.telemetry_every == 0:
-            self.control_tick()
+            with INS.phase(self.phase_seconds, INS.ORCH_CONTROL):
+                self.control_tick()
         out = self._drain_orphans() + fin
-        self._collect_streams(out)
-        self._collect_spans(out)
+        with INS.phase(self.phase_seconds, INS.ORCH_STREAMS):
+            self._collect_streams(out)
+        with INS.phase(self.phase_seconds, INS.ORCH_SPANS):
+            self._collect_spans(out)
         return out
 
     def _collect_spans(self, fin: List[Request]):

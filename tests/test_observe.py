@@ -174,11 +174,16 @@ def test_engine_span_recorder_records_only_registered_rids():
     assert rec.drain() == []
     rec.register(1, "tid")
     rec.on_submit(R)
-    rec.on_chunk(1, 0, 8, rec.now(), rec.now())
+    rec.on_chunk(1, 8, rec.now())
+    rec.on_chunk(1, 4, rec.now())
     rec.on_activate(R, fresh_first=True)
     rec.on_finish(R)
-    names = [s["name"] for s in rec.drain()]
-    assert names.count("prefill_chunk") == 1
+    spans = rec.drain()
+    names = [s["name"] for s in spans]
+    # chunks run asynchronously: counted on the prefill span, not timed
+    assert "prefill_chunk" not in names
+    pre = next(s for s in spans if s["name"] == "prefill")
+    assert pre["attrs"] == {"chunks": 2, "chunk_tokens": 12}
     assert {"queue", "prefill", "first_token", "decode"} <= set(names)
     assert rec.drain() == []         # drained means drained
 
@@ -203,6 +208,18 @@ def test_flight_recorder_ring_dump_and_auto_dump(tmp_path):
     payload = json.loads(path.read_text())
     assert payload["reason"] == "crash_recovery:test"
     assert payload["events"][0]["kind"] == "quarantine"
+
+
+def test_flight_recorder_keeps_a_ring_per_kind():
+    """A frequent kind (engine steps) never pushes a rare one out."""
+    fr = OBS.FlightRecorder(capacity=3)
+    fr.record("migration", rid=7)
+    for i in range(10):
+        fr.record("step", step=i)
+    assert [e["step"] for e in fr.events("step")] == [7, 8, 9]
+    assert [e["kind"] for e in fr.events()] == ["migration"] + ["step"] * 3
+    assert [e["seq"] for e in fr.events()] == [1, 9, 10, 11]
+    assert fr.recorded == 11 == fr.dump()["recorded"]
 
 
 # ================================================ Prometheus exposition
@@ -501,6 +518,53 @@ def test_metrics_endpoint_is_conformant_and_moves(served):
     assert counter(fams, "repro_requests_total") == before + 1
     assert counter(fams, "repro_tokens_out_total") > 0
     assert counter(fams, "repro_trace_spans_dropped_total") == 0
+
+
+def test_metrics_endpoint_exports_step_phases_and_compiles(served):
+    """The operator's view of where step time goes: steps, host seconds
+    per step phase and per pump phase, and compiles by program — every
+    family a counter that grows between two scrapes — and each
+    instance's compiles, which never shrink."""
+    orch, ing = served
+
+    def scrape():
+        _, _, text = _request(ing, "GET", "/metrics")
+        fams = OBS.parse_prometheus(text.decode())
+        return {name: {tuple(sorted(lab.items())): v
+                       for _, lab, v in fams[name]["samples"]}
+                for name in ("repro_engine_steps_total",
+                             "repro_step_phase_seconds_total",
+                             "repro_pump_phase_seconds_total",
+                             "repro_compiles_total",
+                             "repro_compile_seconds_total",
+                             "repro_engine_compiles_total")}
+
+    _request(ing, "POST", "/v1/completions",
+             body={"prompt": [4, 4, 2], "max_tokens": 3})
+    a = scrape()
+
+    def doubled_plus_two(x):
+        return x * 2 + 2
+
+    jax.jit(doubled_plus_two)(np.arange(13.0))      # one more program
+    _request(ing, "POST", "/v1/completions",
+             body={"prompt": [4, 2, 4], "max_tokens": 3})
+    b = scrape()
+    for name in a:
+        assert a[name] and set(a[name]) <= set(b[name]), name
+        assert all(b[name][k] >= v for k, v in a[name].items()), name
+    # the same shapes again: the engines need not compile anything new
+    engine_compiles = a.pop("repro_engine_compiles_total")
+    assert set(engine_compiles) == set(a["repro_engine_steps_total"])
+    for name in a:
+        assert sum(b[name].values()) > sum(a[name].values()), name
+    assert {dict(k)["phase"] for k in b["repro_step_phase_seconds_total"]} \
+        == set(INS.STEP_PHASES)
+    assert {dict(k)["phase"] for k in b["repro_pump_phase_seconds_total"]} \
+        >= {INS.PUMP_SUBMIT, INS.PUMP_PUSH, INS.PUMP_MIRROR,
+            INS.ORCH_STREAMS, INS.ORCH_SPANS}
+    assert (("program", "jit(doubled_plus_two)"),) in \
+        b["repro_compiles_total"]
 
 
 def test_flightrec_endpoint_serves_routing_verdicts(served):

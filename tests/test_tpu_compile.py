@@ -77,7 +77,10 @@ def test_paged_decode_kernel_compiles_at_full_width(one_chip, cfg):
         _spec((N_BLOCKS, KV, BS, D), jnp.bfloat16, one_chip),
         _spec((B, TABLE), jnp.int32, one_chip),
         _spec((B,), jnp.int32, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's name is its op's name, which the device trace shows
+    assert "%paged_decode" in text
 
 
 def test_paged_decode_step_fits_one_chip(one_chip, cfg):
