@@ -2,12 +2,12 @@
 decode path (serving/engine.py with ``cache_kind="paged"``).
 
 Layout: a global pool of fixed-size blocks per layer, stored
-KV-HEAD-MAJOR — ``k/v: [L, n_blocks, KV, bs, hd]`` — so each (block,
-kv-head) pair is a contiguous ``[bs, hd]`` tile. That is exactly the tile
-the Pallas decode kernel (kernels/paged_decode.py) DMAs per grid step, so
-the kernel reads the pool natively instead of transposing the whole pool
-per call (which would defeat its length-proportional HBM traffic on real
-hardware). A per-request block table ``[B, max_blocks]`` of pool indices
+KV-HEAD-MAJOR — ``k/v: [L, n_blocks, KV, bs, hd]`` — so each block's
+page ``[KV, bs, hd]`` (every kv head) is contiguous. That is exactly what
+the Pallas decode kernel (kernels/paged_decode.py) copies with one DMA per
+page, so the kernel reads the pool natively instead of transposing the
+whole pool per call (which would defeat its length-proportional HBM
+traffic on real hardware). A per-request block table ``[B, max_blocks]`` of pool indices
 (-1 = unallocated) maps absolute token position ``p`` to table column
 ``p // block_size``.
 

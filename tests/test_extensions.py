@@ -1,6 +1,9 @@
 """Production-feature extensions: paged KV, chunked prefill, sampling,
 heterogeneous scale-up, gradient accumulation, traffic traces — plus
 hypothesis property tests on attention causality."""
+import inspect
+import itertools
+
 import hypothesis.strategies as st
 import jax
 import jax.numpy as jnp
@@ -13,6 +16,7 @@ from repro.core.cluster import Cluster, Device, GB
 from repro.core.plan import PlacementPlan
 from repro.core.scale_up import scale_up_hetero
 from repro.core.speedup import SpeedupModelConfig, speedup
+from repro.kernels import paged_decode as PD
 from repro.kernels.paged_decode import paged_decode_attention
 from repro.models import transformer as T
 from repro.serving import paged_kv as PK
@@ -97,11 +101,16 @@ def _raw_state(lens, *, n_blocks=16, bs=8, KV=2, hd=64, dtype=jnp.float32):
     return state
 
 
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-5),
-                                       (jnp.bfloat16, 2e-2)])
-def test_paged_kernel_gqa_matches_ref(dtype, tol):
-    """H > KV: query heads grouped by KV head inside the kernel."""
-    H, KV, hd = 8, 2, 64
+@pytest.mark.parametrize("dtype,tol,H,KV,hd", [
+    pytest.param(jnp.float32, 3e-5, 8, 2, 64, id="float32-3e-05"),
+    pytest.param(jnp.bfloat16, 2e-2, 8, 2, 64, id="bfloat16-0.02"),
+    pytest.param(jnp.float32, 3e-5, 8, 2, 128, id="float32-hd128"),
+    pytest.param(jnp.bfloat16, 2e-2, 32, 4, 128, id="bfloat16-r8-hd128"),
+])
+def test_paged_kernel_gqa_matches_ref(dtype, tol, H, KV, hd):
+    """H > KV: query heads grouped by KV head inside the kernel (R = 4,
+    and R = 8 at Yi-1.5-9B's widths); head_dim 128 takes the chunked
+    page copies, 64 the pipelined pages."""
     state = _raw_state([20, 7, 33], KV=KV, hd=hd, dtype=dtype)
     q = jax.random.normal(KEY, (3, H, hd), jnp.float32)
     ref = PK.paged_attention_ref(q.astype(dtype), state, [0, 1, 2], layer=0)
@@ -133,6 +142,84 @@ def test_paged_kernel_ragged_block_boundaries():
         q, state.k[0], state.v[0], jnp.asarray(state.block_tables),
         jnp.asarray(lengths), interpret=True)
     assert (np.asarray(out0[1]) == 0).all()
+
+
+def _chunk_case(hd, dtype, *, H=32, KV=4, bs=16):
+    """Rows one short of, at and one past a chunk of P pages, a single key
+    and a row made inactive, over a table one column wider than the
+    longest row needs (so its width is not a multiple of P; the entries
+    past each row's length are -1)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    P = PD.chunk_pages(bs, KV, hd, itemsize, 1 << 20)
+    lens = [P * bs - 1, P * bs, P * bs + 1, 1, 5]
+    state = _raw_state(lens, n_blocks=sum(-(-n // bs) for n in lens),
+                       bs=bs, KV=KV, hd=hd, dtype=dtype)
+    width = state.block_tables.shape[1]
+    assert PD.chunk_pages(bs, KV, hd, itemsize, width) == P
+    assert width % P != 0 and (state.block_tables < 0).any()
+    q = jax.random.normal(KEY, (len(lens), H, hd), jnp.float32).astype(dtype)
+    return state, q
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_paged_kernel_chunk_boundaries(hd, dtype, tol):
+    """Lengths P·bs - 1, P·bs, P·bs + 1 and 1 match the reference, and an
+    inactive (length 0) row yields exactly zeros; head_dim 128 takes the
+    chunked page copies, 64 the pipelined pages."""
+    state, q = _chunk_case(hd, dtype)
+    n = len(state.lengths)
+    ref = PK.paged_attention_ref(q, state, range(n), layer=0)
+    lengths = state.lengths.copy()
+    lengths[-1] = 0
+    out = paged_decode_attention(
+        q, state.k[0], state.v[0], jnp.asarray(state.block_tables),
+        jnp.asarray(lengths), interpret=True)
+    np.testing.assert_allclose(np.asarray(out[:-1], np.float32),
+                               np.asarray(ref[:-1], np.float32),
+                               rtol=tol, atol=tol)
+    assert (np.asarray(out[-1], np.float32) == 0).all()
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+def test_paged_kernel_shard_invariant(hd):
+    """Each quarter of the batch, run alone (as a degree-4 replicated
+    layer maps the kernel over batch shards), gives bit for bit what the
+    whole batch gives for those rows."""
+    lens = [9, 700, 1, 513, 0, 33, 512, 40]      # row 4 inactive
+    state = _raw_state(lens[:4] + [1] + lens[5:], bs=16, KV=4, hd=hd,
+                       n_blocks=sum(-(-n // 16) for n in lens) + 1,
+                       dtype=jnp.bfloat16)
+    q = jax.random.normal(KEY, (8, 32, hd), jnp.float32).astype(jnp.bfloat16)
+    args = (jnp.asarray(state.block_tables), jnp.asarray(lens, jnp.int32))
+    full = paged_decode_attention(q, state.k[0], state.v[0], *args,
+                                  interpret=True)
+    for rows in (slice(0, 2), slice(2, 4), slice(4, 6), slice(6, 8)):
+        part = paged_decode_attention(
+            q[rows], state.k[0], state.v[0], *(a[rows] for a in args),
+            interpret=True)
+        assert np.array_equal(np.asarray(full[rows], np.float32),
+                              np.asarray(part, np.float32))
+
+
+def test_chunk_pages_from_shapes():
+    """The chunk geometry is a function of shapes alone (no batch size,
+    no option) and its double-buffered K and V fit v5e's default scoped
+    VMEM (16 MiB) with room to spare."""
+    assert list(inspect.signature(PD.chunk_pages).parameters) == [
+        "block_size", "kv_heads", "head_dim", "itemsize", "table_width"]
+    assert PD.chunk_pages(16, 4, 128, 2, 256) == 32   # Yi-1.5-9B: 512 keys
+    assert PD.chunk_pages(16, 8, 128, 2, 256) == 16   # Mistral-7B: 1 MiB
+    assert PD.chunk_pages(16, 4, 128, 2, 3) == 3      # the table's width
+    assert PD.chunk_pages(16, 4, 128, 2, 1) == 1
+    for bs, kv, hd, itemsize in itertools.product(
+            (8, 16, 32, 64, 128), (1, 2, 4, 8, 16), (64, 128, 256), (2, 4)):
+        pages = PD.chunk_pages(bs, kv, hd, itemsize, 4096)
+        page = kv * bs * hd * itemsize
+        assert pages * bs <= PD.TARGET_KEYS or pages == 1
+        assert 2 * 2 * pages * page <= max(2 * PD.CHUNK_BYTES, 4 * page)
+        assert 2 * 2 * pages * page <= 8 * 2 ** 20
 
 
 def _run_engine(cfg, params, prompts, *, max_new=6, **kw):

@@ -1,7 +1,9 @@
 """Compiles for a described TPU v5e (a 2x2 host) with no chip attached.
 
 The serving path's kernels and step programs at TinyLlama-1.1B's published
-widths, compiled by the TPU compiler from shapes alone: what the chip's
+widths, and the paged decode kernel at the benchmark cell's (Yi-1.5-9B's
+attention: head_dim 128, the chunked page copies), compiled by the TPU
+compiler from shapes alone: what the chip's
 compiler would refuse (tiling, fast-memory limits, unpartitionable kernels,
 programs that do not fit the device) fails here at no chip time. Depth is
 cut to 2 layers to keep each compile near two seconds. Nothing runs, so
@@ -83,6 +85,20 @@ def test_paged_decode_kernel_compiles_at_full_width(one_chip, cfg):
     assert "%paged_decode" in text
 
 
+def test_paged_decode_kernel_compiles_at_cell_widths(one_chip):
+    """Yi-1.5-9B's attention at the benchmark cell's shapes: 32 query and
+    4 KV heads of 128, 64 rows over a 256-column table of 16-token blocks
+    — the chunked page copies (head_dim a multiple of 128 lanes)."""
+    B, H, KV, D, bs, table, n_blocks = 64, 32, 4, 128, 16, 256, 8800
+    pool = _spec((n_blocks, KV, bs, D), jnp.bfloat16, one_chip)
+    text = jax.jit(paged_decode_attention).lower(
+        _spec((B, H, D), jnp.bfloat16, one_chip), pool, pool,
+        _spec((B, table), jnp.int32, one_chip),
+        _spec((B,), jnp.int32, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "%paged_decode" in text
+
+
 def test_paged_decode_step_fits_one_chip(one_chip, cfg):
     """The engine's fused decode + sample step, with the kernel."""
     step = jax.jit(E._paged_step_impl,
@@ -157,3 +173,24 @@ def test_replicated_decode_step_compiles(topo, cfg, n_chips):
         assert not collectives, collectives
     else:
         assert collectives.get("all-gather", 0) > 0, collectives
+
+
+def test_paged_decode_kernel_maps_over_batch_shards_at_cell_widths(topo):
+    """The chunked kernel as a degree-4 replicated layer runs it
+    (``layers.apply_gqa_paged``): mapped over four batch shards of a
+    described v5e:2x2 with the pool replicated, at the cell's widths."""
+    B, H, KV, D, bs, table, n_blocks = 64, 32, 4, 128, 16, 256, 8800
+    mesh = R.replication_mesh(4, devices=list(topo.devices))
+    rows, everywhere = P(mesh.axis_names), P()
+    kernel = jax.shard_map(paged_decode_attention, mesh=mesh,
+                           in_specs=(rows, everywhere, everywhere, rows,
+                                     rows),
+                           out_specs=rows, check_vma=False)
+    pool = _spec((n_blocks, KV, bs, D), jnp.bfloat16,
+                 NamedSharding(mesh, everywhere))
+    text = jax.jit(kernel).lower(
+        _spec((B, H, D), jnp.bfloat16, NamedSharding(mesh, rows)), pool, pool,
+        _spec((B, table), jnp.int32, NamedSharding(mesh, rows)),
+        _spec((B,), jnp.int32, NamedSharding(mesh, rows))).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "%paged_decode" in text
