@@ -2,7 +2,8 @@
 
 After the window closes and the server's state is freed, a sample of the
 greedy requests that finished (drawn from the seed, the longest always
-in it) is teacher-forced through ``reference.py`` on weights drawn again
+in it, and in a pod the longest that migrated between instances) is
+teacher-forced through ``reference.py`` on weights drawn again
 from the same seed: prompt plus served tokens, in float32. At each served
 position the gap ``best reference logit - reference logit of the served
 token`` is 0 where the server picked the reference's argmax and small
@@ -41,15 +42,27 @@ def finished_greedy(records, w0: float) -> list:
             and r["times"] and r["times"][-1] >= w0]
 
 
-def sample(records, w0: float, k: int, seed: int) -> list:
+def sample(records, w0: float, k: int, seed: int, migrated=()) -> list:
+    """``k`` of the finished greedy requests: the longest, the longest of
+    those that migrated between instances (``migrated``: the server's
+    stream ids, which the load generator records as ``server_id``) where
+    there is one, and the rest drawn from the seed."""
     pool = sorted(finished_greedy(records, w0), key=lambda r: r["id"])
     if len(pool) <= k:
         return pool
-    longest = max(pool, key=lambda r: (len(r["tokens"]), -r["id"]))
-    rest = [r for r in pool if r is not longest]
+
+    def longest(rs):
+        return max(rs, key=lambda r: (len(r["tokens"]), -r["id"]))
+
+    keep = [longest(pool)]
+    moved = [r for r in pool if r.get("server_id") in migrated
+             and r is not keep[0]]
+    if moved:
+        keep.append(longest(moved))
+    rest = [r for r in pool if all(r is not x for x in keep)]
     rng = np.random.default_rng([int(seed), 7])
-    pick = rng.choice(len(rest), size=k - 1, replace=False)
-    return [longest] + [rest[i] for i in sorted(pick)]
+    pick = rng.choice(len(rest), size=k - len(keep), replace=False)
+    return keep + [rest[i] for i in sorted(pick)]
 
 
 def gaps(w, dm: dict, prompts: dict, recs: List[dict], max_len: int,

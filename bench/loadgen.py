@@ -23,7 +23,8 @@ No request is sent after the window. Requests due inside it are then
 given ``grace_s`` seconds for their first token; every stream is then
 cut. Stdout carries one JSON line per event (``window`` as soon as it is
 known, ``done`` at the end); RESULT.json holds one record per request
-sent: due, sent, HTTP status, each token and its arrival time.
+sent: due, sent, HTTP status, each token and its arrival time, and the
+server's stream id and the instance it was routed to.
 """
 from __future__ import annotations
 
@@ -92,6 +93,9 @@ class Client:
                         rec["times"].append(t)
                     elif "error" in ev:
                         rec["error"] = ev["error"]
+                    elif "id" in ev:          # the routing verdict
+                        rec["server_id"] = ev["id"]
+                        rec["instance"] = ev.get("instance")
         except (OSError, ValueError, asyncio.IncompleteReadError) as e:
             rec["error"] = repr(e)
         finally:

@@ -1,5 +1,5 @@
-"""Shared arithmetic of the metric readers: percentiles and the window's
-token arrivals."""
+"""Shared arithmetic of the metric readers: percentiles, the window's
+token gaps and the requests due in it."""
 from __future__ import annotations
 
 import math
@@ -12,6 +12,17 @@ def percentile(values, q: float):
     if not v:
         return None
     return v[max(0, math.ceil(q / 100.0 * len(v)) - 1)]
+
+
+def window_gaps(run) -> list:
+    """Every gap, in ms, between consecutive streamed tokens of one
+    request, over all requests, where both tokens reached the client
+    inside the window."""
+    gaps = []
+    for r in run.records:
+        t = [x for x in r["times"] if run.w0 <= x <= run.w1]
+        gaps += [(b - a) * 1e3 for a, b in zip(t, t[1:])]
+    return gaps
 
 
 def due_in_window(run):

@@ -1,5 +1,7 @@
-"""Share of the traced sub-window in which no operation ran on the chip:
-one minus the union of the device's op intervals over the window."""
+"""Share of the traced sub-window in which no operation ran on a chip:
+one minus the union of the chip's op intervals over the window, mean over
+the chips in the trace (``trace_reduce.Reduced.busy_s`` averages each
+chip's busy time)."""
 
 
 def read(run):

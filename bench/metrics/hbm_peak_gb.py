@@ -1,5 +1,6 @@
 """Peak device memory in use over the run up to the window's end
-(``memory_stats()["peak_bytes_in_use"]``), in GB."""
+(``memory_stats()["peak_bytes_in_use"]``) on the fullest of the cell's
+chips, in GB."""
 
 
 def read(run):

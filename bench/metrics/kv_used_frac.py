@@ -1,5 +1,5 @@
 """KV pool blocks in use over pool blocks, read after each engine step,
-mean over the steps of the window."""
+mean over the steps of the window (every instance's)."""
 
 
 def read(run):

@@ -5,10 +5,10 @@ keys and values read once, q.k and p.v operations, against the peaks)
 over the device time of the kernel's calls in that step.
 
 The kernel is found in the trace as the Pallas (``tpu_custom_call``)
-custom call of the paged decode step program, and each call is given to
-the ``engine_step`` host span (whose ``step`` argument indexes the
-run's step records) that contains it; only steps wholly inside the
-traced sub-window count."""
+custom call of the paged decode step program, on every chip, and each
+call is given to the ``engine_step`` host span (whose ``step`` argument
+indexes the run's step records, every instance's) that contains it;
+only steps wholly inside the traced sub-window count."""
 from bench import flops as F
 
 PROGRAM = "jit__paged_step_impl"

@@ -1,11 +1,15 @@
-"""Requests the engine preempted for KV pool room during the window (the
-engine's own counter, read after each step)."""
+"""Requests the engines preempted for KV pool room during the window (each
+engine's own counter, read after each of its steps), summed over the
+pod's instances."""
 
 
 def read(run):
     steps = run.window_steps()
-    before = [s for s in run.steps if s.t1 <= run.w0]
     if not steps:
         return None
-    start = before[-1].preempted if before else 0
-    return steps[-1].preempted - start
+    n = 0
+    for i in {s.instance for s in steps}:
+        mine = [s for s in steps if s.instance == i]
+        before = [s for s in run.steps if s.instance == i and s.t1 <= run.w0]
+        n += mine[-1].preempted - (before[-1].preempted if before else 0)
+    return n

@@ -2,7 +2,8 @@
 model needs for the tokens the engine processed there (decode tokens
 with their attention span and logits, prefill chunk tokens with causal
 attention, one logits row per finished prompt; ``flops.py``) over the
-sub-window's length times the chip's peak bf16 rate, in percent."""
+sub-window's length times the peak bf16 rate of every chip the cell
+holds, in percent."""
 from bench import flops as F
 
 
@@ -15,4 +16,5 @@ def read(run):
         total += sum(F.prefill_chunk_flops(run.dm, a, n, end)
                      for a, n, end in s.prefill)
     span = run.trace_host[1] - run.trace_host[0]
-    return 100.0 * total / (span * run.peak["bf16_flops"])
+    chips = run.cell.get("chips", 1)
+    return 100.0 * total / (span * chips * run.peak["bf16_flops"])

@@ -1,5 +1,5 @@
 """Host wall time of one ``Engine.step`` (it ends in a blocking fetch of
-the sampled tokens), median over the steps of the window."""
+the sampled tokens), median over the steps of the window (every instance's)."""
 from bench.metrics._common import percentile
 
 

@@ -1,5 +1,6 @@
 """Tokens the scheduler packed into one engine step (decode tokens plus
-prefill chunk tokens), mean over the steps of the window."""
+prefill chunk tokens), mean over the steps of the window (every
+instance's)."""
 
 
 def read(run):

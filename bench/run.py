@@ -12,10 +12,13 @@ What one run does:
 
 1. set-up: weights drawn from the seed on the device (``model.py``); the
    serving pod as ``serve --http`` builds it (``launch/serve.build_pod``:
-   one in-process instance, token-budget scheduler, prefix sharing,
-   decode attention ``auto``, the controller on, the budget governor as
-   the cell file sets it) behind
-   an in-process ``Ingress`` on an ephemeral port; the load generator
+   the cell file's ``instances`` in-process instances, default 1, one
+   per chip; token-budget scheduler, prefix sharing, decode attention
+   ``auto``, the controller on with the cell file's ``slo_latency`` where
+   it gives one, the budget governor as the cell file sets it) behind
+   an in-process ``Ingress`` on an ephemeral port. A pod of several
+   instances is warmed up in process first, every warm-up group on every
+   instance (``Orchestrator.submit_to``); the load generator
    (``loadgen.py``, a child process without JAX) primes the cell's
    streams and runs the lead-in. JAX's persistent compilation cache lives
    at ``.jax_cache/`` in the checkout, so only a checkout's first run
@@ -49,6 +52,8 @@ import sys  # noqa: E402
 import threading  # noqa: E402
 from typing import Optional  # noqa: E402
 
+import numpy as np  # noqa: E402
+
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 for p in (str(ROOT / "src"), str(ROOT)):
@@ -60,6 +65,7 @@ from bench import model as M  # noqa: E402
 from bench import traffic as TF  # noqa: E402
 
 TRACE_S = 3.0          # profiled sub-window, centred in the window
+WARM_STEPS = 10 ** 5   # a pod warm-up group not served by then is a fault
 CACHE_DIR = ROOT / ".jax_cache"
 
 
@@ -78,22 +84,27 @@ class Run:
     w1: float
     t_end: float              # when the load generator stopped
     records: list             # loadgen records (loadgen.py)
-    steps: list               # spans.StepRecord per Engine.step
+    steps: list               # spans.StepRecord per Engine.step, every
+                              # instance, in the order the steps ran
     traces: list              # the ingress tracer's finished traces
     flight: list              # flight-recorder events
     peak: dict                # peaks.json entry of this device
-    memory_peak_bytes: Optional[int]
+    memory_peak_bytes: Optional[int]   # the fullest chip's peak
     compiles_in_window: list  # (monotonic, program, seconds) compiled or loaded
     gc_in_window: list        # (monotonic, generation, seconds) collections
     trace: object = None      # trace_reduce.Reduced, with --trace 1
     trace_host: tuple = None  # (t0, t1) monotonic of the traced sub-window
 
     def window_steps(self):
-        return [s for s in self.steps if self.w0 <= s.t0 and s.t1 <= self.w1]
+        """The steps wholly inside the window, an idle instance's left out
+        (one instance is stepped only when it has work)."""
+        return [s for s in self.steps
+                if self.w0 <= s.t0 and s.t1 <= self.w1 and not s.idle]
 
     def traced_steps(self):
         a, b = self.trace_host
-        return [s for s in self.steps if a <= s.t0 and s.t1 <= b]
+        return [s for s in self.steps
+                if a <= s.t0 and s.t1 <= b and not s.idle]
 
 
 def metric_names(bench: dict, cell: str, kind: str) -> list:
@@ -128,10 +139,11 @@ def _profile(out_dir: pathlib.Path, start: float, host: list):
     jax.profiler.stop_trace()
 
 
-def serve(cfg, dm, mix, knobs, seed, seconds, trace, device, *,
+def serve(cfg, dm, mix, knobs, seed, seconds, trace, devices, *,
           out_dir: pathlib.Path, engine_kw=None, keep_programs=False):
-    """Set-up, the window, and the server torn down. Returns the raw
-    material of a ``Run`` (without the check), and the prompts sent.
+    """Set-up, the window, and the server torn down. ``devices`` are the
+    chips the cell holds; the weights are made on the first. Returns the
+    raw material of a ``Run`` (without the check), and the prompts sent.
     ``keep_programs`` keeps the compiled programs in memory afterwards,
     for a sweep's later runs in the same process."""
     from bench import spans
@@ -140,44 +152,48 @@ def serve(cfg, dm, mix, knobs, seed, seconds, trace, device, *,
     pauses = spans.GcPauses()
     try:
         return _serve(counter, pauses, cfg, dm, mix, knobs, seed, seconds,
-                      trace, device, out_dir, engine_kw, keep_programs)
+                      trace, devices, out_dir, engine_kw, keep_programs)
     finally:
         counter.close()
         pauses.close()
 
 
 def _serve(counter, pauses, cfg, dm, mix, knobs, seed, seconds, trace,
-           device, out_dir, engine_kw, keep_programs):
+           devices, out_dir, engine_kw, keep_programs):
     import jax
     from repro.launch import serve as SV
     from repro.serving import observe as OBS
     from repro.serving.ingress import Ingress
     from bench import spans
 
-    w = M.make_weights(dm, seed, device)
+    w = M.make_weights(dm, seed, devices[0])
     params = M.program_params(w, cfg)
     del w
     M.check_layout(params, cfg)
+    pod = {"slo": knobs["slo_latency"]} if "slo_latency" in knobs else {}
     args = SV.ServeConfig(
-        arch=cfg.name, full_width=True, instances=1,
+        arch=cfg.name, full_width=True, instances=knobs.get("instances", 1),
         max_batch=knobs["max_batch"], max_len=knobs["max_len"],
         token_budget=knobs["token_budget"], scheduler="budget", http=True,
         http_port=0, max_queue=knobs["max_queue"],
-        govern_budget=knobs["govern_budget"]).validate()
+        govern_budget=knobs["govern_budget"], **pod).validate()
     orch = SV.build_pod(args, cfg, params, cfg.dtype,
                         n_blocks=knobs["pool_blocks"], **(engine_kw or {}))
     del params
     orch.flightrec = OBS.FlightRecorder(capacity=10 ** 7)
+    spec = TF.generate(mix, knobs, dm["V"], seed, seconds)
+    if len(orch.instances) > 1:
+        _warm_pod(orch, spec.pop("warmup"))
     orch.tracer = OBS.Tracer(keep=10 ** 7)
     ing = Ingress(orch, host="127.0.0.1", port=0, model_id=cfg.name,
                   govern_budget=args.govern_budget)
-    steps_by_engine: list = []
-    spans.instrument(ing, orch, steps_by_engine)
+    steps: list = []
+    spans.instrument(ing, orch, steps)
     eng = orch.engines[0]
-    log(f"{cfg.name}: {cfg.num_layers} layers, pool {knobs['pool_blocks']} "
-        f"blocks ({eng.pstate.pool_bytes()} bytes), decode attention "
+    log(f"{cfg.name}: {cfg.num_layers} layers, {len(orch.instances)} "
+        f"instance(s), pool {knobs['pool_blocks']} blocks "
+        f"({eng.pstate.pool_bytes()} bytes each), decode attention "
         f"{eng.paged_attn_impl}, max_batch {knobs['max_batch']}")
-    spec = TF.generate(mix, knobs, dm["V"], seed, seconds)
     out_dir.mkdir(parents=True, exist_ok=True)
     spec_path, res_path = out_dir / "spec.json", out_dir / "records.json"
     prompts = {r["id"]: r["prompt"] for r in spec["requests"]}
@@ -189,8 +205,7 @@ def _serve(counter, pauses, cfg, dm, mix, knobs, seed, seconds, trace,
         with open(spec_path, "w") as f:
             json.dump(spec, f)
         _drive(spec_path, res_path, seconds, trace, out_dir, host)
-        stats = device.memory_stats()
-        mem = stats.get("peak_bytes_in_use") if stats else None
+        mem = _memory_peak(devices)
     finally:
         ing.close()
         orch.close()
@@ -200,7 +215,7 @@ def _serve(counter, pauses, cfg, dm, mix, knobs, seed, seconds, trace,
         res = json.load(f)
     raw = dict(w0=res["w0"], w1=res["w1"], t_end=res["t_end"],
                records=res["records"],
-               steps=steps_by_engine[0], traces=list(orch.tracer.finished),
+               steps=steps, traces=list(orch.tracer.finished),
                flight=orch.flightrec.events(), memory_peak_bytes=mem,
                compiles_in_window=counter.within(res["w0"], res["w1"]),
                gc_in_window=pauses.within(res["w0"], res["w1"]),
@@ -210,6 +225,44 @@ def _serve(counter, pauses, cfg, dm, mix, knobs, seed, seconds, trace,
     if not keep_programs:
         jax.clear_caches()
     return raw, prompts
+
+
+def _warm_pod(orch, groups):
+    """Set-up of a pod of several instances: each warm-up group
+    (``traffic.warmup``) is submitted to every instance at once, by
+    ``Orchestrator.submit_to``, and served to its end before the next, so
+    every instance runs every shape the cell's traffic asks for (each
+    instance is on its own chip, where a program compiled for another
+    chip does not run)."""
+    from repro.serving.request import RequestSpec, SamplingParams
+    rid = 10 ** 9      # above the ingress's stream ids, which count from 1
+    for group in groups:
+        pending = set()
+        for i in range(len(orch.instances)):
+            for r in group:
+                rid += 1
+                pending.add(rid)
+                orch.submit_to(i, RequestSpec(
+                    rid=rid, prompt=np.asarray(r["prompt"], np.int32),
+                    max_tokens=r["max_tokens"],
+                    sampling=SamplingParams(temperature=r["temperature"],
+                                            seed=r["seed"])))
+        for _ in range(WARM_STEPS):
+            pending -= {r.rid for r in orch.step()}
+            if not pending:
+                break
+        else:
+            raise RuntimeError(f"pod warm-up: {len(pending)} requests "
+                               f"unfinished after {WARM_STEPS} steps")
+
+
+def _memory_peak(devices) -> Optional[int]:
+    """The peak bytes in use on the fullest of ``devices``, or None where
+    the device keeps no such count."""
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in devices]
+    peaks = [p for p in peaks if p is not None]
+    return max(peaks) if peaks else None
 
 
 def _drive(spec_path, res_path, seconds, trace, out_dir, host):
@@ -260,7 +313,8 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float, trace: bool,
     if peak is None and device.platform == "tpu":
         raise KeyError(f"no peaks for device kind {device.device_kind!r} "
                        f"in bench/peaks.json")
-    raw, prompts = serve(cfg, dm, mix, knobs, seed, seconds, trace, device,
+    devices = jax.devices()[:cell["chips"]]
+    raw, prompts = serve(cfg, dm, mix, knobs, seed, seconds, trace, devices,
                          out_dir=out_dir, engine_kw=engine_kw,
                          keep_programs=keep_programs)
     run = Run(cell=cell, conf=conf, dm=dm, mix=mix, knobs=knobs, seed=seed,
@@ -282,7 +336,10 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float, trace: bool,
     checks = []
     extra = {}
     if check:
-        sample = CK.sample(run.records, run.w0, mix["check_requests"], seed)
+        moved = {e["rid"] for e in run.flight if e["kind"] == "migration"}
+        sample = CK.sample(run.records, run.w0, mix["check_requests"], seed,
+                           moved)
+        _describe_sample(run, sample, moved)
         t = time.monotonic()
         w = M.make_weights(dm, seed, device)
         g = CK.gaps(w, dm, prompts, sample, knobs["max_len"])
@@ -344,6 +401,18 @@ def _describe(run: Run):
             f"ms, {len(s.decode_ctx)} rows (context max "
             f"{max(s.decode_ctx, default=0)}), chunks {s.prefill}, packed "
             f"{s.packed}, blocks {s.blocks_in_use}")
+
+
+def _describe_sample(run: Run, sample: list, moved: set):
+    """Which of the checked requests migrated, and which finished after
+    the controller's first scale-up, set-up included, replicated layers
+    (information only)."""
+    ups = [e["t"] for e in run.flight if e["kind"] == "controller"
+           and (e.get("action") or "").startswith("scale-up")]
+    log(f"checked requests: {len(sample)}; migrated "
+        f"{sum(r.get('server_id') in moved for r in sample)} (of "
+        f"{len(moved)} migrations in the run); finished after a scale-up "
+        f"{sum(bool(ups) and r['times'][-1] > ups[0] for r in sample)}")
 
 
 def _attempts(run: Run):

@@ -7,8 +7,12 @@ tick, the control tick, ``Engine.step`` and its admission pass) in a
 shows what the host was doing when the device sat idle. Around each
 ``Engine.step`` it also records one ``StepRecord`` from the engine's
 public state: wall time, the tokens the scheduler packed, the prefill
-chunks and decode contexts the step ran, pool blocks in use and the
-preemption counter.
+chunks and decode contexts the step ran, pool blocks in use, the
+preemption counter, which instance of the pod stepped and whether it had
+anything queued or running when the step began (the orchestrator steps
+every instance of a pod on each tick, idle ones too). The records of
+every instance go into one list, in the order the steps ran (the
+orchestrator steps in-process instances one after another).
 
 ``CompileCounter`` records XLA compilations and persistent-cache loads by
 the time they finish, and ``GcPauses`` the interpreter's garbage
@@ -49,6 +53,8 @@ class StepRecord:
     blocks_in_use: int
     n_blocks: int
     preempted: int               # engine preemption counter after the step
+    instance: int = 0            # the pod's instance (engine) index
+    idle: bool = False           # nothing queued or running at its start
 
 
 def _annotate(obj, attr: str, name: str):
@@ -68,14 +74,16 @@ def _annotate(obj, attr: str, name: str):
     setattr(obj, attr, wrapped)
 
 
-def instrument_engine(eng, steps: list):
-    """Record every ``Engine.step`` of ``eng`` into ``steps``."""
+def instrument_engine(eng, steps: list, instance: int = 0):
+    """Record every ``Engine.step`` of ``eng`` into ``steps``; the
+    ``engine_step`` span's ``step`` argument is the record's index."""
     inner = eng.step
 
     @functools.wraps(inner)
     def step(*a, **k):
         pre_pf = {r.rid: r.prefill_pos for r in eng.prefilling.values()}
         pre_act = {r.rid for r in eng.active.values()}
+        idle = not (eng.queue or eng.active or eng.prefilling)
         t0 = time.monotonic()
         with jax.profiler.TraceAnnotation(ENGINE_STEP, step=len(steps)):
             out = inner(*a, **k)
@@ -95,15 +103,16 @@ def instrument_engine(eng, steps: list):
         st = eng.pstate
         steps.append(StepRecord(t0, t1, eng.last_step_packed, ctx, chunks,
                                 st.blocks_in_use(), st.n_blocks,
-                                eng.preempt_count))
+                                eng.preempt_count, instance, idle))
         return out
 
     eng.step = step
     _annotate(eng, "_admit", ENGINE_ADMIT)
 
 
-def instrument(ingress, orch, steps_by_engine: list):
-    """Annotate the pump's work, the orchestrator and every engine."""
+def instrument(ingress, orch, steps: list):
+    """Annotate the pump's work, the orchestrator and every engine; the
+    step records of all engines go into ``steps``."""
     _annotate(ingress, "_drain_submissions", PUMP_SUBMIT)
     _annotate(ingress, "_push_streams", PUMP_STREAMS)
     if ingress.governor is not None:
@@ -111,10 +120,8 @@ def instrument(ingress, orch, steps_by_engine: list):
     _annotate(orch, "step", ORCH_TICK)
     _annotate(orch, "control_tick", CONTROL)
     _annotate(orch, "snapshot", SNAPSHOT)
-    for eng in orch.engines:
-        steps = []
-        steps_by_engine.append(steps)
-        instrument_engine(eng, steps)
+    for i, eng in enumerate(orch.engines):
+        instrument_engine(eng, steps, i)
 
 
 class GcPauses:

@@ -40,11 +40,12 @@ def row(rate: float, seed: int, result: dict, run) -> dict:
             "output_tok_s": read_metric("output_tok_s", run),
             "offered_tok_s": rate * sum(r["max_tokens"] for r in due)
             / max(len(due), 1),
-            "ttft_mean_ms": percentile(ttft, 50),
+            "ttft_p50_ms": percentile(ttft, 50),
             "ttft_mean_ms": read_metric("ttft_mean_ms", run),
             "ttft_max_ms": max(ttft, default=None),
             "queue_wait_mean_ms": read_metric("queue_wait_mean_ms", run),
-            "itl_p95_ms": read_metric("itl_p95_ms", run),
+            "itl_p99_ms": read_metric("itl_p99_ms", run),
+            "token_gap_p95_ms": read_metric("token_gap_p95_ms", run),
             "step_ms_p50": read_metric("step_ms_p50", run),
             "decode_rows_mean": sum(rows) / max(len(rows), 1),
             "kv_used_frac": read_metric("kv_used_frac", run),

@@ -14,7 +14,12 @@ output rate by a seventh between seeds.)
 Two loops:
 
 * ``open``: independent users; requests arrive at a fixed rate
-  (``rate_rps`` in the cell file) whatever the server does.
+  (``rate_rps`` in the cell file) whatever the server does. A mix may add
+  ``bursts`` (``{"period_s": 20, "length_s": 8, "factor": 2.0}``): for
+  ``length_s`` seconds of every ``period_s``, counted from the start of
+  the lead-in and again from the start of the window, requests arrive at
+  ``factor`` times the base rate (the shape of BurstGPT,
+  arXiv:2401.17644). Arrival times stay drawn from the shape seed.
 * ``closed``: ``clients`` callers that each wait for a reply before
   sending the next request.
 
@@ -81,11 +86,38 @@ def _requests(rng, mix, knobs, vocab, p, o, phase, first_id):
             for i in range(len(p))]
 
 
+def _load(mix, span: float):
+    """The rate's breakpoints in [0, span] and the arrivals due by each,
+    in seconds at the base rate (the cumulative intensity); without
+    ``bursts``, None."""
+    b = mix.get("bursts")
+    if not b:
+        return None
+    period, length = float(b["period_s"]), float(b["length_s"])
+    starts = np.arange(0.0, span, period)
+    t = np.unique(np.clip(np.concatenate(
+        [[0.0, span], starts, starts + length]), 0.0, span))
+    inside = ((t[:-1] + t[1:]) / 2) % period < length
+    rate = np.where(inside, float(b["factor"]), 1.0)
+    return t, np.concatenate([[0.0], np.cumsum(rate * np.diff(t))])
+
+
+def _count(rate: float, mix, span: float) -> int:
+    """How many requests arrive in ``span`` seconds at the base ``rate``."""
+    load = _load(mix, span)
+    return int(round(rate * (span if load is None else load[1][-1])))
+
+
 def _arrivals(mix, n: int, span: float, stream: int) -> np.ndarray:
     """``n`` Poisson arrival times in [0, span): uniform order statistics
-    drawn from the shape seed."""
+    drawn from the shape seed, mapped through the inverse of the
+    cumulative intensity where the mix has bursts."""
     srng = np.random.default_rng([mix["shape_seed"], stream])
-    return np.sort(srng.uniform(0.0, span, size=n))
+    load = _load(mix, span)
+    if load is None:
+        return np.sort(srng.uniform(0.0, span, size=n))
+    t, cum = load
+    return np.interp(np.sort(srng.uniform(0.0, cum[-1], size=n)), cum, t)
 
 
 def _prime(rng, mix, knobs, vocab, n: int) -> list:
@@ -150,8 +182,8 @@ def generate(mix: dict, knobs: dict, vocab: int, seed: int,
         primes = _prime(rng, mix, knobs, vocab, int(knobs["prime_streams"]))
         rate = float(knobs["rate_rps"])
         lead = float(mix["lead_in_s"])
-        n_lead = int(round(rate * lead))
-        n_win = int(round(rate * seconds))
+        n_lead = _count(rate, mix, lead)
+        n_win = _count(rate, mix, seconds)
         lrng = np.random.default_rng([int(seed), 2])   # lead-in: own stream
         p, o = _shapes(mix, knobs, n_lead, 2)
         reqs = _requests(lrng, mix, knobs, vocab, p, o, "lead", len(primes))

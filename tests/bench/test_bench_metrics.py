@@ -5,6 +5,7 @@ import json
 
 import pytest
 
+from bench import check as CK
 from bench import flops as F
 from bench import model as M
 from bench import run as R
@@ -22,14 +23,15 @@ def rec(rid, due, sent, times, status=200, phase="window", error=None):
             "max_tokens": len(times)}
 
 
-def step(t0, t1, packed=8, ctx=(), prefill=(), used=10, pre=0):
+def step(t0, t1, packed=8, ctx=(), prefill=(), used=10, pre=0, instance=0,
+         idle=False):
     return S.StepRecord(t0, t1, packed, list(ctx), list(prefill), used, 100,
-                        pre)
+                        pre, instance, idle)
 
 
 def make_run(records=(), steps=(), flight=(), traces=(), trace=None,
-             trace_host=None, mem=None):
-    return R.Run(cell={}, conf={}, dm=DM, mix={}, knobs={}, seed=1,
+             trace_host=None, mem=None, cell=None):
+    return R.Run(cell=cell or {}, conf={}, dm=DM, mix={}, knobs={}, seed=1,
                  seconds=10.0, setup_s=42.5, w0=100.0, w1=110.0, t_end=130.0,
                  records=list(records), steps=list(steps),
                  traces=list(traces), flight=list(flight), peak=PEAK,
@@ -61,9 +63,21 @@ def test_itl_and_output_rate_count_the_window_only():
             rec(1, 101.0, 101.0, [101.0, 101.5])]
     run = make_run(recs)
     # gaps inside the window: 100, 200 (req 0) and 500 (req 1) ms
-    assert read("itl_p95_ms", run) == pytest.approx(500.0)
+    assert read("token_gap_p95_ms", run) == pytest.approx(500.0)
+    assert read("itl_p99_ms", run) == pytest.approx(500.0)
     assert read("output_tok_s", run) == pytest.approx(5 / 10.0)
     assert read("setup_s", run) == 42.5
+
+
+@pytest.mark.parametrize("name,want", [("token_gap_p95_ms", 95.0),
+                                       ("itl_p99_ms", 99.0)])
+def test_gap_percentiles_by_nearest_rank(name, want):
+    # one request whose 100 gaps inside the window are 1, 2, ..., 100 ms
+    times = [100.0]
+    for g in range(1, 101):
+        times.append(times[-1] + g / 1e3)
+    run = make_run([rec(0, 100.0, 100.0, times)])
+    assert read(name, run) == pytest.approx(want)
 
 
 def test_generator_lateness():
@@ -71,16 +85,42 @@ def test_generator_lateness():
     assert read("gen_late_max_ms", make_run(recs)) == pytest.approx(99.0)
 
 
+STEPS = [step(99.0, 99.5, packed=100, used=90, pre=1),
+         step(100.0, 100.2, packed=10, used=20, pre=1),
+         step(100.3, 100.6, packed=30, used=40, pre=3),
+         step(109.9, 110.5, packed=1000, used=99, pre=9)]
+# a second instance, stepped between the first one's steps, with its own
+# preemption counter; its last step found it with nothing to do
+OTHER = [step(99.6, 99.9, packed=70, used=80, pre=4, instance=1),
+         step(100.2, 100.3, packed=50, used=60, pre=5, instance=1),
+         step(100.6, 101.0, packed=90, used=70, pre=5, instance=1),
+         step(101.1, 101.1001, packed=None, used=0, pre=5, instance=1,
+              idle=True)]
+
+
 def test_step_counters():
-    steps = [step(99.0, 99.5, packed=100, used=90, pre=1),
-             step(100.0, 100.2, packed=10, used=20, pre=1),
-             step(100.3, 100.6, packed=30, used=40, pre=3),
-             step(109.9, 110.5, packed=1000, used=99, pre=9)]
-    run = make_run(steps=steps)
+    run = make_run(steps=STEPS)
     assert read("step_tokens_mean", run) == 20
     assert read("kv_used_frac", run) == pytest.approx(0.3)
     assert read("preemptions", run) == 2
     assert read("step_ms_p50", run) == pytest.approx(200.0)
+
+
+@pytest.mark.parametrize("instances,want", [
+    # one instance: what the readers read before pods existed
+    (1, {"step_tokens_mean": 20, "kv_used_frac": 0.3, "preemptions": 2,
+         "step_ms_p50": 200.0}),
+    # two: window steps pooled (packed 10, 30, 50, 90; used .2 .4 .6 .7;
+    # 200, 300, 100, 400 ms), preemptions summed per instance (2 + 1)
+    (2, {"step_tokens_mean": 45, "kv_used_frac": 0.475, "preemptions": 3,
+         "step_ms_p50": 200.0}),
+])
+def test_step_readers_pool_every_instance(instances, want):
+    steps = sorted(STEPS + (OTHER if instances == 2 else []),
+                   key=lambda s: s.t0)
+    run = make_run(steps=steps)
+    assert {k: read(k, run) for k in want} == pytest.approx(want)
+    assert len(run.window_steps()) == 2 * instances
 
 
 def test_plan_changes_and_memory():
@@ -93,6 +133,35 @@ def test_plan_changes_and_memory():
     assert read("plan_changes", make_run(flight=flight)) == 2
     assert read("hbm_peak_gb", make_run(mem=12_500_000_000)) == 12.5
     assert read("hbm_peak_gb", make_run()) is None
+
+
+@pytest.mark.parametrize("events,want", [
+    # two migrations inside the window: 4 ms and 10 ms of stall
+    ([(101.0, 0.004), (105.0, 0.010)], 7.0),
+    # one before and one after the window do not count
+    ([(99.0, 0.5), (103.0, 0.002), (111.0, 0.5)], 2.0),
+    ([(99.0, 0.5)], None),
+    ([], None),
+])
+def test_migration_stall_ms(events, want):
+    flight = [{"t": t, "kind": "migration", "stall_s": s, "mode": "overlap"}
+              for t, s in events]
+    flight.append({"t": 104.0, "kind": "controller", "action": "scale-up[x]"})
+    got = read("migration_stall_ms", make_run(flight=flight))
+    assert got == (None if want is None else pytest.approx(want))
+
+
+@pytest.mark.parametrize("moved,want", [((), {19}), ((4,), {19, 4})])
+def test_check_sample_keeps_the_longest_and_a_migrated_request(moved, want):
+    recs = []
+    for i in range(20):
+        r = rec(i, 100.0, 100.0, [101.0] * (i + 1))
+        r["server_id"] = i + 1000
+        recs.append(r)
+    got = CK.sample(recs, 100.0, 5, 7, {i + 1000 for i in moved})
+    assert len(got) == 5 and want <= {r["id"] for r in got}
+    if not moved:    # what the sample drew before pods: same seed, same picks
+        assert got == CK.sample(recs, 100.0, 5, 7)
 
 
 def test_queue_wait_from_tracer_spans():
@@ -138,6 +207,14 @@ def test_device_metrics_from_a_trace():
     assert read("step_mfu", run) == pytest.approx(
         100 * model / PEAK["bf16_flops"])
     assert _trace().top_gaps(1) == [["pump_push_streams", 0.35]]
+
+
+def test_step_mfu_counts_the_peak_of_every_chip():
+    steps = [step(100.0, 100.3, ctx=[1000, 3000])]
+    one = make_run(steps=steps, trace=_trace(), trace_host=(100.0, 101.0))
+    four = make_run(steps=steps, trace=_trace(), trace_host=(100.0, 101.0),
+                    cell={"chips": 4})
+    assert read("step_mfu", four) == pytest.approx(read("step_mfu", one) / 4)
 
 
 def test_device_metrics_need_a_trace():

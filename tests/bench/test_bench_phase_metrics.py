@@ -20,10 +20,11 @@ DM = M.dims(M.load_config("yi-1.5-9b"))
 PEAK = json.load(open(R.BENCH / "peaks.json"))["TPU v5 lite"]
 
 
-def make_run(flight):
+def make_run(flight, records=()):
     return R.Run(cell={}, conf={}, dm=DM, mix={}, knobs={}, seed=1,
                  seconds=10.0, setup_s=1.0, w0=100.0, w1=110.0, t_end=111.0,
-                 records=[], steps=[], traces=[], flight=list(flight),
+                 records=[], steps=list(records), traces=[],
+                 flight=list(flight),
                  peak=PEAK, memory_peak_bytes=None, compiles_in_window=[],
                  gc_in_window=[])
 
@@ -87,6 +88,25 @@ def test_readers_difference_each_instance_and_skip_a_restart():
     assert R.read_metric("admit_ms_max", run) == pytest.approx(10.0)
     assert R.read_metric("step_host_ms_p50", run) == pytest.approx(2.0)
     assert R.read_metric("step_compiles", run) == 1
+
+
+def test_readers_leave_out_an_idle_instances_steps():
+    # instance 1 is stepped on every tick of the pod; at 101.1 it had
+    # nothing queued or running (its own step record says so)
+    a = steps((100.5, 0.4, 0, dict(plan=1.0, wait=300.0)),
+              (101.0, 0.4, 1, dict(plan=4.0, wave=6.0, wait=300.0)))
+    b = steps((100.6, 0.4, 0, dict(plan=1.0, wait=300.0)),
+              (101.1, 0.05, 2, dict(plan=0.01)), instance=1)
+    recs = [S.StepRecord(e["t"] - e["seconds"], e["t"] - 1e-4, None, [], [],
+                         0, 100, 0, e["instance"], idle)
+            for e, idle in ((a[0], False), (b[0], False), (a[1], False),
+                            (b[1], True))]
+    flight = [a[0], b[0], a[1], b[1]]
+    assert R.read_metric("step_compiles", make_run(flight)) == 3
+    run = make_run(flight, recs)
+    assert R.read_metric("step_compiles", run) == 1
+    assert R.read_metric("step_host_ms_p50", run) == pytest.approx(10.0)
+    assert R.read_metric("admit_ms_max", run) == pytest.approx(10.0)
 
 
 def test_program_spans_lie_inside_the_engine_step_span(tmp_path):

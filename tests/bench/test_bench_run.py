@@ -4,7 +4,9 @@ interpret mode, through the real HTTP door, the real loadgen child and
 the real check) reports the cell's metrics, no device metric, and comes
 out correct. The check is shown to fail: under the fp8 control, with a
 token altered where the decode step produces it, and with a decode step
-that leaves the KV pool as it was."""
+that leaves the KV pool as it was. A pod of two instances, with an SLO
+so tight that the controller migrates streams between them, is warmed up
+on both, reads the steps of both and comes out correct."""
 import json
 import os
 import subprocess
@@ -39,16 +41,22 @@ KERNEL = {"paged_attn_impl": "kernel", "interpret": True}
 # on the chip); its files stay, and its rehearsal keeps the open loop covered
 CHAT = {"name": "mistral-7b-v0.3.chat", "config": "mistral-7b-v0.3",
         "traffic": "chat", "chips": 1}
+# the pod cell is deferred too (the program cannot hold its plan on the
+# chip); its rehearsal keeps the pod path of the harness covered
+BURST = {"name": "mistral-7b-v0.3.burst-scale", "config": "mistral-7b-v0.3",
+         "traffic": "chat-burst", "chips": 4}
+DEFERRED = {c["name"]: c for c in (CHAT, BURST)}
 
 
 def cell(name):
-    return next((w for w in BENCH["workloads"] if w["name"] == name), CHAT)
+    return next((w for w in BENCH["workloads"] if w["name"] == name),
+                None) or DEFERRED[name]
 
 
-def rehearse(name, tmp_path, **kw):
-    return R.run_cell(BENCH, cell(name), 2 ** 33 + 7, 3.0, False,
-                      jax.devices()[0], out_dir=tmp_path, overrides=SMALL,
-                      engine_kw=KERNEL, **kw)
+def rehearse(name, tmp_path, overrides=SMALL, seconds=3.0, **kw):
+    return R.run_cell(BENCH, cell(name), 2 ** 33 + 7, seconds, False,
+                      jax.devices()[0], out_dir=tmp_path,
+                      overrides=overrides, engine_kw=KERNEL, **kw)
 
 
 def test_refuses_without_a_tpu(tmp_path):
@@ -82,6 +90,58 @@ def test_rehearsal(name, tmp_path):
     # the harness's own comparison finds it not correct
     assert max(extra["control_gaps"]) > run.knobs["max_logit_gap"]
     assert not CK.correct(extra["control_checks"])
+
+
+def test_pod_rehearsal(tmp_path):
+    # two instances on the one CPU device; an SLO of 3 engine steps makes
+    # every finished request a violation, so Alg. 2 migrates streams.
+    # Every request greedy, so every one that migrated and finished can be
+    # checked; a window of 6 s, as the first migration compiles programs
+    # inside it, which takes seconds on a loaded CPU
+    small = {**SMALL, "knobs": {**SMALL["knobs"], "instances": 2,
+                                "slo_latency": 3.0},
+             "mix": {**SMALL["mix"], "greedy_share": 1.0,
+                     "bursts": {"period_s": 2, "length_s": 1,
+                                "factor": 2.0}}}
+    result, run, checks, extra = rehearse(BURST["name"], tmp_path, small,
+                                          seconds=6.0)
+    assert result["correct"], result["checks"]
+    assert result["attempted"] > 0 and result["failed"] == 0
+    assert {s.instance for s in run.window_steps()} == {0, 1}
+    assert R.read_metric("plan_changes", run) >= 1
+    assert R.read_metric("migration_stall_ms", run) > 0
+    moved = {e["rid"] for e in run.flight if e["kind"] == "migration"}
+    sample = CK.sample(run.records, run.w0, run.mix["check_requests"],
+                       run.seed, moved)
+    assert any(r["server_id"] in moved for r in sample)
+    assert {r.get("instance") for r in run.records if r["times"]} == {0, 1}
+
+
+def test_pod_warm_up_serves_every_group_on_every_instance():
+    class Pod:
+        instances = [None] * 3
+
+        def __init__(self):
+            self.live, self.log = [], []
+
+        def submit_to(self, idx, spec):
+            self.live.append(spec)
+            self.log.append((idx, len(spec.prompt), spec.max_tokens,
+                             spec.sampling.temperature, spec.sampling.seed))
+
+        def step(self):                   # one request finishes a step
+            done, self.live = self.live[:1], self.live[1:]
+            return done
+
+    groups = [[{"prompt": [5] * n, "max_tokens": 2, "temperature": t,
+                "seed": 10 * n} for n, t in ((g, 0.0), (g + 1, 0.8))]
+              for g in (3, 7)]
+    pod = Pod()
+    R._warm_pod(pod, groups)
+    # group by group, each whole group on every instance, served out
+    assert pod.log == [(i, n, 2, t, 10 * n) for g in (3, 7)
+                       for i in range(3) for n, t in ((g, 0.0), (g + 1, 0.8))]
+    assert not pod.live
 
 
 def _faulty_step(monkeypatch, fault):

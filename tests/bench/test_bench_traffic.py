@@ -5,7 +5,10 @@ import pytest
 
 from bench import traffic as TF
 
-CELLS = [("chat", "mistral-7b-v0.3.chat"), ("reasoning", "yi-1.5-9b.reasoning")]
+CELLS = [("chat", "mistral-7b-v0.3.chat"), ("reasoning", "yi-1.5-9b.reasoning"),
+         ("chat-burst", "mistral-7b-v0.3.burst-scale")]
+OPEN = [("chat", "mistral-7b-v0.3.chat"),
+        ("chat-burst", "mistral-7b-v0.3.burst-scale")]
 
 
 def _gen(mix_name, cell, seed, seconds=51.0):
@@ -56,11 +59,15 @@ def test_lengths_follow_the_mix(mix_name, cell):
     assert abs(greedy - mix["greedy_share"] * len(fresh)) <= 1 + len(fresh) // 50
 
 
-def test_open_loop_rate_and_window():
-    mix, knobs, spec = _gen("chat", "mistral-7b-v0.3.chat", 3, seconds=51.0)
+@pytest.mark.parametrize("mix_name,cell", OPEN)
+def test_open_loop_rate_and_window(mix_name, cell):
+    mix, knobs, spec = _gen(mix_name, cell, 3, seconds=51.0)
     win = [r for r in spec["requests"] if r["phase"] == "window"]
     lead = [r for r in spec["requests"] if r["phase"] == "lead"]
-    assert len(win) == round(knobs["rate_rps"] * 51.0)
+    b = mix.get("bursts")
+    # seconds at the base rate: 51, plus (factor - 1) x the burst seconds
+    extra = (b["factor"] - 1) * 3 * b["length_s"] if b else 0.0
+    assert len(win) == round(knobs["rate_rps"] * (51.0 + extra))
     assert all(mix["lead_in_s"] <= r["due"] < mix["lead_in_s"] + 51.0
                for r in win)
     assert all(0 <= r["due"] < mix["lead_in_s"] for r in lead)
@@ -76,3 +83,57 @@ def test_closed_loop_primes_are_staggered():
     # carried contexts spread over the trajectories, not all at the start
     assert lens[-1] > 4 * lens[0]
     assert all(r["max_tokens"] >= mix["min_remaining"] for r in primes)
+
+
+def _dues(spec, phase):
+    return np.array([r["due"] for r in spec["requests"]
+                     if r["phase"] == phase])
+
+
+def test_a_mix_without_bursts_draws_the_same_arrivals_as_before():
+    """Uniform order statistics from the shape seed, as the generator drew
+    them before bursts existed: the same bytes."""
+    mix, knobs, spec = _gen("chat", "mistral-7b-v0.3.chat", 8, seconds=51.0)
+    rate, lead = knobs["rate_rps"], float(mix["lead_in_s"])
+
+    def before(n, span, stream):
+        rng = np.random.default_rng([mix["shape_seed"], stream])
+        return np.sort(rng.uniform(0.0, span, size=n))
+
+    n_lead, n_win = int(round(rate * lead)), int(round(rate * 51.0))
+    assert _dues(spec, "lead").tobytes() == before(n_lead, lead, 3).tobytes()
+    assert _dues(spec, "window").tobytes() == \
+        (lead + before(n_win, 51.0, 4)).tobytes()
+
+
+@pytest.mark.parametrize("factor", [2.0, 3.0])
+def test_bursts_multiply_the_rate_inside_them(factor):
+    mix = dict(TF.load("traffic", "chat-burst"))
+    mix["bursts"] = dict(mix["bursts"], factor=factor)
+    period, length = mix["bursts"]["period_s"], mix["bursts"]["length_s"]
+    span = 5 * period
+    t = TF._arrivals(mix, 40000, span, 4)
+    assert t.min() >= 0.0 and t.max() < span and np.all(np.diff(t) >= 0)
+    inside = t % period < length
+    rate_in = inside.sum() / (5 * length)
+    rate_out = (~inside).sum() / (span - 5 * length)
+    assert rate_in / rate_out == pytest.approx(factor, rel=0.05)
+    assert TF._count(1.0, mix, span) == round(span + (factor - 1) * 5 * length)
+
+
+def test_bursts_start_with_the_lead_in_and_with_the_window():
+    mix, knobs, spec = _gen("chat-burst", "mistral-7b-v0.3.burst-scale", 9,
+                            seconds=51.0)
+    assert mix["bursts"] == {"period_s": 20, "length_s": 8, "factor": 2.0}
+    # rate changes at 0, 8, 20, 28, 40, 48 s of the window; arrivals due by
+    # each, in seconds at the base rate
+    t, cum = TF._load(mix, 51.0)
+    assert t.tolist() == [0, 8, 20, 28, 40, 48, 51]
+    assert cum.tolist() == [0, 16, 28, 44, 56, 72, 75]
+    t, cum = TF._load(mix, float(mix["lead_in_s"]))
+    assert t.tolist() == [0, 8, 10] and cum.tolist() == [0, 16, 18]
+    lead, rate = float(mix["lead_in_s"]), knobs["rate_rps"]
+    assert np.array_equal(_dues(spec, "window"), lead + TF._arrivals(
+        mix, int(round(rate * 75)), 51.0, 4))
+    assert np.array_equal(_dues(spec, "lead"), TF._arrivals(
+        mix, int(round(rate * 18)), lead, 3))
