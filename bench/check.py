@@ -3,12 +3,13 @@
 After the window closes and the server's state is freed, a sample of the
 greedy requests that finished (drawn from the seed, the longest always
 in it, and in a pod the longest that migrated between instances) is
-teacher-forced through ``reference.py`` on weights drawn again
-from the same seed: prompt plus served tokens, in float32. At each served
-position the gap ``best reference logit - reference logit of the served
-token`` is 0 where the server picked the reference's argmax and small
-where rounding flipped a near tie; a wrong layer, cache entry or token
-makes it large. The number compared is the widest gap over the sample.
+teacher-forced through the architecture module's reference
+(``score``) on weights drawn again from the same seed: prompt plus
+served tokens, in float32. At each served position the gap ``best
+reference logit - reference logit of the served token`` is 0 where the
+server picked the reference's argmax and small where rounding flipped a
+near tie; a wrong layer, cache entry or token makes it large. The
+number compared is the widest gap over the sample.
 
 Also compared, with limit 0: finished requests whose token count differs
 from the ``max_tokens`` they asked for (no end-of-sequence token is
@@ -20,8 +21,6 @@ import dataclasses
 from typing import List
 
 import numpy as np
-
-from bench import reference
 
 
 @dataclasses.dataclass
@@ -65,9 +64,10 @@ def sample(records, w0: float, k: int, seed: int, migrated=()) -> list:
     return keep + [rest[i] for i in sorted(pick)]
 
 
-def gaps(w, dm: dict, prompts: dict, recs: List[dict], max_len: int,
+def gaps(arch, w, dm: dict, prompts: dict, recs: List[dict], max_len: int,
          precision: str = "float32") -> np.ndarray:
-    """Widest gap per request. ``precision="fp8"`` is the control: the
+    """Widest gap per request, by ``arch.score`` (the configuration's
+    architecture module). ``precision="fp8"`` is the control: the
     gap, in the float32 reference, of the token the fp8 reference puts
     first at each position of the same sequences."""
     out = []
@@ -77,11 +77,10 @@ def gaps(w, dm: dict, prompts: dict, recs: List[dict], max_len: int,
         first = len(prompt)
         targets = None
         if precision != "float32":
-            _, _, targets = reference.score(w, dm, seq, first,
-                                            precision=precision,
-                                            max_len=max_len)
-        best, tgt, _ = reference.score(w, dm, seq, first, targets=targets,
-                                       max_len=max_len)
+            _, _, targets = arch.score(w, dm, seq, first,
+                                       precision=precision, max_len=max_len)
+        best, tgt, _ = arch.score(w, dm, seq, first, targets=targets,
+                                  max_len=max_len)
         out.append(float(np.max(best - tgt)))
     return np.asarray(out)
 

@@ -3,8 +3,9 @@ at the cell's own size: for each seed, one run of the cell (the window
 may be shorter than the benchmark's; the load is the cell's own) whose
 finished greedy requests are compared with the float32 reference, as
 every run does, and also with the control: the same reference computed
-in fp8 (``reference.py``), whose first-ranked token at each position of
-the same sequences is scored in the float32 reference.
+in fp8 (the architecture module's ``score``), whose first-ranked token
+at each position of the same sequences is scored in the float32
+reference.
 
     python3 bench/control.py --workload <cell> --seeds 1,2,3 --seconds 30
 

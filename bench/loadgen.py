@@ -183,7 +183,10 @@ class Client:
 
     async def run(self):
         await asyncio.sleep(max(0.0, self.spec["t0"] - now()))
+        t = now()
         await self.warm()
+        emit(event="warm", groups=len(self.spec.get("warmup", [])),
+             seconds=now() - t)
         if self.spec["mode"] == "open":
             await self.open_loop()
         else:

@@ -1,6 +1,7 @@
 """Share of its roofline the paged decode attention kernel reaches, in
 percent: for every decode step inside the traced sub-window, the least
-time the chip needs for the kernel's work in all layers (``flops.py``:
+time the chip needs for the kernel's work in every layer that runs it
+(``run.arch.paged_layers``; ``flops.py``:
 keys and values read once, q.k and p.v operations, against the peaks)
 over the device time of the kernel's calls in that step.
 
@@ -31,6 +32,7 @@ def read(run):
         if not mine or not s.decode_ctx:
             continue
         fl, by = F.paged_decode_cost(run.dm, s.decode_ctx)
-        need += run.dm["L"] * F.roofline_seconds(fl, by, run.peak)
+        need += (run.arch.paged_layers(run.dm)
+                 * F.roofline_seconds(fl, by, run.peak))
         took += sum(c1 - c0 for c0, c1 in mine) / 1e9
     return 100.0 * need / took if took else None

@@ -1,10 +1,9 @@
 """Model FLOP utilization of the traced sub-window: the operations the
 model needs for the tokens the engine processed there (decode tokens
 with their attention span and logits, prefill chunk tokens with causal
-attention, one logits row per finished prompt; ``flops.py``) over the
-sub-window's length times the peak bf16 rate of every chip the cell
-holds, in percent."""
-from bench import flops as F
+attention, one logits row per finished prompt; the architecture
+module's counts, ``run.arch``) over the sub-window's length times the
+peak bf16 rate of every chip the cell holds, in percent."""
 
 
 def read(run):
@@ -12,8 +11,9 @@ def read(run):
         return None
     total = 0
     for s in run.traced_steps():
-        total += sum(F.decode_token_flops(run.dm, c) for c in s.decode_ctx)
-        total += sum(F.prefill_chunk_flops(run.dm, a, n, end)
+        total += sum(run.arch.decode_token_flops(run.dm, c)
+                     for c in s.decode_ctx)
+        total += sum(run.arch.prefill_chunk_flops(run.dm, a, n, end)
                      for a, n, end in s.prefill)
     span = run.trace_host[1] - run.trace_host[0]
     chips = run.cell.get("chips", 1)

@@ -10,7 +10,8 @@ Reads ``BENCHMARK.json`` for the cell, its configuration
 
 What one run does:
 
-1. set-up: weights drawn from the seed on the device (``model.py``); the
+1. set-up: weights drawn from the seed on the device by the
+   configuration's architecture module (``bench/arch/<arch>.py``); the
    serving pod as ``serve --http`` builds it (``launch/serve.build_pod``:
    the cell file's ``instances`` in-process instances, default 1, one
    per chip; token-budget scheduler, prefix sharing, decode attention
@@ -27,7 +28,8 @@ What one run does:
    With ``--trace 1`` a profiler trace is taken of a few seconds in its
    middle.
 3. after the window: device memory peak read, server closed and freed,
-   then the correctness check (``check.py``) on weights drawn again.
+   then the correctness check (``check.py``) on weights drawn again,
+   against the architecture module's reference.
 
 Prints the compilations inside the window on standard error, then each
 number compared with its limit as the last lines there; the last line of
@@ -74,7 +76,8 @@ class Run:
     """Everything a metric reader may read (``bench/metrics/*.py``)."""
     cell: dict
     conf: dict
-    dm: dict
+    dm: dict                  # arch.dims(conf)
+    arch: object              # the configuration's module (bench/arch/)
     mix: dict
     knobs: dict
     seed: int
@@ -139,11 +142,12 @@ def _profile(out_dir: pathlib.Path, start: float, host: list):
     jax.profiler.stop_trace()
 
 
-def serve(cfg, dm, mix, knobs, seed, seconds, trace, devices, *,
+def serve(arch, cfg, dm, mix, knobs, seed, seconds, trace, devices, *,
           out_dir: pathlib.Path, engine_kw=None, keep_programs=False):
     """Set-up, the window, and the server torn down. ``devices`` are the
-    chips the cell holds; the weights are made on the first. Returns the
-    raw material of a ``Run`` (without the check), and the prompts sent.
+    chips the cell holds; ``arch`` (the configuration's module) makes the
+    weights on the first. Returns the raw material of a ``Run`` (without
+    the check), and the prompts sent.
     ``keep_programs`` keeps the compiled programs in memory afterwards,
     for a sweep's later runs in the same process."""
     from bench import spans
@@ -151,23 +155,24 @@ def serve(cfg, dm, mix, knobs, seed, seconds, trace, devices, *,
     counter = spans.CompileCounter()
     pauses = spans.GcPauses()
     try:
-        return _serve(counter, pauses, cfg, dm, mix, knobs, seed, seconds,
-                      trace, devices, out_dir, engine_kw, keep_programs)
+        return _serve(counter, pauses, arch, cfg, dm, mix, knobs, seed,
+                      seconds, trace, devices, out_dir, engine_kw,
+                      keep_programs)
     finally:
         counter.close()
         pauses.close()
 
 
-def _serve(counter, pauses, cfg, dm, mix, knobs, seed, seconds, trace,
-           devices, out_dir, engine_kw, keep_programs):
+def _serve(counter, pauses, arch, cfg, dm, mix, knobs, seed, seconds,
+           trace, devices, out_dir, engine_kw, keep_programs):
     import jax
     from repro.launch import serve as SV
     from repro.serving import observe as OBS
     from repro.serving.ingress import Ingress
     from bench import spans
 
-    w = M.make_weights(dm, seed, devices[0])
-    params = M.program_params(w, cfg)
+    w = arch.make_weights(dm, seed, devices[0])
+    params = arch.program_params(w, cfg)
     del w
     M.check_layout(params, cfg)
     pod = {"slo": knobs["slo_latency"]} if "slo_latency" in knobs else {}
@@ -275,6 +280,9 @@ def _drive(spec_path, res_path, seconds, trace, out_dir, host):
     try:
         for line in child.stdout:
             ev = json.loads(line)
+            if ev["event"] == "warm":
+                log(f"warm-up: {ev['groups']} groups in "
+                    f"{ev['seconds']:.1f} s")
             if ev["event"] == "window" and trace:
                 start = ev["w0"] + (seconds - TRACE_S) / 2
                 profiler = threading.Thread(
@@ -294,19 +302,21 @@ def _drive(spec_path, res_path, seconds, trace, out_dir, host):
 def run_cell(bench: dict, cell: dict, seed: int, seconds: float, trace: bool,
              device, *, out_dir: pathlib.Path, overrides=None,
              engine_kw=None, check: bool = True, control: bool = False,
-             keep_programs: bool = False):
+             keep_programs: bool = False, root: pathlib.Path = BENCH):
     """One run of ``cell``; returns ``(result, run, checks, extra)`` where
     ``extra`` holds the gaps read by the check (and, with ``control``, the
     control's gaps and the checks they give). ``overrides``
     ({"conf": {...}, "mix": {...}, "knobs": {...}}) resizes a cell for a
-    rehearsal; the command line never passes any."""
+    rehearsal; the command line never passes any. ``root`` holds the
+    cell's configuration, architecture, traffic and cell files."""
     import jax
     ov = overrides or {}
-    conf = {**M.load_config(cell["config"]), **ov.get("conf", {})}
-    mix = {**TF.load("traffic", cell["traffic"]), **ov.get("mix", {})}
-    knobs = {**TF.load("cells", cell["name"]), **ov.get("knobs", {})}
-    dm = M.dims(conf)
-    cfg = M.model_config(conf, cell["config"])
+    conf = {**M.load_config(cell["config"], root), **ov.get("conf", {})}
+    mix = {**TF.load("traffic", cell["traffic"], root), **ov.get("mix", {})}
+    knobs = {**TF.load("cells", cell["name"], root), **ov.get("knobs", {})}
+    arch = M.arch(conf, root)
+    dm = arch.dims(conf)
+    cfg = arch.model_config(conf, cell["config"])
     with open(BENCH / "peaks.json") as f:
         peaks = json.load(f)
     peak = peaks.get(device.device_kind)
@@ -314,12 +324,12 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float, trace: bool,
         raise KeyError(f"no peaks for device kind {device.device_kind!r} "
                        f"in bench/peaks.json")
     devices = jax.devices()[:cell["chips"]]
-    raw, prompts = serve(cfg, dm, mix, knobs, seed, seconds, trace, devices,
-                         out_dir=out_dir, engine_kw=engine_kw,
+    raw, prompts = serve(arch, cfg, dm, mix, knobs, seed, seconds, trace,
+                         devices, out_dir=out_dir, engine_kw=engine_kw,
                          keep_programs=keep_programs)
-    run = Run(cell=cell, conf=conf, dm=dm, mix=mix, knobs=knobs, seed=seed,
-              seconds=seconds, setup_s=raw["w0"] - T_START, peak=peak,
-              **raw)
+    run = Run(cell=cell, conf=conf, dm=dm, arch=arch, mix=mix, knobs=knobs,
+              seed=seed, seconds=seconds, setup_s=raw["w0"] - T_START,
+              peak=peak, **raw)
     comp = run.compiles_in_window
     log(f"compilations inside the window: {len(comp)} "
         f"({sum(c[2] for c in comp):.3f} s): "
@@ -341,10 +351,11 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float, trace: bool,
                            moved)
         _describe_sample(run, sample, moved)
         t = time.monotonic()
-        w = M.make_weights(dm, seed, device)
-        g = CK.gaps(w, dm, prompts, sample, knobs["max_len"])
+        w = arch.make_weights(dm, seed, device)
+        g = CK.gaps(arch, w, dm, prompts, sample, knobs["max_len"])
         if control:
-            cg = CK.gaps(w, dm, prompts, sample, knobs["max_len"], "fp8")
+            cg = CK.gaps(arch, w, dm, prompts, sample, knobs["max_len"],
+                         "fp8")
             extra["control_gaps"] = cg.tolist()
             extra["control_checks"] = CK.checks(cg, run.records,
                                                 knobs["max_logit_gap"])
@@ -396,7 +407,7 @@ def _describe(run: Run):
         f"{max((g[2] for g in gcs), default=0.0):.4f} s; generation 2: "
         + ", ".join(f"{g[0] - run.w0:.2f}+{g[2]:.4f}" for g in gcs
                     if g[1] == 2))
-    for s in sorted(st, key=lambda s: s.t0 - s.t1)[:3]:
+    for s in sorted(st, key=lambda s: s.t0 - s.t1)[:8]:
         log(f"long step at {s.t0 - run.w0:.2f} s: {(s.t1 - s.t0) * 1e3:.1f} "
             f"ms, {len(s.decode_ctx)} rows (context max "
             f"{max(s.decode_ctx, default=0)}), chunks {s.prefill}, packed "

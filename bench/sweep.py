@@ -35,6 +35,11 @@ def row(rate: float, seed: int, result: dict, run) -> dict:
             and run.w0 <= r["times"][-1] <= run.w1]
     st = run.window_steps()
     rows = [len(s.decode_ctx) for s in st]
+    # answers still streaming when the window closed, and the tokens they
+    # still owed: output short of the offered rate with these alone, and
+    # no row ever missing, is answers cut off by the close, not a queue
+    open_ = [r for r in due if r["times"] and r["times"][0] <= run.w1
+             and not (r["done"] and r["times"][-1] <= run.w1)]
     return {"rate_rps": rate, "seed": seed, "due": len(due),
             "no_token_in_window": len(late), "completed": len(done),
             "output_tok_s": read_metric("output_tok_s", run),
@@ -48,6 +53,11 @@ def row(rate: float, seed: int, result: dict, run) -> dict:
             "token_gap_p95_ms": read_metric("token_gap_p95_ms", run),
             "step_ms_p50": read_metric("step_ms_p50", run),
             "decode_rows_mean": sum(rows) / max(len(rows), 1),
+            "decode_rows_max": max(rows, default=0),
+            "streaming_at_close": len(open_),
+            "owed_at_close_tok": sum(
+                r["max_tokens"] - sum(t <= run.w1 for t in r["times"])
+                for r in open_),
             "kv_used_frac": read_metric("kv_used_frac", run),
             "compiles_in_window": len(run.compiles_in_window),
             "failed": result["failed"]}

@@ -137,16 +137,20 @@ def _prime(rng, mix, knobs, vocab, n: int) -> list:
     return primes
 
 
-def warmup(knobs: dict, vocab: int, seed: int) -> list:
+def warmup(mix: dict, knobs: dict, vocab: int, seed: int) -> list:
     """Groups of requests that set-up sends before anything else, one group
     at a time, so the server compiles (or loads from the compilation
     cache) the programs the cell's traffic will ask for, and not inside
     the window. Prompt lengths ``k * B + r`` (B = the cell's token budget)
     put a prompt's last prefill chunk at every power-of-two start bucket
     (k) with every power-of-two chunk size (r) the budget allows, once
-    alone and once followed at once by a second prompt (so a step runs two
-    chunks together); lengths up to ``max_len`` also visit every decode
-    table bucket. Each asks for two tokens, greedy and sampled."""
+    alone and once followed at once by a second prompt, queued behind it;
+    lengths up to ``max_len`` also visit every decode table bucket. Each
+    asks for two tokens, greedy and sampled.
+
+    A cell file's ``warm_chunks`` (true) adds ``chunk_groups``: every
+    chunk-prefill bucket that one or two chunks a step reach under
+    open-loop traffic, for prompts up to the mix's longest."""
     B, cap = int(knobs["token_budget"]), int(knobs["max_len"]) - 4
     rng = np.random.default_rng([int(seed), 3])
     starts, seen = [], set()
@@ -166,7 +170,67 @@ def warmup(knobs: dict, vocab: int, seed: int) -> list:
                                 "max_tokens": 2, "temperature": t,
                                 "seed": int(rng.integers(0, 2 ** 31 - 1))}
                                for n, t in zip(lens, (GREEDY, 0.8))])
+    if knobs.get("warm_chunks"):
+        longest = int(mix["prompt_len"]["max"])
+        firsts = [16 << i for i in range(B.bit_length()) if 16 << i < B]
+        for lens, temps in chunk_groups(B, firsts + [
+                a for a in starts if 0 < a <= longest]):
+            groups.append([{"prompt": rng.integers(2, vocab,
+                                                   size=n).tolist(),
+                            "max_tokens": 2, "temperature": t,
+                            "seed": int(rng.integers(0, 2 ** 31 - 1))}
+                           for n, t in zip(lens, temps)])
     return groups
+
+
+def chunk_groups(B: int, starts) -> list:
+    """``(prompt lengths, temperatures)`` of warm-up groups, each sent
+    together to an idle server with the token budget ``B``, that run the
+    chunk-prefill program at every bucket (chunks in the step, context,
+    chunk) that open-loop traffic reaches with one or two chunks a step.
+    A first chunk alone starts at 0 and fills what a whole prompt before
+    it left of the budget: for every chunk bucket ``s`` from 16 to
+    ``B / 2``, a whole prompt of ``B - s`` and then one that gets ``s``.
+    A prompt's last chunk starts at ``a``: at a multiple of ``B`` after
+    whole-budget chunks, or at any multiple of 16 after a partial first
+    chunk that filled what the budget had left. For each ``a`` in
+    ``starts`` and every chunk bucket ``s`` (1 token to ``B``):
+
+    * ``a`` < ``B``: a lead prompt of ``2 B - a`` goes first and takes the
+      budget for two steps; in the second the prompt A of ``a + r`` gets a
+      partial first chunk of ``a``; in the third (the lead decodes one
+      token) A's last chunk of ``r`` runs alone (context bucket of
+      ``a``, chunk bucket ``s``). ``a`` >= ``B``: A alone, its chunks
+      whole budgets; ``r`` < 16 only (``warmup``'s own groups run the
+      rest);
+    * the same, then a whole prompt and one more that gets a partial first
+      chunk of ``n`` in the step of A's last chunk: two chunks whose
+      larger is in bucket ``s`` (16 up); the last is greedy or sampled by
+      turns (the step samples both rows).
+
+    Every group arrives at an idle server whose first request holds the
+    budget while the others queue, as ``warmup``'s pairs do."""
+    sizes = [1 << i for i in range(B.bit_length()) if 1 << i <= B]
+    out = [((B - s, B), (GREEDY, GREEDY)) for s in sizes if 16 <= s < B]
+    for a in starts:
+        lead = [2 * B - a] if a < B else []
+        room = B - len(lead)          # the lead decodes in A's last step
+        for i, s in enumerate(sizes):
+            r = s if s < room else room // 2 + 16
+            if a < B or s < 16:
+                out.append((lead + [a + r], (GREEDY,) * (len(lead) + 1)))
+            if s < 16:
+                continue
+            r = min(s, room // 2 + 16)
+            w = room - r - s - 8      # a whole prompt that leaves ~s
+            n = room - r - max(w, 0)
+            n -= n % 16               # the partial chunk, block-aligned
+            if n < 16 or max(r, n) > s or 2 * max(r, n) <= s:
+                continue              # a budget this small cannot
+            lens = lead + [a + r] + ([w] if w > 0 else []) + [n + 32]
+            out.append((lens, (GREEDY,) * (len(lens) - 1)
+                        + ((GREEDY, 0.8)[i % 2],)))
+    return out
 
 
 def generate(mix: dict, knobs: dict, vocab: int, seed: int,
@@ -177,7 +241,7 @@ def generate(mix: dict, knobs: dict, vocab: int, seed: int,
     spec = {"mode": mix["loop"], "window_s": float(seconds),
             "lead_in_s": float(mix["lead_in_s"]),
             "grace_s": float(mix.get("grace_s", 60.0)),
-            "warmup": warmup(knobs, vocab, seed)}
+            "warmup": warmup(mix, knobs, vocab, seed)}
     if mix["loop"] == "open":
         primes = _prime(rng, mix, knobs, vocab, int(knobs["prime_streams"]))
         rate = float(knobs["rate_rps"])

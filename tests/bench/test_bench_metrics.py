@@ -12,7 +12,9 @@ from bench import run as R
 from bench import spans as S
 from bench import trace_reduce as TR
 
-DM = M.dims(M.load_config("mistral-7b-v0.3"))
+CONF = M.load_config("mistral-7b-v0.3")
+ARCH = M.arch(CONF)
+DM = ARCH.dims(CONF)
 PEAK = json.load(open(R.BENCH / "peaks.json"))["TPU v5 lite"]
 
 
@@ -31,8 +33,9 @@ def step(t0, t1, packed=8, ctx=(), prefill=(), used=10, pre=0, instance=0,
 
 def make_run(records=(), steps=(), flight=(), traces=(), trace=None,
              trace_host=None, mem=None, cell=None):
-    return R.Run(cell=cell or {}, conf={}, dm=DM, mix={}, knobs={}, seed=1,
-                 seconds=10.0, setup_s=42.5, w0=100.0, w1=110.0, t_end=130.0,
+    return R.Run(cell=cell or {}, conf={}, dm=DM, arch=ARCH, mix={},
+                 knobs={}, seed=1, seconds=10.0, setup_s=42.5, w0=100.0,
+                 w1=110.0, t_end=130.0,
                  records=list(records), steps=list(steps),
                  traces=list(traces), flight=list(flight), peak=PEAK,
                  memory_peak_bytes=mem, compiles_in_window=[],
@@ -202,8 +205,8 @@ def test_device_metrics_from_a_trace():
     need = 2 * DM["L"] * F.roofline_seconds(fl, by, PEAK)
     assert read("paged_decode_roofline", run) == pytest.approx(
         100 * need / 0.2)
-    model = 2 * sum(F.decode_token_flops(DM, c) for c in ctx) + \
-        F.prefill_chunk_flops(DM, 0, 64, True)
+    model = 2 * sum(ARCH.decode_token_flops(DM, c) for c in ctx) + \
+        ARCH.prefill_chunk_flops(DM, 0, 64, True)
     assert read("step_mfu", run) == pytest.approx(
         100 * model / PEAK["bf16_flops"])
     assert _trace().top_gaps(1) == [["pump_push_streams", 0.35]]

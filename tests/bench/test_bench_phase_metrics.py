@@ -16,13 +16,16 @@ from repro.serving import instrument as INS
 from repro.serving.engine import Engine
 from repro.serving.request import RequestSpec
 
-DM = M.dims(M.load_config("yi-1.5-9b"))
+CONF = M.load_config("yi-1.5-9b")
+ARCH = M.arch(CONF)
+DM = ARCH.dims(CONF)
 PEAK = json.load(open(R.BENCH / "peaks.json"))["TPU v5 lite"]
 
 
 def make_run(flight, records=()):
-    return R.Run(cell={}, conf={}, dm=DM, mix={}, knobs={}, seed=1,
-                 seconds=10.0, setup_s=1.0, w0=100.0, w1=110.0, t_end=111.0,
+    return R.Run(cell={}, conf={}, dm=DM, arch=ARCH, mix={}, knobs={},
+                 seed=1, seconds=10.0, setup_s=1.0, w0=100.0, w1=110.0,
+                 t_end=111.0,
                  records=[], steps=list(records), traces=[],
                  flight=list(flight),
                  peak=PEAK, memory_peak_bytes=None, compiles_in_window=[],

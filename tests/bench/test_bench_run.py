@@ -37,15 +37,12 @@ SMALL = {"conf": {"num_hidden_layers": 3, "hidden_size": 256,
 KERNEL = {"paged_attn_impl": "kernel", "interpret": True}
 
 
-# the chat cell is deferred (not in BENCHMARK.json until its knee is swept
-# on the chip); its files stay, and its rehearsal keeps the open loop covered
-CHAT = {"name": "mistral-7b-v0.3.chat", "config": "mistral-7b-v0.3",
-        "traffic": "chat", "chips": 1}
-# the pod cell is deferred too (the program cannot hold its plan on the
-# chip); its rehearsal keeps the pod path of the harness covered
+# the pod cell is deferred (the program cannot hold its plan on the chip);
+# its files stay, and its rehearsal keeps the pod path of the harness
+# covered
 BURST = {"name": "mistral-7b-v0.3.burst-scale", "config": "mistral-7b-v0.3",
          "traffic": "chat-burst", "chips": 4}
-DEFERRED = {c["name"]: c for c in (CHAT, BURST)}
+DEFERRED = {BURST["name"]: BURST}
 
 
 def cell(name):
@@ -162,3 +159,22 @@ def test_check_catches_a_broken_decode_step(fault, tmp_path, monkeypatch):
     result, run, checks, extra = rehearse("mistral-7b-v0.3.chat", tmp_path)
     assert not result["correct"]
     assert max(extra["gaps"]) > run.knobs["max_logit_gap"]
+
+
+def test_sweep_row_accounts_for_the_window(tmp_path, monkeypatch):
+    # bench/sweep.py runs a cell at a given rate, as bench/run.py does it,
+    # without the check and without grace; its row says what was due, what
+    # came out, and what was still streaming when the window closed
+    monkeypatch.syspath_prepend(str(R.BENCH))
+    import sweep
+    monkeypatch.setattr(sweep, "BENCH", tmp_path)     # its runs' output
+    row, = sweep.sweep(BENCH, cell("mistral-7b-v0.3.chat"), [4.0],
+                       [2 ** 33 + 9], 3.0, jax.devices()[0],
+                       overrides=SMALL, engine_kw=KERNEL)
+    assert row["rate_rps"] == 4.0 and row["due"] == 12
+    assert row["output_tok_s"] > 0 and row["compiles_in_window"] >= 0
+    assert 0 < row["decode_rows_max"] <= SMALL["knobs"]["max_batch"]
+    started = row["due"] - row["no_token_in_window"]
+    assert 0 < started and 0 <= row["streaming_at_close"] <= started
+    # each answer still streaming owes at least one token
+    assert row["owed_at_close_tok"] >= row["streaming_at_close"]

@@ -137,3 +137,72 @@ def test_bursts_start_with_the_lead_in_and_with_the_window():
         mix, int(round(rate * 75)), 51.0, 4))
     assert np.array_equal(_dues(spec, "lead"), TF._arrivals(
         mix, int(round(rate * 18)), lead, 3))
+
+
+def _replay(B, groups):
+    """Each group submitted at once to an idle paged engine with a budget
+    of ``B`` tokens (blocks of 16) and served to its end; per group, the
+    chunk-prefill programs it ran, as (chunks in the step, context bucket,
+    chunk bucket)."""
+    import jax
+
+    from repro.configs import get_config
+    from repro.models import transformer as T
+    from repro.serving.engine import Engine
+    from repro.serving.request import RequestSpec, SamplingParams
+
+    cfg = get_config("tinyllama-1.1b").reduced()
+    eng = Engine(cfg, T.init_params(cfg, jax.random.PRNGKey(0), "float32"),
+                 max_batch=4, max_len=256, cache_kind="paged", block_size=16,
+                 scheduler="budget", token_budget=B)
+    seen, real = set(), eng._prefill_shared_batch
+
+    def shared(slots, toks, ctxs, cb, Sb, n_real=None):
+        seen.add((len(slots), cb, Sb))
+        return real(slots, toks, ctxs, cb, Sb, n_real=n_real)
+
+    eng._prefill_shared_batch = shared
+    rng, rid, out = np.random.default_rng(0), 0, []
+    for lens, temps in groups:
+        seen.clear()
+        for n, t in zip(lens, temps):
+            rid += 1
+            eng.submit(RequestSpec(
+                rid=rid, prompt=rng.integers(2, 200, size=n, dtype=np.int32),
+                max_tokens=2, sampling=SamplingParams(temperature=t,
+                                                      seed=rid)))
+        for _ in range(50):
+            eng.step()
+            if not (eng.queue or eng.active or eng.prefilling):
+                break
+        else:
+            raise AssertionError(f"group {lens} unfinished")
+        out.append(set(seen))
+    return out
+
+
+def test_chunk_groups_reach_every_chunk_bucket():
+    B, starts = 64, [16, 32, 64, 128]
+    seen = set().union(*_replay(B, TF.chunk_groups(B, starts)))
+    sizes = (1, 2, 4, 8, 16, 32, 64)
+    want = {(1, 1, r) for r in sizes[4:]}     # a first chunk alone
+    want |= {(1, a, r) for a in starts for r in sizes if a < B or r < 16}
+    want |= {(2, a, s) for a in starts for s in sizes[4:]}
+    assert want <= seen, want - seen
+
+
+def test_chunk_groups_each_reach_a_bucket_the_base_warmup_does_not():
+    # warmup's own groups run first; every chunk group must then run a
+    # chunk-prefill program that they did not
+    B = 64
+    knobs = {"token_budget": B, "max_len": 256}
+    mix = {"prompt_len": {"max": 192}}
+    base = TF.warmup(mix, knobs, 200, 1)
+    chunked = TF.warmup(mix, {**knobs, "warm_chunks": True}, 200, 1)
+    assert chunked[:len(base)] == base and len(chunked) > len(base)
+    groups = [([len(r["prompt"]) for r in g], [r["temperature"] for r in g])
+              for g in chunked]
+    reached = _replay(B, groups)
+    before = set().union(*reached[:len(base)])
+    for g, keys in zip(groups[len(base):], reached[len(base):]):
+        assert keys - before, (g, keys)
