@@ -255,36 +255,39 @@ def paged_kernel_serves(cfg: ModelConfig, window) -> bool:
 
 
 def apply_gqa_paged(p, x, cfg: ModelConfig, *, positions, pool_k, pool_v,
-                    block_tables, active, window=None,
+                    layer, block_tables, active, window=None,
                     impl="gather", interpret=False, batch_shard=None):
     """GQA decode attention against a paged block pool (write-then-attend).
 
-    Single-token decode only: x [B,1,d]. ``pool_k/v`` are ONE layer's pool
-    slices [n_blocks, KV, bs, hd] (KV-head-major — the Pallas kernel's
-    native tile layout); ``block_tables`` [B, max_blocks] int32
-    (-1 = unallocated, masked); ``positions`` [B,1] are the pre-write
-    token counts (the new token lands at position ``positions[b,0]``);
-    ``active`` [B] bool — inactive rows write nothing (their scatter index
-    is pushed out of bounds and dropped) and attend over an empty,
-    fully-masked context, producing garbage logits the engine ignores.
+    Single-token decode only: x [B,1,d]. ``pool_k/v`` are the WHOLE pools
+    [L, n_blocks, KV, bs, hd] (KV-head-major — the Pallas kernel's native
+    tile layout) and ``layer`` is this layer's index into them (an int or
+    an int32 scalar): the layer is read and written where it lies, never
+    sliced out. ``block_tables`` [B, max_blocks] int32 (-1 = unallocated,
+    masked); ``positions`` [B,1] are the pre-write token counts (the new
+    token lands at position ``positions[b,0]``); ``active`` [B] bool —
+    inactive rows write nothing and attend over an empty, fully-masked
+    context, producing garbage logits the engine ignores.
 
-    ``impl``: "gather" materializes the table's blocks with a batched
-    gather and reuses the chunked fp32 attention (jit-friendly anywhere);
-    "kernel" calls the Pallas paged-decode kernel (kernels/paged_decode.py)
-    whose HBM traffic stops at each request's true length. The kernel
-    implements plain causal GQA only (``paged_kernel_serves``): asking it
-    to serve a logit softcap or a sliding window raises. The Engine makes
-    that choice once, at construction. ``batch_shard`` = (mesh, batch
-    PartitionSpec) of a replicated layer: the compiler cannot partition a
-    Pallas kernel, so the kernel is mapped over the batch shards there
-    (each shard reads the replicated pool).
+    ``impl``: "gather" writes the new tokens with an XLA scatter, then
+    materializes the table's blocks with a batched gather and reuses the
+    chunked fp32 attention (jit-friendly anywhere); "kernel" calls the
+    Pallas paged-decode kernel (kernels/paged_decode.py), which writes
+    each row's token into its page in place and whose HBM traffic stops
+    at each request's true length. The kernel implements plain causal GQA
+    only (``paged_kernel_serves``): asking it to serve a logit softcap or
+    a sliding window raises. The Engine makes that choice once, at
+    construction. ``batch_shard`` = (mesh, batch PartitionSpec) of a
+    replicated layer: the compiler cannot partition a Pallas kernel, so
+    the kernel is mapped over the batch shards there (``_shard_rows``).
 
     Returns (out [B,1,d], new_pool_k, new_pool_v).
     """
+    from repro.kernels import paged_decode as PD
     B, S, _ = x.shape
     assert S == 1, "paged path is decode-only (one token per step)"
     hd = cfg.resolved_head_dim
-    n_blocks, KV, bs, _ = pool_k.shape
+    KV = pool_k.shape[2]
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
@@ -294,49 +297,68 @@ def apply_gqa_paged(p, x, cfg: ModelConfig, *, positions, pool_k, pool_v,
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
 
-    # ---- write the new token's K/V into its block (inactive rows drop)
-    pos = positions[:, 0]
-    tbl_col = jnp.clip(pos // bs, 0, block_tables.shape[1] - 1)
-    blk = jnp.take_along_axis(block_tables, tbl_col[:, None], axis=1)[:, 0]
-    blk = jnp.where(active & (blk >= 0), blk, n_blocks)  # OOB -> dropped
-    off = pos % bs
-    new_k = pool_k.at[blk, :, off].set(k[:, 0].astype(pool_k.dtype),
-                                       mode="drop")
-    new_v = pool_v.at[blk, :, off].set(v[:, 0].astype(pool_v.dtype),
-                                       mode="drop")
-
+    pos = positions[:, 0].astype(jnp.int32)
+    new_pos = jnp.where(active, pos, -1)          # inactive rows write nothing
     kv_len = jnp.where(active, pos + 1, 0).astype(jnp.int32)
     if impl == "kernel":
         if not paged_kernel_serves(cfg, window):
             raise ValueError("the paged decode kernel has no logit softcap "
                              "or sliding window; use impl='gather'")
-        from repro.kernels.paged_decode import paged_decode_attention
-        kernel = functools.partial(paged_decode_attention,
+        kernel = functools.partial(PD.paged_decode_attention,
                                    interpret=interpret)
         if batch_shard is not None:
-            mesh, spec = batch_shard
-            b = PartitionSpec(*spec)
-            kernel = jax.shard_map(
-                kernel, mesh=mesh, in_specs=(b, PartitionSpec(),
-                                             PartitionSpec(), b, b),
-                out_specs=b, check_vma=False)
-        out = kernel(q[:, 0], new_k, new_v, block_tables, kv_len)[:, None]
+            kernel = _shard_rows(kernel, *batch_shard)
+        out, pool_k, pool_v = kernel(q[:, 0], pool_k, pool_v, layer,
+                                     block_tables, kv_len, k[:, 0], v[:, 0],
+                                     new_pos)
+        out = out[:, None]
     else:
-        max_blocks = block_tables.shape[1]
+        pool_k = PD.scatter_new_tokens(pool_k, k[:, 0], layer, block_tables,
+                                       new_pos)
+        pool_v = PD.scatter_new_tokens(pool_v, v[:, 0], layer, block_tables,
+                                       new_pos)
         tbl = jnp.maximum(block_tables, 0).astype(jnp.int32)
         # gathered blocks are [B, mb, KV, bs, hd]; only the gathered
         # context is re-laid token-major, never the whole pool
-        kk = new_k[tbl].transpose(0, 1, 3, 2, 4).reshape(
-            B, max_blocks * bs, KV, hd)
-        vv = new_v[tbl].transpose(0, 1, 3, 2, 4).reshape(
-            B, max_blocks * bs, KV, hd)
+        kk = pool_k[layer][tbl].transpose(0, 1, 3, 2, 4).reshape(
+            B, -1, KV, hd)
+        vv = pool_v[layer][tbl].transpose(0, 1, 3, 2, 4).reshape(
+            B, -1, KV, hd)
         out = attention(q, kk, vv, q_positions=positions, kv_len=kv_len,
-                        k_positions=jnp.arange(max_blocks * bs,
-                                               dtype=jnp.int32),
+                        k_positions=jnp.arange(kk.shape[1], dtype=jnp.int32),
                         window=window, causal=True,
                         softcap=cfg.logit_softcap)
     out = out.reshape(B, S, cfg.num_heads * hd)
-    return jnp.einsum("bsh,hd->bsd", out, p["wo"]), new_k, new_v
+    return jnp.einsum("bsh,hd->bsd", out, p["wo"]), pool_k, pool_v
+
+
+def _shard_rows(kernel, mesh, spec):
+    """The paged kernel of a replicated layer, mapped over the batch
+    shards of ``spec``. Each shard attends its own rows over the
+    replicated pool, and every device writes EVERY row's new token (the
+    rows come in gathered), so the pool's replicas stay identical: a
+    shard's rows of other shards attend nothing (length 0) and only
+    write."""
+    axes = spec[0]
+    if axes is None:                         # degree 1: one shard, all rows
+        return kernel
+    n = math.prod(mesh.shape[a] for a in axes)
+
+    def local(q, pool_k, pool_v, layer, tables, kv_len, new_k, new_v,
+              new_pos):
+        rows = q.shape[0] // n
+        shard = jax.lax.axis_index(axes)
+        mine = jnp.arange(q.shape[0]) // rows == shard
+        out, pool_k, pool_v = kernel(q, pool_k, pool_v, layer, tables,
+                                     jnp.where(mine, kv_len, 0), new_k,
+                                     new_v, new_pos)
+        return (jax.lax.dynamic_slice_in_dim(out, shard * rows, rows),
+                pool_k, pool_v)
+
+    every = PartitionSpec()
+    return jax.shard_map(local, mesh=mesh, in_specs=(every,) * 9,
+                         out_specs=(PartitionSpec(axes), every, every),
+                         check_vma=False)
 
 
 def compute_cross_kv(p, enc_out):

@@ -412,34 +412,33 @@ def forward_paged(params, cfg: ModelConfig, tokens, cache, *, window=None,
     x = lshard(x, "batch", "seq", None)
 
     def body(carry, xs, batch_shard=None):
-        x, aux = carry
-        lp, kl, vl = xs
+        x, aux, k, v = carry
+        lp, i = xs
         h = L.apply_norm(lp["norm1"], x, cfg)
-        h, kl, vl = L.apply_gqa_paged(
-            lp["attn"], h, cfg, positions=positions, pool_k=kl, pool_v=vl,
-            block_tables=cache["block_tables"], active=active,
+        h, k, v = L.apply_gqa_paged(
+            lp["attn"], h, cfg, positions=positions, pool_k=k, pool_v=v,
+            layer=i, block_tables=cache["block_tables"], active=active,
             window=window, impl=attn_impl, interpret=interpret,
             batch_shard=batch_shard)
         x = _residual(x, h)
         x, a = _mlp_sublayer(lp, x, cfg, "auto")
-        return (x, aux + a), (kl, vl)
+        return (x, aux + a, k, v), None
 
+    # the whole pool rides in the carry and each layer writes its own
+    # slice in place: scanning over the pool as xs/ys would copy a layer
+    # out and stack it back, every layer, every step
+    carry = (x, jnp.float32(0.0), cache["k"], cache["v"])
     if layer_hook is None:
-        (x, aux), (nk, nv) = jax.lax.scan(
-            body, (x, jnp.float32(0.0)),
-            (params["layers"], cache["k"], cache["v"]))
+        carry, _ = jax.lax.scan(
+            body, carry,
+            (params["layers"], jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     else:
-        aux = jnp.float32(0.0)
-        nks, nvs = [], []
         for i in range(cfg.num_layers):
-            x = layer_hook(i, x)
-            (x, aux), (kl, vl) = body(
-                (x, aux), (_layer_slice(params["layers"], i),
-                           cache["k"][i], cache["v"][i]),
+            carry = (layer_hook(i, carry[0]), *carry[1:])
+            carry, _ = body(
+                carry, (_layer_slice(params["layers"], i), i),
                 batch_shard=(layer_hook.mesh, layer_hook.batch_spec(i)))
-            nks.append(kl)
-            nvs.append(vl)
-        nk, nv = jnp.stack(nks), jnp.stack(nvs)
+    x, aux, nk, nv = carry
     new_cache = dict(cache, k=nk, v=nv,
                      lengths=lengths + active.astype(jnp.int32))
     return unembed(params, cfg, x[:, -1]), new_cache, aux

@@ -1131,9 +1131,10 @@ class Engine:
                 try:
                     PK.allocate(self.pstate, slot, 1)
                     if self.prefix_sharing:
-                        # copy-on-write: the fused step scatters this
-                        # slot's next token into column lengths//bs — fork
-                        # it now if it is still shared with another stream
+                        # copy-on-write: the fused step writes this
+                        # slot's next token into column lengths//bs in
+                        # place — fork it now if it is still shared with
+                        # another stream
                         PK.ensure_writable(self.pstate, slot,
                                            int(self.pstate.lengths[slot]), 1)
                     break

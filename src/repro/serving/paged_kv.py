@@ -70,9 +70,12 @@ Division of labour with the engine:
   only the blocks touched after a phase-1 snapshot — the short delta a
   two-phase migration pause-copies while the bulk streamed overlapped
   with decode (``import_blocks_delta`` applies it over the staged base);
-* the per-step decode read is ``models.transformer.forward_paged`` — a
-  gather over the block table inside the jitted step, or the Pallas kernel
-  in kernels/paged_decode.py;
+* the per-step decode read and write is ``models.transformer.forward_paged``
+  — an XLA scatter of each row's new token plus a gather over the block
+  table inside the jitted step, or the Pallas kernel in
+  kernels/paged_decode.py, which reads ``pool[layer, block]`` pages of the
+  whole pool and writes each new token into its page in place (the step
+  never copies the pool);
 * ``paged_attention_ref`` below is the vectorized pure-jnp oracle both
   are tested against.
 """

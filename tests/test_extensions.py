@@ -1,6 +1,7 @@
 """Production-feature extensions: paged KV, chunked prefill, sampling,
 heterogeneous scale-up, gradient accumulation, traffic traces — plus
 hypothesis property tests on attention causality."""
+import dataclasses
 import inspect
 import itertools
 
@@ -44,15 +45,25 @@ def _filled_state(cfg, lens, block_size=8):
     return state
 
 
+def _attend(q, k_pool, v_pool, layer, tables, lengths):
+    """The kernel's output with nothing written (every ``new_pos`` -1)."""
+    B, (KV, D) = q.shape[0], k_pool.shape[2::2]
+    none = jnp.zeros((B, KV, D), k_pool.dtype)
+    out, _, _ = paged_decode_attention(
+        q, k_pool, v_pool, layer, tables, lengths, none, none,
+        jnp.full((B,), -1, jnp.int32), interpret=True)
+    return out
+
+
 def test_paged_kernel_matches_ref():
     cfg = get_config("tinyllama-1.1b").reduced()
     state = _filled_state(cfg, [20, 7, 33])
     q = jax.random.normal(KEY, (3, cfg.num_kv_heads,
                                 cfg.resolved_head_dim), jnp.float32)
     ref = PK.paged_attention_ref(q, state, [0, 1, 2], layer=0)
-    out = paged_decode_attention(
-        q, state.k[0], state.v[0], jnp.asarray(state.block_tables),
-        jnp.asarray(state.lengths[:3]), interpret=True)
+    out = _attend(
+        q, state.k, state.v, 0, jnp.asarray(state.block_tables),
+        jnp.asarray(state.lengths[:3]))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=3e-5, atol=3e-5)
 
@@ -114,10 +125,9 @@ def test_paged_kernel_gqa_matches_ref(dtype, tol, H, KV, hd):
     state = _raw_state([20, 7, 33], KV=KV, hd=hd, dtype=dtype)
     q = jax.random.normal(KEY, (3, H, hd), jnp.float32)
     ref = PK.paged_attention_ref(q.astype(dtype), state, [0, 1, 2], layer=0)
-    out = paged_decode_attention(
-        q.astype(dtype), state.k[0], state.v[0],
-        jnp.asarray(state.block_tables), jnp.asarray(state.lengths),
-        interpret=True)
+    out = _attend(
+        q.astype(dtype), state.k, state.v, 0,
+        jnp.asarray(state.block_tables), jnp.asarray(state.lengths))
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                rtol=tol, atol=tol)
@@ -132,15 +142,15 @@ def test_paged_kernel_ragged_block_boundaries():
     q = jax.random.normal(KEY, (4, 4, 64), jnp.float32)
     ref = PK.paged_attention_ref(q, state, [0, 1, 2, 3], layer=0)
     lengths = np.array(lens, np.int32)
-    out = paged_decode_attention(
-        q, state.k[0], state.v[0], jnp.asarray(state.block_tables),
-        jnp.asarray(lengths), interpret=True)
+    out = _attend(
+        q, state.k, state.v, 0, jnp.asarray(state.block_tables),
+        jnp.asarray(lengths))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=3e-5, atol=3e-5)
     lengths[1] = 0  # deactivate a slot
-    out0 = paged_decode_attention(
-        q, state.k[0], state.v[0], jnp.asarray(state.block_tables),
-        jnp.asarray(lengths), interpret=True)
+    out0 = _attend(
+        q, state.k, state.v, 0, jnp.asarray(state.block_tables),
+        jnp.asarray(lengths))
     assert (np.asarray(out0[1]) == 0).all()
 
 
@@ -173,9 +183,9 @@ def test_paged_kernel_chunk_boundaries(hd, dtype, tol):
     ref = PK.paged_attention_ref(q, state, range(n), layer=0)
     lengths = state.lengths.copy()
     lengths[-1] = 0
-    out = paged_decode_attention(
-        q, state.k[0], state.v[0], jnp.asarray(state.block_tables),
-        jnp.asarray(lengths), interpret=True)
+    out = _attend(
+        q, state.k, state.v, 0, jnp.asarray(state.block_tables),
+        jnp.asarray(lengths))
     np.testing.assert_allclose(np.asarray(out[:-1], np.float32),
                                np.asarray(ref[:-1], np.float32),
                                rtol=tol, atol=tol)
@@ -193,14 +203,134 @@ def test_paged_kernel_shard_invariant(hd):
                        dtype=jnp.bfloat16)
     q = jax.random.normal(KEY, (8, 32, hd), jnp.float32).astype(jnp.bfloat16)
     args = (jnp.asarray(state.block_tables), jnp.asarray(lens, jnp.int32))
-    full = paged_decode_attention(q, state.k[0], state.v[0], *args,
-                                  interpret=True)
+    full = _attend(q, state.k, state.v, 0, *args)
     for rows in (slice(0, 2), slice(2, 4), slice(4, 6), slice(6, 8)):
-        part = paged_decode_attention(
-            q[rows], state.k[0], state.v[0], *(a[rows] for a in args),
-            interpret=True)
+        part = _attend(
+            q[rows], state.k, state.v, 0, *(a[rows] for a in args))
         assert np.array_equal(np.asarray(full[rows], np.float32),
                               np.asarray(part, np.float32))
+
+
+def _layered_case(hd, dtype, *, L=3, H=32, KV=4, bs=16):
+    """A pool of L layers, each with its own random contents, and rows: one
+    with no token (inactive) first, then rows whose new tokens land on a
+    row's first position, at offset bs - 1, on a block's first token, at
+    the start of a chunk of P pages and mid block, another inactive row,
+    and a row that writes but attends nothing (a replicated layer's row of
+    another shard). Every row owns its blocks, and some blocks belong to
+    no row."""
+    itemsize = jnp.dtype(dtype).itemsize
+    P = PD.chunk_pages(bs, KV, hd, itemsize, 1 << 20)
+    new_pos = np.array([-1, 0, bs - 1, 2 * bs, P * bs, 3 * bs + 5, -1,
+                        bs + 2], np.int32)
+    lengths = np.where(new_pos >= 0, new_pos + 1, 0).astype(np.int32)
+    lengths[-1] = 0                                  # writes, attends nothing
+    held = [max(p, 0) // bs + 1 for p in new_pos]
+    n_blocks = sum(held) + 5
+    order = np.random.default_rng(3).permutation(n_blocks)
+    tables = np.full((len(held), max(held) + 1), -1, np.int32)
+    used = 0
+    for row, n in enumerate(held):
+        tables[row, :n] = order[used:used + n]
+        used += n
+    rng = np.random.default_rng(11)
+    pool_k, pool_v = (jnp.asarray(rng.normal(size=(L, n_blocks, KV, bs, hd)),
+                                  dtype) for _ in range(2))
+    q = jax.random.normal(KEY, (len(held), H, hd), jnp.float32).astype(dtype)
+    new_k, new_v = (jnp.asarray(rng.normal(size=(len(held), KV, hd)), dtype)
+                    for _ in range(2))
+    return (q, pool_k, pool_v, jnp.asarray(tables), jnp.asarray(lengths),
+            (new_k, new_v), jnp.asarray(new_pos))
+
+
+def _state_of(pool_k, pool_v, tables, lengths):
+    return PK.PagedState(k=pool_k, v=pool_v, block_tables=np.asarray(tables),
+                         lengths=np.asarray(lengths), free=[],
+                         block_size=pool_k.shape[3])
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("hd", [64, 128])
+def test_paged_kernel_reads_its_layer(hd, layer):
+    """The layer index picks the layer: over a pool whose layers hold
+    different contents, each layer's output matches the reference at
+    that layer (head_dim 128: the chunked page copies; 64: the pipelined
+    pages)."""
+    q, pool_k, pool_v, tables, lengths, _, _ = _layered_case(hd, jnp.float32)
+    out = _attend(q, pool_k, pool_v, layer, tables, lengths)
+    ref = PK.paged_attention_ref(
+        q, _state_of(pool_k, pool_v, tables, lengths), range(len(q)),
+        layer=layer)
+    attend = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(out)[attend],
+                               np.asarray(ref)[attend], rtol=3e-5, atol=3e-5)
+    other = PK.paged_attention_ref(
+        q, _state_of(pool_k, pool_v, tables, lengths), range(len(q)),
+        layer=(layer + 1) % 3)
+    assert not np.allclose(np.asarray(out)[attend],
+                           np.asarray(other)[attend], atol=1e-2)
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 3e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_paged_kernel_write_matches_scatter(hd, dtype, tol):
+    """Each row's new token lands where the XLA scatter puts it, bit for
+    bit — at a row's first position, at offsets 0 and bs - 1, on a
+    block's first token and at a chunk's first page — and the row attends
+    over it (write-then-attend); the inactive and the write-only rows
+    yield zeros."""
+    q, pool_k, pool_v, tables, lengths, new, new_pos = _layered_case(
+        hd, dtype)
+    layer = 1
+    out, got_k, got_v = paged_decode_attention(
+        q, pool_k, pool_v, layer, tables, lengths, *new, new_pos,
+        interpret=True)
+    want_k, want_v = (PD.scatter_new_tokens(p, x, layer, tables, new_pos)
+                      for p, x in zip((pool_k, pool_v), new))
+    assert np.array_equal(np.asarray(got_k), np.asarray(want_k))
+    assert np.array_equal(np.asarray(got_v), np.asarray(want_v))
+    ref = PK.paged_attention_ref(
+        q, _state_of(want_k, want_v, tables, lengths), range(len(q)),
+        layer=layer)
+    attend = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(out, np.float32)[attend],
+                               np.asarray(ref, np.float32)[attend],
+                               rtol=tol, atol=tol)
+    assert (np.asarray(out, np.float32)[~attend] == 0).all()
+
+
+@pytest.mark.parametrize("writes", [True, False], ids=["writes", "none"])
+@pytest.mark.parametrize("hd", [64, 128])
+def test_paged_kernel_write_leaves_the_rest(hd, writes):
+    """The write touches exactly one token of one page per writing row, in
+    the given layer: every other token of that page, every other page
+    (the inactive rows' included) and every other layer keep their
+    contents; a batch with no token to write leaves the pools as they
+    were."""
+    q, pool_k, pool_v, tables, lengths, new, new_pos = _layered_case(
+        hd, jnp.bfloat16)
+    if not writes:
+        new_pos = jnp.full_like(new_pos, -1)
+    layer, bs = 2, pool_k.shape[3]
+    _, got_k, got_v = paged_decode_attention(
+        q, pool_k, pool_v, layer, tables, lengths, *new, new_pos,
+        interpret=True)
+    written = np.zeros(pool_k.shape[:2] + (bs,), bool)    # [L, n_blocks, bs]
+    tables, new_pos = np.asarray(tables), np.asarray(new_pos)
+    for row, p in enumerate(new_pos):
+        if p >= 0:
+            written[layer, tables[row, p // bs], p % bs] = True
+    assert written.sum() == (new_pos >= 0).sum()
+    for got, old, x in ((got_k, pool_k, new[0]), (got_v, pool_v, new[1])):
+        got, old = np.asarray(got, np.float32), np.asarray(old, np.float32)
+        keep = ~written[:, :, None, :, None]
+        assert np.array_equal(np.where(keep, got, 0), np.where(keep, old, 0))
+        for row, p in enumerate(new_pos):
+            if p >= 0:
+                assert np.array_equal(
+                    got[layer, tables[row, p // bs], :, p % bs],
+                    np.asarray(x[row], np.float32))
 
 
 def test_chunk_pages_from_shapes():
@@ -249,6 +379,24 @@ def test_paged_engine_matches_dense_greedy():
                        cache_kind="paged", block_size=8,
                        paged_attn_impl="kernel", interpret=True)
     assert kern == {k: v[:3] for k, v in dense.items() if k < 2}
+
+
+def test_paged_kernel_engine_greedy_across_layers():
+    """Greedy streams through the engine with the kernel's chunked side
+    (head_dim 128), each new token written into its page in place, over
+    three layers and prompts that cross block boundaries mid-generation,
+    equal the dense engine's token for token."""
+    cfg = dataclasses.replace(get_config("tinyllama-1.1b").reduced(),
+                              num_layers=3, head_dim=128)
+    params = T.init_params(cfg, KEY, "float32")
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(2, cfg.vocab_size, size=s).astype(np.int32)
+               for s in (6, 13)]
+    dense = _run_engine(cfg, params, prompts, max_new=5, cache_kind="dense")
+    kern = _run_engine(cfg, params, prompts, max_new=5, cache_kind="paged",
+                       block_size=8, paged_attn_impl="kernel",
+                       interpret=True)
+    assert kern == dense
 
 
 def test_paged_engine_out_of_blocks_backpressure():

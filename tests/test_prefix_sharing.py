@@ -16,6 +16,7 @@ import pytest
 from repro.configs import get_config
 from repro.models import transformer as T
 from repro.serving import paged_kv as PK
+from repro.serving import engine as E
 from repro.serving.engine import Engine
 from repro.serving.orchestrator import Orchestrator
 from repro.serving.request import RequestSpec, SamplingParams
@@ -241,6 +242,40 @@ def test_aligned_duplicate_prompt_triggers_cow(tiny):
     assert eng.pstate.cow_forks >= 1
     assert eng.pstate.blocks_in_use() == 0
     _check_invariants(eng.pstate)
+
+
+def test_decode_rows_write_distinct_private_pages(tiny, monkeypatch):
+    """The paged decode step writes each row's new token into its page in
+    place, and relies on no two rows writing one page and no row writing
+    a page another row reads. Before every step the engine has forked the
+    shared blocks a write would touch, so each active row's write page is
+    its own (refcount 1), under prefix sharing with aligned duplicate
+    prompts that alias every prompt block."""
+    cfg, params = tiny
+    dense, paged = E._jitted_steps()
+    eng = Engine(cfg, params, max_batch=4, max_len=64, cache_kind="paged",
+                 block_size=8, prefix_sharing=True)
+    pages_seen = []
+
+    def checked(params, k, v, tables, lengths, active, *rest, **kw):
+        bs = eng.pstate.block_size
+        rows = np.flatnonzero(active)
+        pages = [int(tables[r, lengths[r] // bs]) for r in rows]
+        assert len(set(pages)) == len(pages), pages
+        assert all(eng.pstate.refcount[p] == 1 for p in pages), pages
+        pages_seen.extend(pages)
+        return paged(params, k, v, tables, lengths, active, *rest, **kw)
+
+    monkeypatch.setattr(E, "_jitted_steps", lambda: (dense, checked))
+    prompt = np.random.default_rng(3).integers(
+        2, cfg.vocab_size, size=16).astype(np.int32)
+    for i in range(2):
+        eng.submit(RequestSpec(rid=10 + i, prompt=prompt.copy(),
+                               max_tokens=5))
+    for r in _shared_prompt_requests(cfg, 2):
+        eng.submit(r)
+    eng.run_until_done()
+    assert pages_seen and eng.pstate.cow_forks >= 1
 
 
 def test_sharing_with_preemption_replays_identically(tiny):

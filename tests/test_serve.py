@@ -180,11 +180,11 @@ def test_kernel_impl_refuses_window_inside_the_layer(tiny):
     lp = jax.tree_util.tree_map(lambda p: p[0], params["layers"])["attn"]
     KV, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     jnp = jax.numpy
-    pool = jnp.zeros((4, KV, 8, hd), jnp.float32)
+    pool = jnp.zeros((cfg.num_layers, 4, KV, 8, hd), jnp.float32)
     with pytest.raises(ValueError, match="softcap or sliding window"):
         L.apply_gqa_paged(lp, jnp.zeros((1, 1, cfg.d_model)), cfg,
                           positions=jnp.zeros((1, 1), jnp.int32),
-                          pool_k=pool, pool_v=pool,
+                          pool_k=pool, pool_v=pool, layer=0,
                           block_tables=jnp.zeros((1, 2), jnp.int32),
                           active=jnp.ones((1,), bool), window=64,
                           impl="kernel", interpret=True)
