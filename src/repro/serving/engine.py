@@ -13,21 +13,21 @@ batching as the default step loop (DESIGN.md §10):
   preemption, migration and sliding-window reclamation compose with
   chunking. ``scheduler="phase"`` pins the legacy prefill-wave/decode-
   step alternation (identity baseline; forced for dense caches).
-* **Prefill** runs over a throwaway dense cache sized to the prompt's
-  POWER-OF-TWO length bucket — a whole prompt under the phase scheduler,
-  a budget-sliced chunk under the default one (a chunk continuation IS a
-  suffix prefill against the written span; both run the fused
-  ``_chunk_prefill_fn``: pool gather → splice → decode-mode extend →
-  suffix scatter, with per-row last-token gather picking each prompt's
-  real logits) — then scatters each request's true-length K/V into the
-  shared block pool via ``paged_kv.write_tokens_batch``. PREFIX SHARING
-  (on by default, ``prefix_sharing=``): an admission whose prompt opens
-  with an already-cached full-block prefix ALIASES those blocks
-  (refcounted, copy-on-write — paged_kv's prefix cache) and prefills
-  only its private suffix against the spliced shared context
-  (``_prefill_shared_batch``, one bucketed extend per (context, suffix)
-  group of hits), so a shared system prompt is stored and
-  prefilled once per pool, not once per request. Block
+* **Prefill** of a paged engine has ONE path, the fused
+  ``_chunk_prefill_fn`` (pool context gather → splice into a throwaway
+  dense cache → decode-mode extend over a POWER-OF-TWO suffix bucket,
+  with a per-row last-token gather picking each prompt's real logits →
+  suffix K/V scatter into the donated block pool). A whole prompt under
+  the phase scheduler, a budget-sliced chunk under the default one (a
+  chunk continuation IS a suffix prefill against the written span), a
+  prefix-cache hit (its suffix against the aliased blocks) and a miss (a
+  suffix with no context) all run it, one bucketed call per group
+  (``_prefill_shared_batch``). PREFIX SHARING (on by default,
+  ``prefix_sharing=``): an admission whose prompt opens with an
+  already-cached full-block prefix ALIASES those blocks (refcounted,
+  copy-on-write — paged_kv's prefix cache) and prefills only its
+  private suffix, so a shared system prompt is stored and prefilled
+  once per pool, not once per request. Block
   allocation/eviction is driven by the host-side free list — admission
   applies backpressure (requests wait in the queue) when the pool is out
   of blocks, and decode-time pressure preempts the youngest request back
@@ -159,27 +159,10 @@ STEP_COMPILER_OPTIONS = {"xla_allow_excess_precision": False}
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "window"))
-def _prefill_fn(params, tokens, cache, enc, last_idx, *, cfg, window):
+def _prefill_fn(params, tokens, cache, enc, *, cfg, window):
+    # the dense engine's whole-prompt prefill
     return T.forward(params, cfg, tokens, mode="prefill", cache=cache,
-                     window=window, encoder_input=enc, last_idx=last_idx)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "window"))
-def _extend_fn(params, tokens, positions, cache, *, cfg, window):
-    # multi-token continuation (chunked prefill tail chunks)
-    return T.forward(params, cfg, tokens, positions=positions,
-                     mode="decode", cache=cache, window=window)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg", "window"))
-def _extend_last_fn(params, tokens, positions, cache, last_idx, *, cfg,
-                    window):
-    # suffix prefill over an adopted shared prefix (prefix-cache hits):
-    # decode-mode continuation with a per-row last-REAL-token gather so
-    # padded suffix buckets return the right first-token logits
-    return T.forward(params, cfg, tokens, positions=positions,
-                     mode="decode", cache=cache, window=window,
-                     last_idx=last_idx)
+                     window=window, encoder_input=enc)
 
 
 @functools.partial(jax.jit,
@@ -194,14 +177,18 @@ def _chunk_prefill_fn(params, pool_k, pool_v, tbl, suffix, spos, pos,
     power-of-two (group, context, suffix) bucket instead of ~15 eager
     dispatches and four whole-buffer copies. This is what makes a chunk
     step cost like a decode step on the host side, which is the whole
-    point of slicing prefills under the token budget (DESIGN.md §10)."""
+    point of slicing prefills under the token budget (DESIGN.md §10).
+    Every paged prefill runs here: a whole prompt that missed the prefix
+    cache is a suffix with no context (one gathered block, all masked)."""
     L, _, KV, bs, hd = pool_k.shape
     G, n_blk = tbl.shape
-    cb = n_blk * bs
+    # the gathered blocks may outrun a short miss's cache; the context
+    # never does (it ends within the cache_len - Sb context bucket)
+    cb = min(n_blk * bs, cache_len)
     ctx_k = pool_k[:, tbl].transpose(0, 1, 2, 4, 3, 5).reshape(
-        L, G, cb, KV, hd)
+        L, G, n_blk * bs, KV, hd)[:, :, :cb]
     ctx_v = pool_v[:, tbl].transpose(0, 1, 2, 4, 3, 5).reshape(
-        L, G, cb, KV, hd)
+        L, G, n_blk * bs, KV, hd)[:, :, :cb]
     cache = T.init_cache(cfg, G, cache_len, dtype)
     kd = cache["layers"]["k"].dtype
     cache["layers"]["k"] = cache["layers"]["k"].at[:, :, :cb].set(
@@ -288,7 +275,6 @@ class Engine:
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 4,
                  max_len: int = 128, dtype="float32", swa: bool = False,
                  encoder_input_fn: Optional[Callable] = None,
-                 prefill_chunk: int = 0,
                  cache_kind: str = "dense", block_size: int = 16,
                  n_blocks: Optional[int] = None,
                  paged_attn_impl: str = "auto", interpret: bool = False,
@@ -313,7 +299,6 @@ class Engine:
         self.window = cfg.sliding_window if swa else None
         self.dtype = dtype
         self.encoder_input_fn = encoder_input_fn
-        self.prefill_chunk = prefill_chunk  # 0 = one-shot prefill
         self.cache_kind = cache_kind
         self.active: Dict[int, Request] = {}   # slot -> request
         # slots whose prompt is only PARTIALLY written (chunked prefill
@@ -418,7 +403,7 @@ class Engine:
         self.replication_degrees: Optional[tuple] = None  # plan intent
         self._step_degrees: Optional[tuple] = None        # quantized/static
         self._replicated = False    # weights + pool spread over the mesh
-        self._prefill_shapes = set()  # (G, S) executables admitted so far
+        self._prefill_shapes = set()  # (G, Sb) paged prefills run so far
 
     # ------------------------------------------------------------- sampling
     def _sample_batch(self, logits, reqs) -> np.ndarray:
@@ -520,40 +505,6 @@ class Engine:
                                    np.asarray(req.generated[:-1], np.int32)])
         return np.asarray(req.prompt, np.int32)
 
-    def _run_prefill(self, tokens_2d, cache_len: Optional[int] = None,
-                     enc=None, last_idx=None):
-        """Batched (possibly chunked) prefill over a throwaway cache.
-
-        The paged path sizes the cache exactly to the (bucket-padded)
-        prompt — its K/V is immediately scattered into the block pool; the
-        dense path keeps ``max_len`` so ``kvcache.insert_request`` shapes
-        line up. ``last_idx`` [G] selects each row's last REAL token for
-        the returned logits (power-of-two prefill buckets; incompatible
-        with chunking). Returns (last-token logits, cache)."""
-        G, S = tokens_2d.shape
-        self._prefill_shapes.add((G, S))
-        rcache = T.init_cache(self.cfg, G, cache_len or S, self.dtype)
-        if enc is None and self.cfg.family == "audio":
-            enc = jnp.zeros((G, self.cfg.encoder_seq_len,
-                             self.cfg.d_model), jnp.float32)
-        chunk = self.prefill_chunk or S
-        assert last_idx is None or chunk >= S, \
-            "per-row last-token gather needs one-shot prefill"
-        first = min(chunk, S)
-        logits, rcache, _ = _prefill_fn(
-            self.params, jnp.asarray(tokens_2d[:, :first]), rcache, enc,
-            last_idx, cfg=self.cfg, window=self.window)
-        off = first
-        while off < S:  # chunked prefill: bound per-iteration work
-            n = min(chunk, S - off)
-            toks = jnp.asarray(tokens_2d[:, off:off + n])
-            pos = jnp.broadcast_to(
-                jnp.arange(off, off + n, dtype=jnp.int32), (G, n))
-            logits, rcache, _ = _extend_fn(self.params, toks, pos, rcache,
-                                           cfg=self.cfg, window=self.window)
-            off += n
-        return logits, rcache
-
     def _activate(self, req: Request, slot: int, length: int,
                   first_tok: Optional[int]):
         fresh_first = first_tok is not None and not req.generated
@@ -599,9 +550,15 @@ class Engine:
             enc = (self.encoder_input_fn(req)
                    if self.cfg.family == "audio" and self.encoder_input_fn
                    else None)
-            logits, rcache = self._run_prefill(toks[None, :],
-                                               cache_len=self.max_len,
-                                               enc=enc)
+            if enc is None and self.cfg.family == "audio":
+                enc = jnp.zeros((1, self.cfg.encoder_seq_len,
+                                 self.cfg.d_model), jnp.float32)
+            # a throwaway cache of max_len, so that insert_request's
+            # shapes line up
+            rcache = T.init_cache(self.cfg, 1, self.max_len, self.dtype)
+            logits, rcache, _ = _prefill_fn(
+                self.params, jnp.asarray(toks[None, :]), rcache, enc,
+                cfg=self.cfg, window=self.window)
             first = None
             if not req.generated:
                 first = self._sample_batch(logits, [req])[0]
@@ -610,13 +567,64 @@ class Engine:
             self._activate(req, slot, len(toks), first)
 
     # ---------------------------------------------------------- paged admit
+    def _check_fits(self, req: Request, S: int):
+        """Reject a request that can never be admitted: its LIVE block
+        columns — prompt plus headroom for the first decode write, minus
+        the leading columns a sliding window has already retired
+        (``allocate`` skips them, so a long prompt never needs transient
+        full-length residency in a window pool) — outnumber the pool, or
+        its decode write head lies past the block-table row (prompt >=
+        max_len). Marks it finished with no output and raises
+        OutOfBlocks, on which the caller puts its other requests back."""
+        bs = self.pstate.block_size
+        width = self.pstate.block_tables.shape[1]
+        need = self._live_blocks(S)
+        if need > self.pstate.n_blocks or S // bs >= width:
+            req.finish_time = self.clock  # rejected: no output
+            if self.span_hook:
+                self.span_hook.on_finish(req)
+            raise PK.OutOfBlocks(
+                f"request rid={req.rid} needs {need} live blocks up to "
+                f"column {S // bs}; pool has {self.pstate.n_blocks}, "
+                f"table rows hold {width}")
+
+    def _live_blocks(self, S: int) -> int:
+        """Blocks an S-token prompt holds once written, with room for its
+        first decode write (``allocate``'s own dead-column rule under a
+        window — never fewer, so this bound never under-reserves)."""
+        bs = self.pstate.block_size
+        cols = S // bs + 1
+        if self.window:
+            cols -= min(PK.dead_columns(S, bs, self.window), cols - 1)
+        return cols
+
+    def _prefix_hit(self, toks) -> tuple:
+        """``(matched block ids, context tokens)`` an admission of
+        ``toks`` may alias from the prefix cache; ``([], 0)`` on a miss.
+        The context is capped at S-1 so at least one suffix token remains
+        to produce first-token logits (a fully-aliased aligned prompt
+        recomputes its last token — the write into the shared tail block
+        is what copy-on-write forks). Matching is off under a window,
+        whose reclamation invalidates full-prefix residency."""
+        if not self.prefix_sharing or self.window:
+            return [], 0
+        matched = PK.match_prefix(self.pstate, toks, record=False)
+        ctx = min(len(matched) * self.pstate.block_size, len(toks) - 1)
+        if not (matched and ctx >= 1):
+            return [], 0
+        return matched, ctx
+
     def _admit_paged(self, wave: Optional[List[Request]] = None):
-        """Admit a prefill WAVE: whole prompts, one bucketed forward per
-        pow2 length group (misses) / (ctx, suffix) group (prefix hits).
-        Phase scheduling pops its own wave from the queue; the
-        token-budget scheduler passes the full grants it popped as
-        ``wave``. Returns the requests actually admitted (callers detect
-        backpressure requeues by comparing against the wave)."""
+        """Admit a prefill WAVE of whole prompts through the chunk-prefill
+        program: a prefix hit prefills its suffix against the aliased
+        context, and a miss is a suffix with no context. One bucketed
+        forward per (context, suffix) group; the misses' groups run
+        first, so a wave-mate that hits a miss's just-registered prefix
+        reads written pages. Phase scheduling pops its own wave from the
+        queue; the token-budget scheduler passes the full grants it
+        popped as ``wave``. Returns the requests actually admitted
+        (callers detect backpressure requeues by comparing against the
+        wave)."""
         if wave is None:
             # phase mode still drains mid-prefill slots first (a
             # migrated-in chunked request must finish somewhere): grant
@@ -639,41 +647,19 @@ class Engine:
             if not taken:
                 return []
             assert len(taken) <= len(free), (len(taken), len(free))
-        bs = self.pstate.block_size
         ptoks = {id(r): self._prefill_tokens(r) for r in taken}
 
-        def blocks_needed(req):
-            # LIVE columns only: prompt + headroom for the first decode
-            # write, minus the leading columns a sliding window has
-            # already retired (allocate skips them — a long prompt never
-            # needs transient full-length residency in a window pool)
-            S = len(ptoks[id(req)])
-            cols = S // bs + 1
-            if self.window:
-                # allocate()'s own dead-column count at prefill time —
-                # never larger, so this bound never under-reserves
-                cols -= min(max((S - self.window + 1) // bs, 0), cols - 1)
-            return cols
-
-        def last_col(req):
-            return len(ptoks[id(req)]) // bs  # the decode write head
-
-        # pre-pass BEFORE any allocation: a request that can never fit —
-        # pool too small, or prompt >= max_len (block-table row too
-        # narrow) — is rejected now rather than head-of-line blocking
-        # everything behind it; the rest of the wave goes back to the
-        # queue intact, nothing is lost and no block leaks.
-        width = self.pstate.block_tables.shape[1]
+        # pre-pass BEFORE any allocation: a request that can never fit is
+        # rejected now rather than head-of-line blocking everything
+        # behind it; the rest of the wave goes back to the queue intact,
+        # nothing is lost and no block leaks.
         for req in taken:
-            need = blocks_needed(req)
-            if need > self.pstate.n_blocks or last_col(req) >= width:
+            try:
+                self._check_fits(req, len(ptoks[id(req)]))
+            except PK.OutOfBlocks:
                 for r in reversed([t for t in taken if t is not req]):
                     self.queue.appendleft(r)
-                req.finish_time = self.clock  # rejected: no output
-                raise PK.OutOfBlocks(
-                    f"request rid={req.rid} needs {need} live blocks up "
-                    f"to column {last_col(req)}; pool has "
-                    f"{self.pstate.n_blocks}, table rows hold {width}")
+                raise
 
         admitted: List[Request] = []
         slot_of: Dict[int, int] = {}
@@ -686,23 +672,15 @@ class Engine:
             # only needs pool capacity for its suffix (aliased blocks are
             # revived/shared in place, never popped), so a shared-prefix
             # request admits under pressure that would stall a cold one —
-            # the regime sharing exists for. The adopted context is
-            # capped at S-1 so at least one suffix token remains to
-            # produce first-token logits (a fully-aliased aligned prompt
-            # recomputes its last token — the write into the shared tail
-            # block is what copy-on-write forks).
-            matched = (PK.match_prefix(self.pstate, toks, record=False)
-                       if self.prefix_sharing and not self.window else [])
-            ctx = min(len(matched) * bs, len(toks) - 1)
-            if not (matched and ctx >= 1):
-                matched, ctx = [], 0
-            # blocks_needed covers every prompt column + write headroom
+            # the regime sharing exists for.
+            matched, ctx = self._prefix_hit(toks)
+            # live blocks cover every prompt column + write headroom
             # (enough for the worst-case CoW fork too); aliased columns
             # need no pop, but reviving a cached-free block does consume
             # a unit of free_block_count
             revive = sum(1 for b in matched
                          if int(self.pstate.refcount[b]) == 0)
-            if (blocks_needed(req) - len(matched)
+            if (self._live_blocks(len(toks)) - len(matched)
                     > self.pstate.free_block_count() - revive):
                 # out of blocks: backpressure — requeue IN ORDER and stop
                 for r in reversed(taken[idx:]):
@@ -717,77 +695,55 @@ class Engine:
                     for r in reversed(taken[idx:]):
                         self.queue.appendleft(r)
                     break
-                ctx_of[id(req)] = ctx
             else:
                 PK.allocate(self.pstate, slot, len(toks),
                             window=self.window)
                 if self.prefix_sharing and not self.window:
                     # publish this prompt's full blocks NOW so wave-mates
-                    # behind it match them: their reads (context gather
-                    # in _prefill_shared_batch) run only after this wave's
-                    # prefill writes, so the content is there by the time
-                    # it's read. Hit requests register AFTER their suffix
-                    # prefill instead — it can still fail (CoW fork under
-                    # pool pressure), and keys must never describe
-                    # unwritten blocks.
+                    # behind it match them: the misses' groups run before
+                    # any hit group below, so the content is written by
+                    # the time a hit's context gather reads it. Hit
+                    # requests register AFTER their suffix prefill
+                    # instead — it can still fail (CoW fork under pool
+                    # pressure), and keys must never describe unwritten
+                    # blocks.
                     PK.register_prefix(self.pstate, slot, toks)
+            ctx_of[id(req)] = ctx
             matched_of[id(req)] = matched
             slot_of[id(req)] = slot
             admitted.append(req)
-        # group prompts into power-of-two LENGTH BUCKETS (pad + per-row
-        # last-token gather) so admission compiles O(log max_len)
-        # executables instead of one per (group, prompt-len) pair; then
-        # activate in SUBMISSION order (group iteration would reorder
-        # _admit_order and break youngest-first preemption). Chunked
-        # prefill keeps exact lengths (chunking already bounds shapes).
-        groups: Dict[int, List[Request]] = {}
+        # BUCKETED prefill: group by (pow2 context bucket, pow2 suffix
+        # bucket), so each group runs ONE batched extend + ONE suffix
+        # scatter + ONE sampling call and admission compiles
+        # O(log^2 max_len) executables, not one per request or prompt
+        # length. A miss is a chunk with no context: context bucket 1,
+        # the program a first chunk runs. Misses group apart from hits
+        # and run first; then activate in SUBMISSION order (group
+        # iteration would reorder _admit_order and break youngest-first
+        # preemption)
+        groups: Dict[tuple, List[Request]] = {}
         for req in admitted:
-            if id(req) in ctx_of:
-                continue        # prefix-cache hit: suffix-only path below
-            S = len(ptoks[id(req)])
-            Sb = S if self.prefill_chunk else _pow2_at_least(S)
-            groups.setdefault(Sb, []).append(req)
-        first_of: Dict[int, Optional[int]] = {}
-        for Sb, reqs in groups.items():
-            lens = [len(ptoks[id(r)]) for r in reqs]
-            toks = np.zeros((len(reqs), Sb), np.int32)
-            for i, r in enumerate(reqs):
-                toks[i, :lens[i]] = ptoks[id(r)]
-            last = (None if self.prefill_chunk
-                    else jnp.asarray(np.asarray(lens, np.int32) - 1))
-            logits, rcache = self._run_prefill(toks, last_idx=last)
-            firsts = self._sample_batch(logits, reqs)
-            self.pstate = PK.write_tokens_batch(
-                self.pstate, [slot_of[id(r)] for r in reqs],
-                rcache["layers"]["k"], rcache["layers"]["v"],
-                lengths=lens)
-            for i, req in enumerate(reqs):
-                first_of[id(req)] = None if req.generated else firsts[i]
-        # cache hits: prefill the suffix only — BUCKETED like the miss
-        # wave: hits group by (pow2 context bucket, pow2 suffix bucket)
-        # and each group runs ONE batched extend + ONE suffix scatter +
-        # ONE sampling call, instead of one of each per hit request
-        hit_groups: Dict[tuple, List[Request]] = {}
-        for req in admitted:
-            if id(req) not in ctx_of:
-                continue
             ctx = ctx_of[id(req)]
-            n_new = len(ptoks[id(req)]) - ctx
-            key = (_pow2_at_least(max(ctx, 1)), _pow2_at_least(n_new))
-            hit_groups.setdefault(key, []).append(req)
+            key = (ctx > 0, _pow2_at_least(max(ctx, 1)),
+                   _pow2_at_least(len(ptoks[id(req)]) - ctx))
+            groups.setdefault(key, []).append(req)
+        first_of: Dict[int, Optional[int]] = {}
         failed: List[Request] = []
-        for (cb, Sb), greqs in hit_groups.items():
+        # misses first (a stable sort keeps each class's order)
+        for (hit, cb, Sb), greqs in sorted(groups.items(),
+                                           key=lambda kv: kv[0][0]):
             ok: List[Request] = []
             for req in greqs:
-                # copy-on-write forks BEFORE the group forward: the
+                # copy-on-write forks BEFORE the group forward: a hit's
                 # suffix write may land inside an aliased tail block. A
                 # fork that finds no free block (wave-mates consumed the
                 # headroom) drops just that request — nothing was
                 # written or registered for it — and it retries next step
                 try:
-                    PK.ensure_writable(self.pstate, slot_of[id(req)],
-                                       ctx_of[id(req)],
-                                       len(ptoks[id(req)]) - ctx_of[id(req)])
+                    if hit:
+                        PK.ensure_writable(
+                            self.pstate, slot_of[id(req)], ctx_of[id(req)],
+                            len(ptoks[id(req)]) - ctx_of[id(req)])
                     ok.append(req)
                 except PK.OutOfBlocks:
                     PK.free_slot(self.pstate, slot_of[id(req)])
@@ -797,8 +753,8 @@ class Engine:
             # pad the GROUP dim to a power of two as well (dummy rows
             # replicate the last member; their pool writes drop, their
             # logits are discarded) so executables are keyed on
-            # (pow2 G, pow2 ctx, pow2 suffix) — a warmed wave shape
-            # serves every later wave regardless of its exact hit count
+            # (pow2 G, context bucket, pow2 suffix) — a warmed wave shape
+            # serves every later wave regardless of its exact size
             Gb = _pow2_at_least(len(ok))
             pad = [ok[-1]] * (Gb - len(ok))
             logits = self._prefill_shared_batch(
@@ -806,7 +762,7 @@ class Engine:
                 [ptoks[id(r)] for r in ok + pad],
                 [ctx_of[id(r)] for r in ok + pad], cb, Sb,
                 n_real=len(ok))
-            if self.prefix_sharing and not self.window:
+            if hit:     # a hit implies sharing on and no window
                 for r in ok:
                     PK.register_prefix(self.pstate, slot_of[id(r)],
                                        ptoks[id(r)])
@@ -836,19 +792,21 @@ class Engine:
     def _prefill_shared_batch(self, slots: List[int], toks_list,
                               ctxs: List[int], cb: int, Sb: int,
                               n_real: Optional[int] = None):
-        """Bucketed suffix-only prefill for a GROUP of prefix-cache hits:
-        splice every hit's adopted shared-block K/V (ONE batched pool
-        gather) into a shared throwaway dense cache as attention context,
-        run one decode-mode continuation over the padded suffix rows, and
-        scatter ONLY the suffix K/V back (one batched pool write — the
-        shared spans are never re-written). Prefill compute scales with
-        the unshared suffixes, and executable count with the number of
-        (context, suffix) power-of-two buckets — O(log² max_len) — not
-        with the number of hit requests. Per-row true context lengths
-        ride in the positions array (poisoned past ctx_i: BIG_POS rows
-        are masked out of attention), so one executable serves every
-        member of the bucket. Callers run ``ensure_writable`` (CoW fork)
-        per member beforehand."""
+        """Bucketed suffix prefill for a GROUP of slots — the one paged
+        prefill: prefix-cache hits, chunks, and misses (no context: one
+        gathered block, every position masked). Splice every row's
+        written context K/V (ONE batched pool gather) into a shared
+        throwaway dense cache as attention context, run one decode-mode
+        continuation over the padded suffix rows, and scatter ONLY the
+        suffix K/V back (one batched pool write — the shared spans are
+        never re-written).
+        Prefill compute scales with the suffixes, and executable count
+        with the number of (context, suffix) power-of-two buckets —
+        O(log² max_len) — not with the number of requests. Per-row true
+        context lengths ride in the positions array (poisoned past
+        ctx_i: BIG_POS rows are masked out of attention), so one
+        executable serves every member of the bucket. Callers run
+        ``ensure_writable`` (CoW fork) per member beforehand."""
         G = len(slots)
         n_real = G if n_real is None else n_real
         # dummy pad rows (duplicated slots past n_real) scatter nothing:
@@ -952,39 +910,26 @@ class Engine:
 
     def _begin_chunked(self, req: Request, n: int) -> Optional[_ChunkSpec]:
         """Admit a WAITING request with a partial grant: claim a slot,
-        run the same never-fits rejection and prefix-cache lookup as the
-        wave path, and hand back the first chunk for execution. The
-        request may sit anywhere in the queue (class-aware policies
-        grant out of FIFO order); it keeps its position on allocation
-        failure (backpressure). Prefix hits advance the cursor for free
-        (aliased context costs no compute). Returns None when nothing
-        was admitted."""
+        run the wave path's never-fits rejection (``_check_fits``) and
+        prefix-cache lookup (``_prefix_hit``), and hand back the first
+        chunk for execution. The request may sit anywhere in the queue
+        (class-aware policies grant out of FIFO order); it keeps its
+        position on allocation failure (backpressure). Prefix hits
+        advance the cursor for free (aliased context costs no compute).
+        Returns None when nothing was admitted."""
         assert any(r is req for r in self.queue), req.rid
         toks = self._prefill_tokens(req)
         S = len(toks)
-        bs = self.pstate.block_size
-        width = self.pstate.block_tables.shape[1]
-        need = S // bs + 1
-        if self.window:
-            need -= min(max((S - self.window + 1) // bs, 0), need - 1)
-        if need > self.pstate.n_blocks or S // bs >= width:
+        try:
+            self._check_fits(req, S)
+        except PK.OutOfBlocks:
             self._queue_remove(req)
-            req.finish_time = self.clock  # rejected: no output
-            if self.span_hook:
-                self.span_hook.on_finish(req)
-            raise PK.OutOfBlocks(
-                f"request rid={req.rid} needs {need} live blocks up to "
-                f"column {S // bs}; pool has {self.pstate.n_blocks}, "
-                f"table rows hold {width}")
+            raise
         free = self._free_slots()
         if not free:
             return None
         slot = free[0]
-        matched = (PK.match_prefix(self.pstate, toks, record=False)
-                   if self.prefix_sharing and not self.window else [])
-        ctx = min(len(matched) * bs, S - 1)
-        if not (matched and ctx >= 1):
-            matched, ctx = [], 0
+        matched, ctx = self._prefix_hit(toks)
         if matched:
             PK.adopt_prefix(self.pstate, slot, matched, ctx)
         self._queue_remove(req)

@@ -52,8 +52,10 @@ Division of labour with the engine:
   cache ops (``match_prefix`` / ``adopt_prefix`` / ``register_prefix`` /
   ``ensure_writable``) run on the HOST free list + refcounts (forking is
   the only one that touches the device: one pool-block copy);
-* ``write_tokens`` scatters a freshly prefilled request's K/V into the
-  pool (one functional scatter per request, issued at admission);
+* ``scatter_plan`` is the host half of every prefill write into the
+  pool: the engine's jitted chunk-prefill program does the device half
+  (misses, prefix hits and chunks alike), and ``write_tokens`` is the
+  eager single-request write that migration tooling and tests use;
 * ``export_blocks`` / ``import_blocks`` are the block-granular migration
   wire format (DESIGN.md): CoCoServe's scale-down moves a live request's
   KV blocks between instances' pools without touching dense slabs;
@@ -234,8 +236,9 @@ def mark_written(state: PagedState, block_ids) -> int:
     dirty-set bookkeeping behind two-phase migration: a later
     ``export_blocks(..., since_epoch=e)`` ships exactly the blocks
     stamped after epoch ``e``. Called by every pool-content writer: the
-    batched prefill scatter, the fused decode step's host bookkeeping
-    (serving/engine.py), CoW forks, and imports. Returns the new epoch."""
+    prefill scatter (``scatter_plan``), the fused decode step's host
+    bookkeeping (serving/engine.py), CoW forks, and imports. Returns the
+    new epoch."""
     state.write_epoch += 1
     ids = [int(b) for b in block_ids if 0 <= int(b) < state.n_blocks]
     if ids:
@@ -244,6 +247,15 @@ def mark_written(state: PagedState, block_ids) -> int:
 
 
 # -------------------------------------------------------------- allocation
+def dead_columns(length: int, block_size: int, window: int) -> int:
+    """Leading block-table columns wholly out of the attention window of
+    the query at position ``length``: it attends keys with position >
+    ``length - window`` (see layers._attn_mask), so column c is dead once
+    ``(c+1)*bs - 1 <= length - window``. The one rule behind window
+    allocation, reclamation and the engine's admission bound."""
+    return max((length - window + 1) // block_size, 0)
+
+
 def allocate(state: PagedState, slot: int, n_tokens: int,
              window: Optional[int] = None):
     """Ensure ``slot`` has blocks for lengths[slot] + n_tokens tokens.
@@ -267,10 +279,8 @@ def allocate(state: PagedState, slot: int, n_tokens: int,
     first_col = start // bs
     last_col = (start + n_tokens - 1) // bs
     if window is not None:
-        # same dead-column rule as free_out_of_window at the post-write
-        # length: the next query (pos start+n_tokens) attends kpos >
-        # start+n_tokens-window only
-        dead = (start + n_tokens - window + 1) // bs
+        # columns dead for the next query (pos start+n_tokens)
+        dead = dead_columns(start + n_tokens, bs, window)
         first_col = max(first_col, min(dead, last_col))
     if last_col >= state.block_tables.shape[1]:
         raise OutOfBlocks(
@@ -303,21 +313,20 @@ def free_out_of_window(state: PagedState, slot: int, window: int) -> int:
     """Sliding-window reclamation: release the leading blocks of ``slot``
     whose every token has fallen out of the attention window.
 
-    The next query sits at position ``lengths[slot]`` and attends keys
-    with position > ``lengths[slot] - window`` (see layers._attn_mask), so
-    table column c is dead once ``(c+1)*bs - 1 <= lengths[slot] - window``.
-    Dead columns become holes (-1) that the masked attention never reads
-    and column-indexed ``allocate`` never refills. Out-of-window release
-    is a DECREF like any other: a block another stream still references
-    merely loses this slot's claim. Returns #blocks this slot released.
+    The next query sits at position ``lengths[slot]``; the columns
+    ``dead_columns`` counts for it become holes (-1) that the masked
+    attention never reads and column-indexed ``allocate`` never refills.
+    Out-of-window release is a DECREF like any other: a block another
+    stream still references merely loses this slot's claim. Returns
+    #blocks this slot released.
 
     Called per slot per decode step, so it must not rescan history: dead
     columns below the newly-dead ones are already holes (freed earlier or
     window-skipped at allocation), hence the backward scan stops at the
     first hole — O(newly dead + 1) per call, O(1) amortized.
     """
-    bs = state.block_size
-    n_dead = min(max((int(state.lengths[slot]) - window + 1) // bs, 0),
+    n_dead = min(dead_columns(int(state.lengths[slot]), state.block_size,
+                              window),
                  state.block_tables.shape[1])
     freed = 0
     for c in range(n_dead - 1, -1, -1):
@@ -483,13 +492,6 @@ def prefix_stats(state: PagedState) -> Dict:
 
 
 # ------------------------------------------------------------- pool writes
-def write_tokens(state: PagedState, slot: int, k_new, v_new):
-    """Append k/v for S new tokens of one request (k_new/v_new:
-    [L, S, KV, hd]). Requires allocate() first; if any touched column is
-    shared, the caller must ``ensure_writable`` first (the engine does)."""
-    return write_tokens_batch(state, [slot], k_new[:, None], v_new[:, None])
-
-
 def scatter_plan(state: PagedState, slots, S: int,
                  lengths: Optional[Sequence[int]] = None):
     """Host half of a batched S-token append for G slots: per-token
@@ -498,8 +500,8 @@ def scatter_plan(state: PagedState, slots, S: int,
     prefixes; -1 would WRAP, not drop) point at the out-of-range block
     ``n_blocks`` so a ``mode="drop"`` scatter discards them. ADVANCES
     ``state.lengths`` and stamps the write epoch — callers must execute
-    the device scatter they planned (``write_tokens_batch``, or the
-    engine's fused chunk-prefill executable)."""
+    the device scatter they planned (``write_tokens``, or the engine's
+    fused chunk-prefill executable)."""
     bs = state.block_size
     if lengths is None:
         lengths = [S] * len(slots)
@@ -520,32 +522,23 @@ def scatter_plan(state: PagedState, slots, S: int,
     return bidx, np.concatenate(offs)
 
 
-def write_tokens_batch(state: PagedState, slots, k_new, v_new,
-                       lengths: Optional[Sequence[int]] = None):
-    """Append k/v for up to S new tokens of G requests in ONE pool scatter.
-
-    k_new/v_new: [L, G, S, KV, hd] — S is the (possibly padded) group
-    length; ``lengths`` gives each request's TRUE new-token count (default
-    S for all). Rows are padded to a shared S by the engine's power-of-two
-    prefill buckets; pad positions scatter to an out-of-range block index
-    and are dropped, so one executable serves the whole bucket.
-
-    A functional ``.at[].set`` copies the whole pool, so batching a
-    G-request admission wave into one scatter per pool costs 2 copies
-    instead of 2·G. Requires allocate() first (for the true lengths), and
-    — refcount contract — every written column must be OWNED (refcount 1)
-    by its slot: the engine forks shared columns via ``ensure_writable``
-    before scattering. Returns the updated (functional) device arrays
-    stored back into ``state``.
-    """
-    L, G, S = k_new.shape[:3]
-    bidx, oidx = scatter_plan(state, slots, S, lengths)
-    bidx = jnp.asarray(bidx, jnp.int32)                     # [G*S]
+def write_tokens(state: PagedState, slot: int, k_new, v_new):
+    """Append k/v for S new tokens of one request (k_new/v_new:
+    [L, S, KV, hd]) in one functional pool scatter, outside any jit: the
+    single-slot write that migration tooling and tests use (the engine's
+    prefill writes ride inside its jitted chunk-prefill program).
+    Requires allocate() first, and — refcount contract — every written
+    column must be OWNED (refcount 1) by ``slot``: fork shared columns
+    with ``ensure_writable`` first. Returns ``state`` with the updated
+    device arrays."""
+    S = k_new.shape[1]
+    bidx, oidx = scatter_plan(state, [slot], S)
+    bidx = jnp.asarray(bidx, jnp.int32)                     # [S]
     oidx = jnp.asarray(oidx, jnp.int32)
     # pool is [L, n_blocks, KV, bs, hd]: advanced indices at axes 1 and 3
-    # move to the front, so updates are laid out [G*S, L, KV, hd]
-    kf = k_new.reshape(L, G * S, *k_new.shape[3:]).transpose(1, 0, 2, 3)
-    vf = v_new.reshape(L, G * S, *v_new.shape[3:]).transpose(1, 0, 2, 3)
+    # move to the front, so updates are laid out [S, L, KV, hd]
+    kf = jnp.asarray(k_new).transpose(1, 0, 2, 3)
+    vf = jnp.asarray(v_new).transpose(1, 0, 2, 3)
     state.k = state.k.at[:, bidx, :, oidx].set(kf.astype(state.k.dtype),
                                                mode="drop")
     state.v = state.v.at[:, bidx, :, oidx].set(vf.astype(state.v.dtype),

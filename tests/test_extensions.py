@@ -467,21 +467,7 @@ def test_paged_engine_out_of_blocks_backpressure():
     assert e4.pstate.blocks_in_use() == 0
 
 
-# ---------------------------------------------------- chunked prefill + sample
-@pytest.mark.parametrize("arch", ["tinyllama-1.1b", "mamba2-780m"])
-def test_chunked_prefill_equivalence(arch):
-    cfg = get_config(arch).reduced()
-    params = T.init_params(cfg, KEY, "float32")
-    prompt = np.random.default_rng(3).integers(
-        2, cfg.vocab_size, size=19).astype(np.int32)
-    outs = []
-    for chunk in (0, 7):
-        e = Engine(cfg, params, max_batch=2, max_len=64, prefill_chunk=chunk)
-        e.submit(RequestSpec(rid=0, prompt=prompt, max_tokens=4))
-        outs.append(e.run_until_done()[0].generated)
-    assert outs[0] == outs[1]
-
-
+# ------------------------------------------------------------------ sampling
 def test_sampling_seeded():
     cfg = get_config("tinyllama-1.1b").reduced()
     params = T.init_params(cfg, KEY, "float32")

@@ -308,6 +308,45 @@ def test_sharing_skips_prefill_compute_for_shared_span(tiny):
     assert full and suffix, shapes
 
 
+@pytest.mark.parametrize("temperature,top_k", [(0.0, 0), (0.8, 16)])
+def test_wave_miss_then_wave_mate_hit(tiny, temperature, top_k):
+    """One admission wave holds a miss and a wave-mate whose prompt opens
+    with the miss's two full blocks. The miss registers its blocks before
+    its prefill, so the wave-mate aliases them in the same wave: the
+    misses' group has to write them before the hit's group gathers them
+    as context. Tokens equal the two requests admitted one step apart
+    with sharing off."""
+    cfg, params = tiny
+    rng = np.random.default_rng(11)
+    first = rng.integers(2, cfg.vocab_size, size=19).astype(np.int32)
+    second = np.concatenate(
+        [first[:16], rng.integers(2, cfg.vocab_size, size=5).astype(np.int32)])
+
+    def spec(rid, prompt):
+        return RequestSpec(rid=rid, prompt=prompt, max_tokens=6,
+                           sampling=SamplingParams(temperature=temperature,
+                                                   top_k=top_k, seed=3 + rid))
+
+    def run(share, apart):
+        eng = Engine(cfg, params, max_batch=2, max_len=64,
+                     cache_kind="paged", block_size=8, prefix_sharing=share)
+        eng.submit(spec(0, first))
+        if apart:
+            eng.step()
+        eng.submit(spec(1, second))
+        done = eng.step() or []
+        assert sorted(r.rid for r in eng.active.values()) == [0, 1]
+        done += eng.run_until_done()
+        return {r.rid: r.generated for r in done}, eng
+
+    together, eng = run(share=True, apart=False)
+    assert eng.prefix_stats()["hits"] == 2      # both full blocks aliased
+    assert eng.pstate.blocks_in_use() == 0
+    _check_invariants(eng.pstate)
+    apart, _ = run(share=False, apart=True)
+    assert together == apart
+
+
 def test_hit_admits_under_pressure_that_stalls_cold_request(tiny):
     """Backpressure accounts for aliasing: a request whose prefix is
     RESIDENT (held by an active stream) admits when the pool only has

@@ -219,21 +219,46 @@ def test_paged_decode_step_copies_no_pool(one_chip, cell):
     assert temp < STEP_TEMP_LIMIT, temp
 
 
-def test_chunk_prefill_compiles_at_full_width(one_chip, cfg):
-    """One chunk bucket: 128 new tokens over a 256-token pool context."""
-    G, ctx_blocks, Sb = 1, 256 // BS, 128
-    cache_len = ctx_blocks * BS + Sb
-    pool = _pool(cfg, one_chip)
+def _compile_chunk_prefill(cfg, sharding, G, ctx_blocks, Sb, cache_len,
+                           n_blocks=N_BLOCKS):
+    """The engine's chunk-prefill program, the one paged prefill: ``G``
+    rows of ``Sb`` new tokens over ``ctx_blocks`` gathered pool blocks,
+    its pools donated as the engine donates them."""
+    pool = _pool(cfg, sharding, n_blocks)
 
     def ints(*shape):
-        return _spec(shape, jnp.int32, one_chip)
+        return _spec(shape, jnp.int32, sharding)
 
-    compiled = E._chunk_prefill_fn.lower(
-        _params(cfg, one_chip), pool, pool, ints(G, ctx_blocks), ints(G, Sb),
+    return E._chunk_prefill_fn.lower(
+        _params(cfg, sharding), pool, pool, ints(G, ctx_blocks), ints(G, Sb),
         ints(G, Sb), ints(G, cache_len), ints(G), ints(G * Sb),
         ints(G * Sb), cfg=cfg, window=None, cache_len=cache_len,
         dtype="bfloat16").compile()
+
+
+def test_chunk_prefill_compiles_at_full_width(one_chip, cfg):
+    """One chunk bucket: 128 new tokens over a 256-token pool context."""
+    G, ctx_blocks, Sb = 1, 256 // BS, 128
+    compiled = _compile_chunk_prefill(cfg, one_chip, G, ctx_blocks, Sb,
+                                      ctx_blocks * BS + Sb)
     assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_miss_prefill_copies_at_most_one_pool_half(one_chip, cell):
+    """A whole prompt that missed the prefix cache, at a benchmark cell's
+    shapes: a chunk with no context (one gathered block, context bucket
+    1) of 512 tokens. Its suffix scatter may re-lay out a pool half, as
+    the eager write it replaced did, but its temporaries stay under one
+    pool half plus 16 MB: a copy of the whole pool would fail here."""
+    cfg, _, n_blocks, _ = _cell(cell)
+    Sb = 512
+    compiled = _compile_chunk_prefill(cfg, one_chip, 1, 1, Sb, 1 + Sb,
+                                      n_blocks)
+    half = (cfg.num_layers * n_blocks * cfg.num_kv_heads * BS
+            * cfg.resolved_head_dim * 2)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < half + 16 * 2 ** 20, (temp, half)
 
 
 def _compile_replicated(topo, cfg, n_chips, rows=B, n_blocks=N_BLOCKS,
